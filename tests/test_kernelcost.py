@@ -75,30 +75,37 @@ def clean(monkeypatch):
 
 
 class TestRooflineMath:
-    def test_default_peaks_labeled_as_default(self, clean):
+    def test_table_peaks_labeled_as_table(self, clean):
         pf, pb, prov = kernelcost.roofline_peaks("cpu")
-        assert (pf, pb) == kernelcost.DEFAULT_PEAKS["cpu"]
-        assert prov == "default"
+        assert (pf, pb) == kernelcost.PEAKS["cpu"]
+        assert prov == "table"
+        # keyed by the device_kind jax reports, not by platform name
+        assert kernelcost.roofline_peaks("TPU v5 lite")[:2] == (1.97e14, 8.19e11)
 
     def test_env_peaks_override_and_provenance(self, clean):
         clean.setenv(
-            kernelcost.ENV_PEAKS, "tpu=1e14:1e12, cpu=4e10:1e10"
+            kernelcost.ENV_PEAKS, "TPU v5 lite=1e14:1e12, cpu=4e10:1e10"
         )
         pf, pb, prov = kernelcost.roofline_peaks("cpu")
         assert (pf, pb, prov) == (4e10, 1e10, "env")
-        # unknown platform falls through to defaults
-        assert kernelcost.roofline_peaks("gpu")[2] == "default"
+        assert kernelcost.roofline_peaks("tpu v5 lite") == (1e14, 1e12, "env")
 
-    def test_garbage_env_degrades_to_defaults(self, clean):
+    def test_unknown_device_kind_is_an_error(self, clean):
+        with pytest.raises(LookupError, match="no peaks for device kind 'tpu'"):
+            kernelcost.roofline_peaks("tpu")
+        with pytest.raises(LookupError, match="no peaks for"):
+            kernelcost.classify(1e6, 1e6, device_kind="TPU v9")
+
+    def test_garbage_env_degrades_to_table(self, clean):
         clean.setenv(kernelcost.ENV_PEAKS, "cpu=fast:wide,,tpu")
         pf, pb, prov = kernelcost.roofline_peaks("cpu")
-        assert (pf, pb) == kernelcost.DEFAULT_PEAKS["cpu"]
-        assert prov == "default"
+        assert (pf, pb) == kernelcost.PEAKS["cpu"]
+        assert prov == "table"
 
     def test_classify_ridge_point_split(self, clean):
         clean.setenv(kernelcost.ENV_PEAKS, "cpu=1e10:1e9")  # ridge = 10 flop/B
-        lo = kernelcost.classify(flops=1e6, bytes_accessed=1e6, platform="cpu")
-        hi = kernelcost.classify(flops=1e8, bytes_accessed=1e6, platform="cpu")
+        lo = kernelcost.classify(flops=1e6, bytes_accessed=1e6, device_kind="cpu")
+        hi = kernelcost.classify(flops=1e8, bytes_accessed=1e6, device_kind="cpu")
         assert lo["classification"] == "memory-bound"
         assert hi["classification"] == "compute-bound"
         assert lo["arithmetic_intensity"] == pytest.approx(1.0)
@@ -107,16 +114,16 @@ class TestRooflineMath:
 
     def test_roofline_pct_needs_measured_seconds(self, clean):
         clean.setenv(kernelcost.ENV_PEAKS, "cpu=1e10:1e9")
-        unmeasured = kernelcost.classify(1e6, 1e6, platform="cpu")
+        unmeasured = kernelcost.classify(1e6, 1e6, device_kind="cpu")
         assert unmeasured["roofline_pct"] is None
         # AI=1 → attainable = 1e9 flop/s; 1e6 flops in 0.01s = 1e8 → 10%
         measured = kernelcost.classify(
-            1e6, 1e6, device_secs=0.01, platform="cpu"
+            1e6, 1e6, device_secs=0.01, device_kind="cpu"
         )
         assert measured["roofline_pct"] == pytest.approx(0.1)
         # achieved can never render above the roof
         capped = kernelcost.classify(
-            1e12, 1e6, device_secs=1e-9, platform="cpu"
+            1e12, 1e6, device_secs=1e-9, device_kind="cpu"
         )
         assert capped["roofline_pct"] == 1.0
 
@@ -124,7 +131,7 @@ class TestRooflineMath:
         clean.setenv(kernelcost.ENV_PEAKS, "cpu=1e10:1e9")
         line = kernelcost.render_roofline(
             1.2e9, 890 * (1 << 20), peak_hbm_bytes=98304,
-            device_secs=0.5, platform="cpu",
+            device_secs=0.5, device_kind="cpu",
         )
         assert line.startswith("flops 1.2G · hbm 890MB · peak 96KB · arith ")
         assert "flop/B → " in line and line.endswith(" @ cpu")
@@ -501,14 +508,10 @@ class TestLadderAndRegress:
     @pytest.fixture(autouse=True)
     def _bench_env(self, monkeypatch, tmp_path):
         """bench._make_runner setdefault()s a repo-level TRINO_TPU_CAP_STORE
-        into os.environ and repoints the jax compilation cache — both would
-        leak past this class into the rest of the pytest session. Pre-set
-        the env to a tmp path (so the setdefault is a no-op monkeypatch
-        undoes) and restore the cache-dir config afterwards."""
+        into os.environ, which would leak past this class into the rest of
+        the pytest session. Pre-set the env to a tmp path (so the setdefault
+        is a no-op monkeypatch undoes)."""
         monkeypatch.setenv("TRINO_TPU_CAP_STORE", str(tmp_path / "caps.json"))
-        prev = jax.config.jax_compilation_cache_dir
-        yield
-        jax.config.update("jax_compilation_cache_dir", prev)
 
     def _micro_ladder(self, **kw):
         import bench

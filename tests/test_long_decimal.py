@@ -227,3 +227,18 @@ class TestCastsAndFunctions:
             "AS decimal(18,2)) AS try_like_marker) t",
         )
         assert got == [(None,)]
+
+
+class TestShortDecimalDecode:
+    """DECIMAL(p<=18) decodes to float only while float division is exact."""
+
+    def test_scaled_magnitude_of_2_53_or_more_is_exact(self, runner):
+        # TPC-H q1's sum_charge at SF1: decoded through float it came out
+        # as ...685326
+        rows = q(runner, "SELECT x FROM (VALUES 58957128388.685325, -0.000001, NULL) t(x)")
+        assert rows == [(D("58957128388.685325"),), (D("-0.000001"),), (None,)]
+        assert all(isinstance(r[0], D) for r in rows[:2])
+
+    def test_below_it_stays_float(self, runner):
+        assert q(runner, "SELECT 5.25") == [(5.25,)]
+        assert isinstance(q(runner, "SELECT 5.25")[0][0], float)

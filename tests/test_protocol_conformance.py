@@ -3,7 +3,7 @@
 Black-box validation of `/v1/statement` against the reference's documented
 client protocol, keyed to the sections of
 docs/src/main/sphinx/develop/client-protocol.md (no JVM Trino client can
-run in this image — BASELINE.md records the constraint — so conformance is
+run in this image, so conformance is
 asserted against the protocol DOCUMENT, the same contract those clients
 implement).
 

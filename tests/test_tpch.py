@@ -1,4 +1,4 @@
-"""Real TPC-H queries vs the pandas oracle (the BASELINE.md workload ladder:
+"""Real TPC-H queries vs the pandas oracle (the BASELINE.json workload ladder:
 Q6 scan+filter+sum, Q1 multi-key group-by, Q3/Q14 joins, Q13 left join,
 Q18 having+in-subquery+joins, Q5 six-way join)."""
 

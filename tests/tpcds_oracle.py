@@ -4,8 +4,7 @@ expected results over IDENTICAL generated data.
 The analogue of the reference's H2QueryRunner (testing/trino-testing/.../
 H2QueryRunner.java) — Trino verifies engine results against a second,
 unrelated SQL engine over the same rows; we use the stdlib sqlite3 (3.39+
-has window functions and FULL OUTER JOIN). No DuckDB exists in this image
-(BASELINE.md records the constraint).
+has window functions and FULL OUTER JOIN). No DuckDB exists in this image.
 
 Canonical-text translation (to_sqlite_sql): DATE literals become epoch-day
 integers (our storage representation, so `date +/- INTERVAL 'n' DAY`

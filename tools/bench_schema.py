@@ -41,8 +41,6 @@ _ALL = frozenset(REQUIREMENTS)
 # checked-in record; see module docstring). Nothing else is ever waived.
 LEGACY_EXCEPTIONS: dict = {
     "BENCH_r01.json": _ALL,
-    "BENCH_r02.json": _ALL,
-    "BENCH_r03.json": _ALL,
     "BENCH_r04.json": _ALL,
     "BENCH_r05.json": _ALL,
     "BENCH_r06_ooc_ab.json": _ALL,

@@ -43,7 +43,6 @@ def load_sql(name: str) -> str:
 
 def child(name: str, scale: float) -> None:
     """Runs in a subprocess: prints ONE json line with the result."""
-    os.environ.setdefault("JAX_PLATFORM_NAME", "cpu")
     sys.path.insert(0, REPO)  # script lives in tools/: repo root isn't on path
     sys.setrecursionlimit(20000)  # q08-class giant IN-lists recurse in the parser
     out = {"query": name}
@@ -51,14 +50,8 @@ def child(name: str, scale: float) -> None:
     try:
         import jax
 
+        # conformance is a CPU run; the package import places the compile cache
         jax.config.update("jax_platforms", "cpu")
-        cache = os.path.join(REPO, "tests", ".jax_cache")
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        try:
-            jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
-        except Exception:
-            jax.config.update("jax_compilation_cache_dir", "")
 
         sql = load_sql(name)
         from trino_tpu.sql import parse_statement

@@ -310,12 +310,11 @@ TABLES: Dict[str, Dict[str, Tuple[ColumnMetadata, ...]]] = {
 
 
 def device_kind() -> str:
-    try:
-        import jax
+    """``device_kind`` of the default device, as JAX reports it (e.g. "TPU v5
+    lite", "cpu") — what system.runtime.nodes and node announcements show."""
+    import jax
 
-        return jax.default_backend()
-    except Exception:  # noqa: BLE001 — table degrades, never fails
-        return "unknown"
+    return jax.devices()[0].device_kind
 
 
 def _ms(secs: Optional[float]) -> Optional[int]:

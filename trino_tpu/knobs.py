@@ -272,12 +272,12 @@ ENV_KNOBS: Tuple[EnvKnob, ...] = (
         "own retryable request); unset/0 = 8MB",
     ),
     EnvKnob(
-        "TRINO_TPU_ROOFLINE_PEAKS", "str", "built-in per-platform defaults",
-        "measured roofline peaks per platform for kernel-cost diagnosis, "
-        "\"platform=FLOPS:BYTES\" comma-separated (e.g. "
-        "\"cpu=5e10:2e10,tpu=1.97e14:8.19e11\"); unset = conservative "
-        "placeholder defaults (classification still honest, pct-of-roofline "
-        "approximate)",
+        "TRINO_TPU_ROOFLINE_PEAKS", "str", "the kernelcost.PEAKS table",
+        "measured roofline peaks per device kind for kernel-cost diagnosis, "
+        "\"device_kind=FLOPS:BYTES\" comma-separated (e.g. "
+        "\"cpu=5e10:2e10,TPU v5 lite=1.97e14:8.19e11\"); unset = the "
+        "published peaks in kernelcost.PEAKS; a device kind in neither is "
+        "an error",
     ),
 )
 

@@ -1,13 +1,15 @@
 """Native (C++) runtime kernels, loaded via ctypes.
 
-Built on demand with g++ (baked toolchain) and cached next to the source; falls
-back to a pure-Python store codec when no compiler is available, so the engine
-never hard-depends on the native build.
+Built on demand with g++ (baked toolchain) on the machine that runs them and
+cached next to the source. Where the build fails, ``native_available()`` is
+False, ``load_error()`` says why, and serde uses its pure-Python store codec.
 """
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import glob
 import hashlib
 import os
 import subprocess
@@ -19,18 +21,27 @@ import numpy as np
 _LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
+_ERROR: Optional[str] = None
+
+
+def _machine() -> bytes:
+    """This boot of this host. Part of the binary's key because the build
+    uses -march=native and a checkout's disk gets copied between hosts."""
+    with open("/proc/sys/kernel/random/boot_id", "rb") as f:
+        return f.read().strip()
 
 
 def _build_and_load() -> Optional[ctypes.CDLL]:
-    # The binary is keyed by a content hash of the source so a stale (or
-    # tampered/committed) .so is never dlopen'd as-is: binaries are always
-    # rebuilt from the reviewed source on content change, never shipped in git
-    # (*.so is gitignored).
-    src = os.path.join(os.path.dirname(__file__), "pageserde.cpp")
+    # The binary is keyed by a content hash of the source and the machine, so
+    # a stale, committed or copied-in .so is never dlopen'd: it is rebuilt
+    # here from the reviewed source (*.so is gitignored).
+    global _ERROR
+    here = os.path.dirname(__file__)
+    src = os.path.join(here, "pageserde.cpp")
     try:
         with open(src, "rb") as f:
-            digest = hashlib.sha256(f.read()).hexdigest()[:16]
-        out = os.path.join(os.path.dirname(__file__), f"_pageserde-{digest}.so")
+            digest = hashlib.sha256(f.read() + _machine()).hexdigest()[:16]
+        out = os.path.join(here, f"_pageserde-{digest}.so")
         if not os.path.exists(out):
             tmp = out + f".tmp{os.getpid()}"
             subprocess.run(
@@ -39,8 +50,16 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
                 capture_output=True,
             )
             os.replace(tmp, out)
+            for stale in glob.glob(os.path.join(here, "_pageserde-*.so")):
+                if stale != out:
+                    with contextlib.suppress(FileNotFoundError):
+                        os.unlink(stale)  # a sibling process got there first
         lib = ctypes.CDLL(out)
-    except (OSError, subprocess.CalledProcessError):
+    except subprocess.CalledProcessError as e:
+        _ERROR = f"g++ failed: {e.stderr.decode(errors='replace').strip()}"
+        return None
+    except OSError as e:
+        _ERROR = f"{type(e).__name__}: {e}"
         return None
     lib.lz4_compress.restype = ctypes.c_int64
     lib.lz4_compress.argtypes = [
@@ -68,6 +87,11 @@ def get_lib() -> Optional[ctypes.CDLL]:
 
 def native_available() -> bool:
     return get_lib() is not None
+
+
+def load_error() -> Optional[str]:
+    """Why the codec is unavailable (None while it is, or before a load)."""
+    return _ERROR
 
 
 def lz4_compress(data: bytes) -> bytes:

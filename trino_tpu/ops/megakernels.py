@@ -41,10 +41,15 @@ bit-identical to the serial op-chain oracle BY CONSTRUCTION:
   serial formulas, inside the kernel;
 - the fused dest re-traces repartition._partition_dest.
 
-Hardware status: the interpret path IS the contract tier-1 enforces; the
-Mosaic lowering of the build loop (SMEM scalar stores) and the probe gathers
-belongs to the ROADMAP item-2 hardware-verified ladder, like every BENCH
-number since round 5 (CPU-labeled). Unsupported shapes (nested layouts,
+Hardware status: the interpret path IS the contract tier-1 enforces. On a
+TPU v5e (PR 21, jax 0.9.0) Mosaic refuses the first fused launch of a
+join->aggregate query before it looks at the body: "The Pallas TPU lowering
+currently supports only blocks of rank >= 1 ... outputs[5] ... has block
+shape ()" — ``_mega_call`` hands scalar leaves over as rank-0 refs. With
+``pallas_fusion`` on, that error is the query's error on the chip (no serial
+fall-back for a compiled launch); what comes after it — whole-array int64
+refs, the build loop's scalar stores, the probe gathers — Mosaic has not been
+shown yet (ROADMAP S4/D3). Unsupported shapes (nested layouts,
 non-equi residuals, FULL joins, multi-lane keys, sort-path aggregations)
 fall back to the op-chain path per-fragment with a labeled
 ``trino_tpu_pallas_fallbacks_total`` tick — see ARCHITECTURE.md "Megakernel

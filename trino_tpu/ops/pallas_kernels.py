@@ -2,11 +2,12 @@
 
 Reference blueprint: the role of gen/columnar (compiled columnar filters,
 SURVEY.md §2.4) taken below XLA: a fused scan→filter→aggregate pass written
-against the TPU VPU directly. XLA's own fusion already reaches the HBM roofline
-for Q6-shaped pipelines (BASELINE.md), so the value here is (a) proving the
-Pallas path end-to-end for round-2 kernels (join build/probe, grouped
-aggregation) where XLA's lowering is weaker, and (b) exact integer accumulation
-without int64 emulation.
+against the TPU VPU directly. XLA fuses Q6-shaped pipelines by itself, so the
+value here is (a) proving the Pallas path end-to-end for round-2 kernels (join
+build/probe, grouped aggregation) where XLA's lowering is weaker, and (b) exact
+integer accumulation without int64 emulation. All three kernels compile
+through Mosaic on a TPU v5e at SF1 shapes and equal numpy (PR 21); none has
+been timed against XLA on today's code (ROADMAP S4/D4).
 
 Exactness trick: the VPU has no int64, so block sums of int32 products are
 accumulated as two int32 lanes — sum(x & 0xFFFF) and sum(x >> 16) — recombined
@@ -113,7 +114,7 @@ def q6_fused(
     # runs under interpret mode INSIDE an enclosing jit (the engine's
     # direct-aggregate program), lowering happens after this scope exits and
     # weak-typed literals would re-promote to int64 against int32 operands
-    with jax.experimental.enable_x64(False):
+    with jax.enable_x64(False):
         partials = pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct((grid, 8, 128), jnp.int32),
@@ -202,7 +203,7 @@ def _grouped_limb_sums(gid, weight, vals32, num_groups, nlimbs, interpret):
     G_pad = max(8, ((num_groups + 7) // 8) * 8)
     kernel = partial(_gsum_kernel, G_pad=G_pad, nlimbs=nlimbs)
     block_in = pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0))
-    with jax.experimental.enable_x64(False):
+    with jax.enable_x64(False):
         partials = pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct((grid, G_pad, 128), jnp.int32),

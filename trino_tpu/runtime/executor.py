@@ -824,7 +824,8 @@ class PlanExecutor:
         (RIGHT-swapped) join: ops/compiler.plan_megakernel recognizes the
         shape, ops/megakernels runs build+probe+expand (+ the repartition
         dest) as Pallas launches. Returns the fused Relation, or None after
-        a labeled fallback tick — the caller runs the serial op-chain."""
+        a labeled fallback tick — the caller runs the serial op-chain. A
+        compiled (non-interpret) launch that fails raises instead."""
         from ..ops import megakernels as MK
 
         spec = self._fused_join_spec(kind, node, probe, build, pkeys, bkeys)
@@ -848,8 +849,11 @@ class PlanExecutor:
                 out_capacity, out_symbols, None, None, epi_spec, interp,
             )
         except Exception:
-            # an unexpected kernel failure must degrade to the serial path,
-            # never fail the query — the counter + flight instant surface it
+            if not interp:
+                # a compiled launch that Mosaic refuses is the query's
+                # error, with the compiler's message: a kernel must not
+                # quietly give way to its reference on the chip
+                raise
             MK.on_pallas_fallback("kernel_error")
             return None
         if dest is not None:
@@ -1042,6 +1046,8 @@ class PlanExecutor:
                     num_groups, epi_spec, interp,
                 )
         except Exception:
+            if not interp:
+                raise  # as in _try_fused_join
             MK.on_pallas_fallback("kernel_error")
             return serial_finish()
         if dest is not None:
@@ -1541,8 +1547,8 @@ class PlanExecutor:
         launch where the serial pair books two). Shared by the serial walk
         and the vector serving tier's per-lane fallback
         (runtime/device_scheduler.py), so both paths compute the same bytes.
-        A runtime failure falls back to the serial Project + TopN pair with
-        a labeled counter tick; the query still answers."""
+        Off the chip a runtime failure falls back to the serial Project +
+        TopN pair with a labeled counter tick; on the chip it is raised."""
         from ..ops import tensor as T
         from ..planner.plan import ProjectNode as _PN
 
@@ -1560,6 +1566,8 @@ class PlanExecutor:
             self._maybe_sample_ann_recall(node, out)
             return out
         except Exception:
+            if jax.default_backend() == "tpu":
+                raise  # on the chip a fused program that fails is an error
             T.on_topk_fallback("kernel_error")
             proj = self._project_relation(
                 _PN(source=node.source, assignments=node.assignments), rel

@@ -35,10 +35,10 @@ Three pieces:
       flops 1.2G · hbm 890MB · arith 1.3 flop/B → memory-bound,
       72% of roofline @ cpu
 
-  Peak FLOP/s / bytes/s per platform come from ``$TRINO_TPU_ROOFLINE_PEAKS``
-  (``"cpu=5e10:2e10,tpu=1.97e14:8.19e11"``); the built-in defaults are
-  conservative placeholders, labeled as such in the output of
-  :func:`roofline_peaks`.
+  Peak FLOP/s / bytes/s come from the :data:`PEAKS` table keyed by the
+  device_kind JAX reports, or from ``$TRINO_TPU_ROOFLINE_PEAKS``
+  (``"cpu=5e10:2e10,TPU v5 lite=1.97e14:8.19e11"``); a device kind in
+  neither is an error (:func:`roofline_peaks`).
 
 Availability degrades, never raises: ``cost_analysis``/``memory_analysis``
 vary by backend and jax version, Pallas interpret-mode programs may expose
@@ -76,29 +76,30 @@ from .. import knobs
 # roofline peaks
 # --------------------------------------------------------------------------- #
 
-# conservative single-core/host-class placeholders (FLOP/s, bytes/s) — real
-# deployments pin measured peaks via $TRINO_TPU_ROOFLINE_PEAKS; the point of
-# shipping defaults is that the CLASSIFICATION (memory- vs compute-bound) is
-# driven by arithmetic intensity vs the ridge point, which is robust to the
-# absolute numbers being placeholder-grade
-DEFAULT_PEAKS: Dict[str, Tuple[float, float]] = {
+# (peak FLOP/s, peak bytes/s) keyed by the device_kind JAX reports, each with
+# its source. A device that is not here is an error, not a default: a roofline
+# share against another chip's peaks is a wrong number.
+PEAKS: Dict[str, Tuple[float, float]] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM
+    "TPU v5 lite": (1.97e14, 8.19e11),
+    # XLA:CPU, the tier-1 test backend: a single-core host-class placeholder.
+    # Only the memory- vs compute-bound CLASSIFICATION is read from it, and
+    # that follows arithmetic intensity against the ridge point.
     "cpu": (5.0e10, 2.0e10),
-    "tpu": (1.97e14, 8.19e11),
-    "gpu": (9.89e13, 2.04e12),
-    "interpreter": (5.0e10, 2.0e10),
 }
 
 ENV_PEAKS = "TRINO_TPU_ROOFLINE_PEAKS"
 
 
-def roofline_peaks(platform: str) -> Tuple[float, float, str]:
-    """(peak_flops_per_sec, peak_bytes_per_sec, provenance) for a platform.
+def roofline_peaks(device_kind: str) -> Tuple[float, float, str]:
+    """(peak_flops_per_sec, peak_bytes_per_sec, provenance) for a device kind.
 
-    ``$TRINO_TPU_ROOFLINE_PEAKS`` format: ``platform=FLOPS:BYTES`` pairs,
-    comma-separated — ``"cpu=5e10:2e10,tpu=1.97e14:8.19e11"``. Unparseable
-    entries are ignored (a typo'd knob degrades to defaults, it does not
-    take down EXPLAIN). Provenance is ``"env"`` or ``"default"`` so the
-    output can say whether the pct-of-roofline is against a measured peak.
+    ``$TRINO_TPU_ROOFLINE_PEAKS`` format: ``device_kind=FLOPS:BYTES`` pairs,
+    comma-separated — ``"cpu=5e10:2e10,TPU v5 lite=1.97e14:8.19e11"``.
+    Unparseable entries are ignored (a typo'd knob does not take down
+    EXPLAIN). Provenance is ``"env"`` or ``"table"`` so the output can say
+    whether the pct-of-roofline is against a measured peak. A device kind in
+    neither raises LookupError.
     """
     spec = knobs.env_str(ENV_PEAKS) or ""
     for entry in spec.split(","):
@@ -106,7 +107,7 @@ def roofline_peaks(platform: str) -> Tuple[float, float, str]:
         if not entry or "=" not in entry:
             continue
         name, _, vals = entry.partition("=")
-        if name.strip().lower() != platform.lower():
+        if name.strip().lower() != device_kind.lower():
             continue
         fl, _, by = vals.partition(":")
         try:
@@ -115,15 +116,20 @@ def roofline_peaks(platform: str) -> Tuple[float, float, str]:
             continue
         if pf > 0 and pb > 0:
             return pf, pb, "env"
-    pf, pb = DEFAULT_PEAKS.get(platform.lower(), DEFAULT_PEAKS["cpu"])
-    return pf, pb, "default"
+    for name, (pf, pb) in PEAKS.items():
+        if name.lower() == device_kind.lower():
+            return pf, pb, "table"
+    raise LookupError(
+        f"no peaks for device kind {device_kind!r}: add it to "
+        f"kernelcost.PEAKS with its source, or set ${ENV_PEAKS}"
+    )
 
 
 def classify(
     flops: Optional[float],
     bytes_accessed: Optional[float],
     device_secs: Optional[float] = None,
-    platform: Optional[str] = None,
+    device_kind: Optional[str] = None,
 ) -> Optional[dict]:
     """Roofline verdict for one program (or one operator's aggregate).
 
@@ -135,8 +141,8 @@ def classify(
     """
     if not flops and not bytes_accessed:
         return None
-    platform = platform or jax.default_backend()
-    peak_flops, peak_bw, provenance = roofline_peaks(platform)
+    device_kind = device_kind or jax.devices()[0].device_kind
+    peak_flops, peak_bw, provenance = roofline_peaks(device_kind)
     flops = float(flops or 0.0)
     bytes_accessed = float(bytes_accessed or 0.0)
     ai = flops / bytes_accessed if bytes_accessed > 0 else None
@@ -152,7 +158,7 @@ def classify(
     if device_secs and device_secs > 0 and attainable > 0 and flops > 0:
         pct = min((flops / device_secs) / attainable, 1.0)
     return {
-        "platform": platform,
+        "device_kind": device_kind,
         "arithmetic_intensity": ai,
         "classification": bound,
         "attainable_flops_per_sec": attainable,
@@ -180,11 +186,11 @@ def render_roofline(
     bytes_accessed: Optional[float],
     peak_hbm_bytes: Optional[float] = None,
     device_secs: Optional[float] = None,
-    platform: Optional[str] = None,
+    device_kind: Optional[str] = None,
 ) -> Optional[str]:
     """The EXPLAIN ANALYZE VERBOSE one-liner. ``None`` when unclassifiable
     (the caller renders ``cost_unavailable`` instead)."""
-    verdict = classify(flops, bytes_accessed, device_secs, platform)
+    verdict = classify(flops, bytes_accessed, device_secs, device_kind)
     if verdict is None:
         return None
     parts = []
@@ -200,7 +206,7 @@ def render_roofline(
     tail = verdict["classification"]
     if verdict["roofline_pct"] is not None:
         tail += f", {verdict['roofline_pct'] * 100.0:.0f}% of roofline"
-    tail += f" @ {verdict['platform']}"
+    tail += f" @ {verdict['device_kind']}"
     return " · ".join(parts) + " → " + tail
 
 
@@ -473,6 +479,7 @@ class CostJit:
             "label": self.label,
             "key": key,
             "platform": platform,
+            "device_kind": jax.devices()[0].device_kind,
             "status": "ok",
             "source": "computed",
             "flops": None,
@@ -588,7 +595,7 @@ _federated: Dict[str, Tuple[float, List[dict]]] = {}
 def _ledger_append(scope: _Scope, record: dict) -> None:
     verdict = classify(
         record.get("flops"), record.get("bytes_accessed"),
-        platform=record.get("platform"),
+        device_kind=record.get("device_kind"),
     ) or {}
     row = {
         "ts": time.time(),

@@ -573,6 +573,18 @@ def _on_jax_duration(event: str, duration: float, **kwargs) -> None:
         pass
 
 
+def _on_jax_event(event: str, **kwargs) -> None:
+    # a backend_compile_duration event also fires when the executable came
+    # from the persistent cache; this counter tells the two apart
+    if event == "/jax/compilation_cache/cache_hits":
+        from .metrics import REGISTRY
+
+        REGISTRY.counter(
+            "trino_tpu_xla_persistent_cache_hits_total",
+            help="XLA backend compiles answered by the persistent cache",
+        ).inc()
+
+
 def _ensure_jax_listener() -> None:
     global _listener_registered
     if _listener_registered:
@@ -586,6 +598,7 @@ def _ensure_jax_listener() -> None:
             jax.monitoring.register_event_duration_secs_listener(
                 _on_jax_duration
             )
+            jax.monitoring.register_event_listener(_on_jax_event)
         except Exception:
             pass  # plane degrades to no compile attribution, never fails
         _listener_registered = True

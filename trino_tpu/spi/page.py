@@ -299,9 +299,9 @@ class Column:
     def decode(self, active: Optional[np.ndarray] = None) -> np.ndarray:
         """Host materialization into python-visible values (objects), nulls as None.
 
-        Note: decimals decode via float division, exact only up to 2**53 of scaled
-        magnitude — fine for result display/tests; a lossless Decimal path can be
-        added at the client-protocol layer when needed.
+        Note: short decimals decode to float where float division is exact
+        (every scaled magnitude of the column below 2**53) and to Decimal
+        where it is not; long decimals always decode to Decimal.
         """
         from .types import ArrayType, MapType, RowType
 
@@ -386,9 +386,19 @@ class Column:
             return out
         if isinstance(self.type, DecimalType) and self.type.scale > 0:
             out = np.empty(len(data), dtype=object)
-            scale = 10 ** self.type.scale
+            if np.abs(data[valid].astype(np.float64)).max(initial=0.0) >= 2.0**53:
+                # float division would round here (a TPC-H q1 sum_charge
+                # passes 2**53 at SF1), so the column decodes as long
+                # decimals do
+                import decimal as _d
+
+                sc = self.type.scale
+                convert = lambda x: _d.Decimal(x).scaleb(-sc)  # noqa: E731
+            else:
+                scale = 10 ** self.type.scale
+                convert = lambda x: x / scale  # noqa: E731
             for i, (x, ok) in enumerate(zip(data.tolist(), valid.tolist())):
-                out[i] = (x / scale) if ok else None
+                out[i] = convert(x) if ok else None
             return out
         if self.type.name == "date":
             import datetime
