@@ -2820,7 +2820,7 @@ def measure_fleet_ab(scale: float = 0.0005, clients: int = 100,
                             qs.get("deviceBusyTime") or 0.0
                         )
                         attr["compile"] += float(
-                            qs.get("analysisTime") or 0.0
+                            qs.get("compileTime") or 0.0
                         )
                     except Exception:  # noqa: BLE001 — counted, not fatal
                         attr["stats_missing"] += 1

@@ -138,7 +138,7 @@ class TestBoundedRing:
 
 class TestProtocolPhaseSpans:
     """proto_* spans across a REAL coordinator + worker request: every
-    begun phase span ends (B/E pairing), on both sides of the wire."""
+    phase is one whole X event, on both sides of the wire."""
 
     def test_phase_span_rejects_unknown_phase(self):
         with pytest.raises(ValueError):
@@ -206,24 +206,25 @@ class TestProtocolPhaseSpans:
 
         assert validate_chrome_trace(trace) == []
         events = trace.get("traceEvents", [])
-        begins: dict = {}
-        ends: dict = {}
+        # a phase is one X event, written when its span has finished: an
+        # export never holds half of one
+        seen = set()
         for e in events:
             name = e.get("name", "")
-            if not name.startswith("proto_"):
-                continue
-            if e.get("ph") == "B":
-                begins[name] = begins.get(name, 0) + 1
-            elif e.get("ph") == "E":
-                ends[name] = ends.get(name, 0) + 1
-        assert begins == ends, f"unpaired protocol spans: {begins} vs {ends}"
-        seen = set(begins)
+            if name.startswith("proto_"):
+                assert e.get("ph") == "X", f"{name} is not a whole event: {e}"
+                assert e.get("cat") == "protocol"
+                seen.add(name)
         # coordinator client path + worker internal path + query manager
         for phase in ("accept", "auth", "parse", "verify", "dispatch",
-                      "admit", "execute", "result_stream"):
+                      "queue", "admit", "result_stream"):
             assert f"proto_{phase}" in seen, f"missing proto_{phase}: {seen}"
         for name in seen:
             assert name[len("proto_"):] in PROTOCOL_PHASES
+        # the runner's own boundary, from the same span source
+        assert any(
+            e.get("name") == "execution" and e.get("ph") == "X" for e in events
+        )
 
     def test_queue_phase_and_wait_split_with_resource_groups(self):
         from trino_tpu.runtime.query_manager import QueryManager
@@ -251,7 +252,7 @@ class TestProtocolPhaseSpans:
             RECORDER.disable()
         assert validate_chrome_trace(trace) == []
         names = {e["name"] for e in trace["traceEvents"]
-                 if e.get("ph") == "B"}
+                 if e.get("ph") == "X"}
         assert "proto_queue" in names
         for q in qs:
             qq = qm.get(q.query_id)

@@ -84,7 +84,18 @@ class TestTracing:
             ).read()
         )
         names = [s["name"] for s in info["spans"]]
-        assert names == ["query", "planner", "optimizer", "execution"]
+        # the trace id is the query id; the root opens at submit
+        assert info["traceId"] == res.query_id
+        assert names[0] == "statement"
+        in_root = [
+            s["name"] for s in info["spans"]
+            if s["parentSpanId"] == info["spans"][0]["spanId"]
+            and s["name"] != "result_stream"
+        ]
+        assert in_root == [
+            "queue", "admit", "parse", "planner", "optimizer", "execution",
+            "drain", "encode",
+        ]
 
 
 class TestSpool:

@@ -1709,8 +1709,9 @@ def run_hostprof_smoke(scale: float = 0.001) -> List[str]:
     ``host_profile`` session property must scope the sampling profiler to
     the statement (refcounted, off afterwards), the sampler must capture
     collapsed stacks keyed by thread NAME, the speedscope export must pass
-    its schema validator, protocol-phase spans (proto_admit/proto_execute
-    through the QueryManager) must pair in a valid Perfetto trace, the
+    its schema validator, the QueryManager's protocol phases (proto_queue,
+    proto_admit) and the runner's `execution` must be whole X events of a
+    valid Perfetto trace (the tracer's finished spans), the
     ``system.runtime.host_profile`` table must serve on-schema rows, the
     ``trino_tpu_host_threads{state=}`` gauges must export, and the
     GIL-contention probe must produce a numeric jitter summary.
@@ -1767,10 +1768,10 @@ def run_hostprof_smoke(scale: float = 0.001) -> List[str]:
     problems += [f"speedscope: {p}" for p in validate_speedscope(doc)]
     problems += [f"trace: {p}" for p in validate_chrome_trace(trace)]
     events = trace.get("traceEvents", [])
-    begun = {e.get("name") for e in events if e.get("ph") == "B"}
-    for want in ("proto_admit", "proto_execute"):
-        if want not in begun:
-            problems.append(f"no paired {want} protocol-phase span recorded")
+    whole = {e.get("name") for e in events if e.get("ph") == "X"}
+    for want in ("proto_queue", "proto_admit", "execution"):
+        if want not in whole:
+            problems.append(f"no finished {want} span recorded")
 
     rows = runner.execute(
         "SELECT thread, stack, samples, share "
@@ -1812,7 +1813,7 @@ def run_fleet_smoke(scale: float = 0.001) -> List[str]:
     reassign ONLY its hash range (survivor-owned keys keep their owner), a
     follower must serve a status-board read for the dead owner's query
     DURING the failover window, the dead owner's users must be served by a
-    survivor afterwards, proto_route spans must pair in a valid Perfetto
+    survivor afterwards, proto_route spans must be whole in a valid Perfetto
     trace with a fleet_reassign span for the departure, and the fleet
     counters must pass the shared HELP lint.
 
@@ -1979,8 +1980,9 @@ def run_fleet_smoke(scale: float = 0.001) -> List[str]:
     problems += [f"trace: {p}" for p in validate_chrome_trace(trace)]
     events = trace.get("traceEvents", [])
     begun = {e.get("name") for e in events if e.get("ph") == "B"}
-    if "proto_route" not in begun:
-        problems.append("no paired proto_route span recorded")
+    whole = {e.get("name") for e in events if e.get("ph") == "X"}
+    if "proto_route" not in whole:
+        problems.append("no finished proto_route span recorded")
     if "fleet_reassign" not in begun:
         problems.append("no fleet_reassign span recorded for the departure")
     problems += _registry_help_problems(

@@ -47,7 +47,9 @@ from ..planner.plan import LogicalPlan, OutputNode, PlanNode, TableScanNode, vis
 from ..runtime import kernelcost
 from ..runtime.executor import Relation, _concat_pages, _round_capacity
 from ..runtime.local import QueryResult
+from ..runtime.memory import page_bytes
 from ..runtime.traced import _TracedExecutor, is_traceable
+from ..runtime.tracing import TRACER
 from ..spi.page import Column, Page
 from ..sql import parse_statement
 from . import exchange
@@ -244,11 +246,24 @@ class MeshQueryRunner:
     # ---------------------------------------------------------------- execution
 
     def execute(self, sql: str) -> QueryResult:
-        subplan = self.plan_distributed(sql)
-        names, page = self.execute_subplan(subplan)
-        return QueryResult(names, page.to_pylist())
+        with TRACER.statement(sql):
+            subplan = self.plan_distributed(sql)
+            names, page = self.execute_subplan(subplan)
+            return QueryResult(names, self.gather(page))
+
+    @staticmethod
+    def gather(page: Page) -> list:
+        """The answer's rows on the host (span `mesh:gather`): the wait for
+        the mesh program, the copy from device 0 and the row encoding."""
+        with TRACER.span("mesh:gather") as span:
+            rows = page.to_pylist()
+            span.attributes["rows"] = len(rows)
+        return rows
 
     def execute_subplan(self, subplan: SubPlan) -> Tuple[List[str], Page]:
+        """Spans `mesh:load_scan`, `mesh:shard` (per scan) and `mesh:program`
+        (per attempt) under the caller's statement root; `gather` adds
+        `mesh:gather`."""
         self._check_lowerable(subplan)
         scan_specs, scan_counts = self._shard_scans(subplan)
         root = subplan.root_fragment.root
@@ -257,8 +272,6 @@ class MeshQueryRunner:
         join_factor = float(self.session.get("mesh_join_capacity_factor") or 1.0)
         bucket_caps = self._initial_bucket_caps(subplan, scan_specs)
         flat_pages = [s.page for s in scan_specs]
-
-        import time as _time
 
         from ..runtime import observability as obs
 
@@ -282,17 +295,18 @@ class MeshQueryRunner:
                 self._program_cache[cache_key] = program
             elif collector is not None:
                 collector.add_count("compile_cache_hits")
-            t0 = _time.perf_counter()
-            with obs.RECORDER.span(
-                "mesh_program", "mesh", attempt=attempt,
+            # the one shard_map program and the read of its overflow flag
+            # (the flight recorder keeps the span under the category `mesh`)
+            with TRACER.span(
+                "mesh:program", cat="mesh", attempt=attempt,
                 join_factor=join_factor, cached=cached,
-            ), obs.compile_window() as cw:
+            ) as ran, obs.compile_window() as cw:
                 out_page, overflow = program(*flat_pages)
                 done = int(overflow) == 0
             if collector is not None:
                 collector.add_time(
                     "device_busy_secs",
-                    max(_time.perf_counter() - t0 - cw.seconds, 0.0),
+                    max(ran.duration_secs - cw.seconds, 0.0),
                 )
             if done:
                 break
@@ -381,12 +395,20 @@ class MeshQueryRunner:
             visit_plan(frag.root, collect)
             scan_counts[frag.fragment_id] = len(scans)
             for node in scans:
-                page = self._load_scan(node)
+                with TRACER.span(
+                    "mesh:load_scan", table=str(node.table.schema_table)
+                ) as loaded:
+                    page = self._load_scan(node)
+                    # the splits' pages concatenated on device 0
+                    loaded.attributes["rows"] = page.capacity
+                    loaded.attributes["bytes"] = page_bytes(page)
                 per_shard = _round_capacity(
                     max(math.ceil(page.capacity / self.n), 1), base=8
                 )
-                padded = _pad_page(page, per_shard * self.n)
-                sharded = jax.device_put(padded, sharding)
+                with TRACER.span("mesh:shard") as sharding_span:
+                    padded = _pad_page(page, per_shard * self.n)
+                    sharded = jax.device_put(padded, sharding)
+                    sharding_span.attributes["h2d_bytes"] = page_bytes(padded)
                 symbols = tuple(s for s, _ in node.assignments)
                 scan_specs.append(_ScanSpec(frag.fragment_id, sharded, symbols))
         return scan_specs, scan_counts

@@ -293,9 +293,13 @@ class DistributedQueryRunner:
     def execute(self, sql: str) -> QueryResult:
         from ..runtime.failure import execute_with_retry
 
-        return execute_with_retry(
-            self._execute_once, sql, retry_policy=str(self.session.get("retry_policy"))
-        )
+        # the statement's root (the QueryManager's where there is one): the
+        # mesh tier's spans and whatever a fragment executor opens hang here
+        with TRACER.statement(sql):
+            return execute_with_retry(
+                self._execute_once, sql,
+                retry_policy=str(self.session.get("retry_policy")),
+            )
 
     def _feedback_enabled(self) -> bool:
         try:
@@ -381,7 +385,8 @@ class DistributedQueryRunner:
                 self.last_tier = "ici"
                 self.last_tier_reason = None
                 return QueryResult(
-                    names, page.to_pylist(), [c.type for c in page.columns]
+                    names, self._mesh_runner.gather(page),
+                    [c.type for c in page.columns],
                 )
             except MeshLoweringError as e:
                 # observability for the tier decision (VERDICT r2: nothing

@@ -70,6 +70,8 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
+from .tracing import TRACER
+
 # how long a shared-scan entry may serve after its flight completed: long
 # enough for back-to-back dashboard arrivals to subsume, short enough that
 # lingering device pages cannot pile up (entries are also LRU-bounded)
@@ -143,6 +145,9 @@ def on_program_launch(n: int = 1) -> None:
     if c is None:
         c = _programs_counter = _counter("trino_tpu_device_programs_total")
     c.inc(n)
+    # the same tick on the span that launched (the operator's `op:`): a
+    # statement's `launches` is the sum over its tree
+    TRACER.count("launches", n)
 
 
 def program_launches() -> float:

@@ -34,6 +34,7 @@ from . import kernelcost
 from .device_scheduler import on_program_launch
 from .failure import FailureInjector
 from .observability import on_spill_read, on_spill_write
+from .tracing import OP_PREFIX, SYNC_PREFIX, TRACER
 from ..ops import kernels as K
 from ..ops.compiler import CVal, ColumnLayout, CompileError, compile_expression
 from ..spi.connector import Split
@@ -193,6 +194,21 @@ class _KeyView:
         return self._cols[symbol]
 
 
+def _sync_int(x, site: str) -> int:
+    """The device-to-host read of one integer on the operator path: the host
+    waits here for everything dispatched before it. One span `sync:<site>`
+    under the operator that waits (runtime/tracing.py); the statement's
+    `host_syncs` counts them."""
+    with TRACER.span(SYNC_PREFIX + site) as span:
+        value = span.attributes["value"] = int(x)
+    return value
+
+
+def _live_rows(active, site: str) -> int:
+    """Rows a page holds, read back to the host (a sync: `_sync_int`)."""
+    return _sync_int(jnp.sum(active.astype(jnp.int32)), site)
+
+
 @dataclass
 class OperatorStats:
     """Per-plan-node execution stats (ref: operator/OperatorStats.java — the
@@ -225,7 +241,7 @@ class PlanExecutor:
         """Join output capacity: host-sync the exact emitted row count (the
         operator-at-a-time model; traced executors override with a static
         bound + overflow accounting)."""
-        total = int(jnp.sum(emit))
+        total = _sync_int(jnp.sum(emit), "join_capacity")
         return _round_capacity(max(total, 1))
 
     def __init__(
@@ -311,7 +327,7 @@ class PlanExecutor:
                 # only this node's output (stats for EXPLAIN ANALYZE, memory
                 # accounting, actuals for the feedback plane)
                 if self.collect_stats:
-                    rows = int(jnp.sum(rel.page.active.astype(jnp.int32)))
+                    rows = _live_rows(rel.page.active, "stats")
                     self.stats[id(node)] = OperatorStats(
                         node=node, wall_secs=0.0, output_rows=rows,
                         output_capacity=rel.capacity, device_secs=0.0,
@@ -346,6 +362,13 @@ class PlanExecutor:
         return self._eval_node(node)
 
     def _eval_node(self, node: PlanNode) -> Relation:
+        # one span per operator, under `execution` or the operator that
+        # consumes it: its `launches`, `sync:` and `compact` children land
+        # on it; the flight recorder keeps it under the category `operator`
+        with TRACER.span(OP_PREFIX + type(node).__name__, cat="operator"):
+            return self._eval_node_spanned(node)
+
+    def _eval_node_spanned(self, node: PlanNode) -> Relation:
         method = getattr(self, "_exec_" + type(node).__name__, None)
         if method is None:
             raise ExecutionError(f"no executor for {type(node).__name__}")
@@ -387,19 +410,18 @@ class PlanExecutor:
             return rel
         import time as _time
 
-        from .observability import RECORDER, compile_window
+        from .observability import compile_window
 
         t0 = _time.perf_counter()
-        with RECORDER.span(type(node).__name__, "operator"):
-            with compile_window() as cw:
-                with self._kernel_cost_scope(node):
-                    rel = method(node)
-            t1 = _time.perf_counter()
-            # sync fence: exact device/host attribution needs the drain
-            # isolated from the next dispatch (the opt-in cost of stats mode)
-            jax.block_until_ready(rel.page.active)
+        with compile_window() as cw:
+            with self._kernel_cost_scope(node):
+                rel = method(node)
+        t1 = _time.perf_counter()
+        # sync fence: exact device/host attribution needs the drain
+        # isolated from the next dispatch (the opt-in cost of stats mode)
+        jax.block_until_ready(rel.page.active)
         t2 = _time.perf_counter()
-        rows = int(jnp.sum(rel.page.active.astype(jnp.int32)))
+        rows = _live_rows(rel.page.active, "stats")
         self.stats[id(node)] = OperatorStats(
             node=node,
             wall_secs=t2 - t0,
@@ -597,7 +619,7 @@ class PlanExecutor:
             for sp in splits:
                 p = provider.create_page_source(sp, col_indexes)
                 pages.append(p)
-                counts.append(int(jnp.sum(p.active.astype(jnp.int32))))
+                counts.append(_live_rows(p.active, "scan_limit"))
                 rows += counts[-1]
                 if rows >= node.limit:
                     break
@@ -612,9 +634,7 @@ class PlanExecutor:
         sink = split_event_sink()
         if sink is not None:
             if counts is None:
-                counts = [
-                    int(jnp.sum(p.active.astype(jnp.int32))) for p in pages
-                ]
+                counts = [_live_rows(p.active, "split_event") for p in pages]
             for sp, p, n in zip(splits, pages, counts):
                 sink({
                     "catalog": handle.catalog,
@@ -1037,7 +1057,7 @@ class PlanExecutor:
                     )
                 # the group-count host sync the serial sort path performs
                 out_cap = min(
-                    _round_capacity(max(int(num_groups), 1), base=16),
+                    _round_capacity(max(_sync_int(num_groups, "num_groups"), 1), base=16),
                     max(out_capacity, 16),
                 )
                 page, dest = MK.aggregate_phase(
@@ -1348,7 +1368,7 @@ class PlanExecutor:
         )
         if not bsyms:
             return
-        if int(jnp.sum(build.page.active.astype(jnp.int32))) != 1:
+        if _live_rows(build.page.active, "vector_broadcast") != 1:
             return
         out.page._vector_broadcast = bsyms
 
@@ -1397,7 +1417,7 @@ class PlanExecutor:
             pid = partition_ids(hash_key_columns(cols), nparts)
             for p in range(nparts):
                 mask = rel.page.active & (pid == p)
-                n = int(jnp.sum(mask.astype(jnp.int32)))
+                n = _live_rows(mask, "spill_partition")
                 part = _jit_compact(
                     _round_capacity(max(n, 1)), Page(rel.page.columns, mask)
                 )
@@ -1482,7 +1502,7 @@ class PlanExecutor:
             if _is_str(bc.type):
                 continue  # code spaces differ across dictionaries; skip strings
             w = build.page.active & bc.valid
-            n = int(jnp.sum(w.astype(jnp.int32)))
+            n = _live_rows(w, "dynamic_filter")
             if n == 0:
                 continue
             info_min = jnp.where(w, bc.data, bc.data.max()).min()
@@ -1717,7 +1737,7 @@ class PlanExecutor:
 
     def _exec_EnforceSingleRowNode(self, node: EnforceSingleRowNode) -> Relation:
         rel = self.eval(node.source)
-        n = int(jnp.sum(rel.page.active.astype(jnp.int32)))
+        n = _live_rows(rel.page.active, "single_row")
         if n > 1:
             raise ExecutionError("scalar subquery returned more than one row")
         if n == 1:
@@ -1783,11 +1803,16 @@ def _maybe_compact(rel: Relation, density: int = 4, min_cap: int = 8192) -> Rela
     cap = rel.capacity
     if cap <= min_cap:
         return rel
-    n = int(jnp.sum(rel.page.active.astype(jnp.int32)))
+    n = _live_rows(rel.page.active, "compact")
     if n * density > cap:
         return rel
     new_cap = _round_capacity(max(n, 1))
-    page = _jit_compact(new_cap, rel.page)
+    # what is sorted (capacity_in rows of `columns`) against what is kept
+    with TRACER.span(
+        "compact", capacity_in=cap, live_rows=n, capacity_out=new_cap,
+        columns=len(rel.page.columns),
+    ):
+        page = _jit_compact(new_cap, rel.page)
     # compaction is a stable partition by activity — order preserved
     return Relation(page, rel.symbols, rel.sorted_by)
 
@@ -1933,14 +1958,14 @@ def aggregate_relation(
             p, ng, n_grp, viol = _jit_presorted_group(
                 node.group_keys, needed, rel.symbols, rel.page
             )
-            if not bool(viol):
+            if not _sync_int(viol, "presorted_check"):
                 sorted_page, new_group, num_groups = p, ng, n_grp
         if sorted_page is None:
             sorted_page, new_group, num_groups = _jit_group_sort(
                 node.group_keys, needed, rel.symbols, rel.page
             )
         out_cap = min(
-            _round_capacity(max(int(num_groups), 1), base=16), max(rel.capacity, 16)
+            _round_capacity(max(_sync_int(num_groups, "num_groups"), 1), base=16), max(rel.capacity, 16)
         )
     else:
         # global aggregation: no sort at all — select the needed columns
@@ -1953,9 +1978,9 @@ def aggregate_relation(
     agg_w = 0
     if any(a.function in _LANE_AGGS for _, a in node.aggregations):
         if node.group_keys:
-            agg_w = int(_jit_max_run(new_group, sorted_page.active))
+            agg_w = _sync_int(_jit_max_run(new_group, sorted_page.active), "lane_width")
         else:
-            agg_w = int(jnp.sum(sorted_page.active.astype(jnp.int32)))
+            agg_w = _live_rows(sorted_page.active, "lane_width")
         agg_w = _round_capacity(max(agg_w, 1), base=8)
     page = _jit_aggregate(
         node.group_keys,
@@ -2015,8 +2040,8 @@ _RESORT_AGGS = frozenset(
 
 def _force_dense(rel: Relation) -> Relation:
     """Compact unless active rows already form a dense prefix."""
-    n = int(jnp.sum(rel.page.active.astype(jnp.int32)))
-    if n == rel.capacity or bool(jnp.all(rel.page.active[:n])):
+    n = _live_rows(rel.page.active, "force_dense")
+    if n == rel.capacity or _sync_int(jnp.all(rel.page.active[:n]), "force_dense"):
         return rel
     page = _jit_compact(_round_capacity(max(n, 1)), rel.page)
     return Relation(page, rel.symbols, rel.sorted_by)

@@ -47,6 +47,7 @@ import sys
 import threading
 import time
 from collections import deque
+from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
 
 from .. import knobs
@@ -70,21 +71,35 @@ _WAIT_LEAVES = frozenset({
 # ownership hashing + non-owner forwarding cost is attributed, not hidden
 PROTOCOL_PHASES = (
     "accept", "auth", "verify", "parse", "route", "proxy", "queue",
-    "admit", "execute", "result_stream", "dispatch",
+    "admit", "result_stream", "dispatch",
 )
 
 
 def phase_span(recorder, phase: str, **args):
     """The protocol-phase span: ``with phase_span(RECORDER, "auth"): ...``.
 
-    One naming scheme (``proto_<phase>``, category ``protocol``) across the
-    coordinator and worker so trace tooling and the hostpath bench can
-    select the host/protocol side of a request with a single prefix. The
-    recorder's own ``enabled`` guard makes this free when recording is off.
-    """
+    A call into ``TRACER`` (runtime/tracing.py), the one span source: the
+    span is named by the phase and lands under the statement's root when one
+    is current on this thread (``queue``, ``admit``, ``result_stream``); a
+    phase before its statement exists (``accept``, ``auth``, the worker's)
+    is kept in no tree. While ``RECORDER`` records, the tracer's sink hands
+    each finished phase to it as one ``proto_<phase>`` X event, category
+    ``protocol``, so trace tooling selects the host/protocol side of a
+    request with a single prefix (``recorder``: a worker's own ring, where
+    it has one). Yields the span's attributes: keys written while it is open
+    ride the event."""
     if phase not in PROTOCOL_PHASES:
         raise ValueError(f"unknown protocol phase: {phase!r}")
-    return recorder.span(f"proto_{phase}", "protocol", **args)
+    return _phase(recorder, phase, args)
+
+
+@contextmanager
+def _phase(recorder, phase: str, args: dict):
+    from .tracing import TRACER
+
+    with TRACER.span(phase, root=False, cat="protocol", **args) as span:
+        span.recorder = recorder
+        yield span.attributes
 
 
 def _interval_secs() -> float:

@@ -177,27 +177,35 @@ class LocalQueryRunner:
         user: Optional[str] = None,
         client: Optional[ClientContext] = None,
     ) -> QueryResult:
+        from .tracing import TRACER
+
         self._user_tls.user = user or self.session.user
         self._ctx_tls.ctx = client  # None -> runner-default embedded context
         self._client.updates.clear()
         try:
-            self.access_control.check_can_execute_query(self._current_user())
-            # warm path tier (c): a textually-identical statement under
-            # identical session state skips parse/analysis/optimization —
-            # the cached optimized plan goes straight to execution (where
-            # the result tier may short-circuit the rest)
-            from .cachestore import CACHES
+            with TRACER.statement(sql):
+                self.access_control.check_can_execute_query(self._current_user())
+                # warm path tier (c): a textually-identical statement under
+                # identical session state skips parse/analysis/optimization —
+                # the cached optimized plan goes straight to execution (where
+                # the result tier may short-circuit the rest)
+                from .cachestore import CACHES
 
-            if CACHES.plan_enabled(self.session) and self._txn is None:
-                hit = CACHES.plan.lookup(
-                    sql, self.session, self.catalogs.cache_nonce
-                )
+                hit = stmt = None
+                with TRACER.span("parse") as parse:
+                    if CACHES.plan_enabled(self.session) and self._txn is None:
+                        hit = CACHES.plan.lookup(
+                            sql, self.session, self.catalogs.cache_nonce
+                        )
+                    if hit is not None:
+                        parse.attributes["cache"] = "plan"
+                    else:
+                        stmt = parse_statement(sql)
                 if hit is not None:
                     return self._execute_query(None, sql, cached=hit)
-            stmt = parse_statement(sql)
-            if isinstance(stmt, t.QueryStatement):
-                return self._execute_query(stmt, sql, plan_sql=sql)
-            return self._dispatch(stmt, sql)
+                if isinstance(stmt, t.QueryStatement):
+                    return self._execute_query(stmt, sql, plan_sql=sql)
+                return self._dispatch(stmt, sql)
         finally:
             self._ctx_tls.ctx = None
 
@@ -694,13 +702,13 @@ class LocalQueryRunner:
             collector = obs.QueryStatsCollector()
             collector.sync_mode = sync
             # span structure mirrors the reference's planning spans
-            # (TracingMetadata: "planner"/"optimizer"/per-stage execution)
+            # (TracingMetadata: "planner"/"optimizer"/per-stage execution),
+            # all children of the statement's root
             cache_tier = None
             rkey = versions = None
             try:
-                with obs.collecting(collector), obs.compile_window(), TRACER.span(
-                    "query", sql=sql[:200]
-                ) as root:
+                with obs.collecting(collector), obs.compile_window(), \
+                        TRACER.statement(sql) as root:
                     if cached is not None:
                         # plan tier hit: parse/analysis/optimization skipped
                         plan, profile = cached
@@ -771,61 +779,57 @@ class LocalQueryRunner:
                             plan_sql, self.session, plan, profile,
                             registry=self.catalogs.cache_nonce,
                         )
-                    with TRACER.span("execution"), obs.RECORDER.span(
-                        "execution", "query", sql=sql[:200]
+                    import jax as _jax
+
+                    executor = PlanExecutor(
+                        plan, self.metadata, self.session, collect_stats=sync
+                    )
+                    if (
+                        CACHES.fragment_enabled(self.session)
+                        and self._txn is None
                     ):
-                        import time as _time
+                        from .cachestore import FragmentBinding
+                        from .statstore import current_query_id
 
-                        import jax as _jax
-
-                        t0 = _time.perf_counter()
-                        executor = PlanExecutor(
-                            plan, self.metadata, self.session, collect_stats=sync
+                        executor.fragment_cache = FragmentBinding(
+                            CACHES.fragment, self.metadata, self.session,
+                            query_id=current_query_id()
+                            or root.trace_id or "",
+                            registry=self.catalogs.cache_nonce,
                         )
-                        if (
-                            CACHES.fragment_enabled(self.session)
-                            and self._txn is None
-                        ):
-                            from .cachestore import FragmentBinding
-                            from .statstore import current_query_id
+                    # device batching plane: route batchable subtrees
+                    # through the scheduler (off by default — attach()
+                    # is a no-op leaving the path byte-identical)
+                    from .device_scheduler import attach as _attach_batching
 
-                            executor.fragment_cache = FragmentBinding(
-                                CACHES.fragment, self.metadata, self.session,
-                                query_id=current_query_id()
-                                or root.trace_id or "",
-                                registry=self.catalogs.cache_nonce,
-                            )
-                        # device batching plane: route batchable subtrees
-                        # through the scheduler (off by default — attach()
-                        # is a no-op leaving the path byte-identical)
-                        from .device_scheduler import attach as _attach_batching
-
-                        _attach_batching(
-                            executor, self.metadata, self.session,
-                            catalogs=self.catalogs,
+                    _attach_batching(
+                        executor, self.metadata, self.session,
+                        catalogs=self.catalogs,
+                    )
+                    # cardinality actuals ride every execution (one async
+                    # row-count scalar per operator; host reads deferred
+                    # past the drain)
+                    try:
+                        executor.collect_actuals = bool(
+                            self.session.get("statistics_feedback")
                         )
-                        # cardinality actuals ride every execution (one async
-                        # row-count scalar per operator; host reads deferred
-                        # past the drain)
-                        try:
-                            executor.collect_actuals = bool(
-                                self.session.get("statistics_feedback")
-                            )
-                        except KeyError:
-                            executor.collect_actuals = True
+                    except KeyError:
+                        executor.collect_actuals = True
+                    # the operators: host work and dispatch, device work
+                    # overlapped (its `op:` and `sync:` children say where)
+                    with TRACER.span("execution", cat="query") as dispatched:
                         names, page = executor.execute()
-                        dispatch_secs = _time.perf_counter() - t0
-                        # drain = waiting on in-flight device work only; row
-                        # conversion below is pure-Python host time and must
-                        # NOT be booked as device time
+                    # drain = waiting on in-flight device work only; row
+                    # conversion below is pure-Python host time and must
+                    # NOT be booked as device time
+                    with TRACER.span("drain") as drained:
                         _jax.block_until_ready(page.active)
-                        drain_secs = (
-                            _time.perf_counter() - t0 - dispatch_secs
-                        )
+                    with TRACER.span("encode") as encoded:
                         result = QueryResult(
                             names, page.to_pylist(),
                             [c.type for c in page.columns],
                         )
+                        encoded.attributes["rows"] = len(result.rows)
                     result.trace_id = root.trace_id
                     root.attributes["rows"] = len(result.rows)
                     if executor.fragment_cache_hits and cache_tier is None:
@@ -852,7 +856,7 @@ class LocalQueryRunner:
                                 rows=list(result.rows),
                                 nbytes=nbytes,
                                 rows_encoded=rows_enc,
-                                created=_time.time(),
+                                created=time.time(),
                                 tables=profile.tables,
                                 versions=versions,
                                 query_id=current_query_id()
@@ -919,11 +923,13 @@ class LocalQueryRunner:
                     sum(s.device_secs for s in executor.stats.values()),
                 )
             else:
-                # async attribution: the drain observed by the result fetch
-                # is a device-time floor; dispatch covers host + overlapped
-                # device work (exact splits need query_stats_sync)
-                collector.add_time("device_busy_secs", drain_secs)
-                collector.add_time("dispatch_secs", max(dispatch_secs, 0.0))
+                # async attribution, read off the spans: dispatch covers host
+                # + overlapped device work, the drain is the last wait for
+                # the device and not its busy time (exact splits need
+                # query_stats_sync)
+                collector.add_time("drain_secs", drained.duration_secs)
+                collector.add_time("dispatch_secs", dispatched.duration_secs)
+            self._book_planning(collector, root)
             snap = collector.snapshot()
             snap["cacheHitTier"] = cache_tier
             if executor.cache_provenance:
@@ -937,6 +943,19 @@ class LocalQueryRunner:
 
         return execute_with_retry(
             run_once, sql, retry_policy=str(self.session.get("retry_policy"))
+        )
+
+    @staticmethod
+    def _book_planning(collector, root) -> None:
+        """Planning seconds of the statement, read off its spans: analysis is
+        the planner's, planning is parse + planner + optimizer."""
+        from .tracing import child_secs
+
+        tree = root._trace or ()
+        collector.add_time("analysis_secs", child_secs(tree, root, "planner"))
+        collector.add_time(
+            "planning_secs",
+            child_secs(tree, root, "parse", "planner", "optimizer"),
         )
 
     def _maybe_plan_flight(self, sql: str, compute):

@@ -33,6 +33,8 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
+from .tracing import TRACER
+
 # logical pid for all engine events (one process; workers override)
 _PID = 1
 _PROCESS_NAME = "trino-tpu"
@@ -233,6 +235,25 @@ class FlightRecorder:
 RECORDER = FlightRecorder()
 
 
+def flight_sink(span) -> None:
+    """``TRACER.sink``: a finished span of runtime/tracing.py as one X event
+    of the flight recorder, while it records. A span still open when an
+    export is taken is simply not there yet. Protocol phases keep the
+    ``proto_`` prefix and the ``protocol`` category the recorder's readers
+    select by; ``execution`` keeps ``query``."""
+    recorder = RECORDER if span.recorder is None else span.recorder
+    if not recorder.enabled:
+        return
+    cat = span.cat or "trace"
+    name = f"proto_{span.name}" if cat == "protocol" else span.name
+    recorder.complete(
+        name, cat, (span.end_ns - span.start_ns) / 1e9, **span.attributes
+    )
+
+
+TRACER.sink = flight_sink
+
+
 def validate_chrome_trace(trace: dict) -> List[str]:
     """Minimal schema validation for an exported trace: required fields,
     known pids/tids (declared via metadata events), per-track monotonic
@@ -317,9 +338,11 @@ def validate_chrome_trace(trace: dict) -> List[str]:
 class QueryStatsCollector:
     """Thread-safe per-query accumulator for the observability plane.
 
-    Time attribution (seconds): ``device_busy`` (inside device dispatch +
-    drain), ``host_wait`` (blocked on host I/O / prefetch results),
-    ``compile`` (XLA compiles, attributed by the jax.monitoring listener).
+    Time attribution (seconds): ``device_busy`` (fenced operators' device
+    time: sync mode only), ``host_wait`` (blocked on host I/O / prefetch
+    results), ``compile`` (XLA compiles, attributed by the jax.monitoring
+    listener); ``dispatch``, ``drain``, ``analysis`` and ``planning`` are
+    read off the statement's spans (runtime/tracing.py).
     Exact per-operator splits need sync mode (block_until_ready fencing —
     see PlanExecutor.collect_stats); async callers still get honest query-
     level dispatch/drain deltas plus every counter.
@@ -327,7 +350,8 @@ class QueryStatsCollector:
 
     _TIME_KEYS = (
         "device_busy_secs", "host_wait_secs", "compile_secs", "emit_secs",
-        "fallback_secs", "dispatch_secs",
+        "fallback_secs", "dispatch_secs", "drain_secs",
+        "analysis_secs", "planning_secs",
     )
     _COUNT_KEYS = (
         "compile_count", "compile_cache_hits", "caps_from_store",
@@ -471,7 +495,12 @@ def query_stats_fields(snapshot: dict) -> dict:
         "deviceBusyTime": round(times.get("device_busy_secs", 0.0), 6),
         "hostWaitTime": round(times.get("host_wait_secs", 0.0), 6),
         "dispatchTime": round(times.get("dispatch_secs", 0.0), 6),
-        "analysisTime": round(times.get("compile_secs", 0.0), 6),
+        # the last wait for the device (unfenced mode), not its busy time
+        "drainTime": round(times.get("drain_secs", 0.0), 6),
+        # off the statement's spans: the planner's, and parse to optimizer
+        "analysisTime": round(times.get("analysis_secs", 0.0), 6),
+        "planningTime": round(times.get("planning_secs", 0.0), 6),
+        "compileTime": round(times.get("compile_secs", 0.0), 6),
         "spilledDataSize": counts.get("spill_write_bytes", 0),
         "spilledReadDataSize": counts.get("spill_read_bytes", 0),
         "internalNetworkInputDataSize": counts.get("exchange_pull_bytes", 0),

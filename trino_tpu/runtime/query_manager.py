@@ -67,22 +67,48 @@ class CancelResult(Enum):
     TERMINAL = "TERMINAL"
 
 
+# the statement's spans between admission and its first page
+_EXEC_SPANS = ("parse", "planner", "optimizer", "execution", "drain", "encode")
+
+
 @dataclass
 class QueryStats:
     create_time: float = field(default_factory=time.time)
     end_time: Optional[float] = None
+    # CPU seconds of the pool thread that ran the statement (thread_time:
+    # XLA's own threads are not in it)
     cpu_time: float = 0.0
     rows: int = 0
-    # host-path plane: the per-request queue-wait vs on-cpu split — time
-    # QUEUED behind the resource-group gate vs time from admission to done
-    # (runtime/hostprof.py; surfaced in /v1/query/{id} queryStats)
-    queued_secs: float = 0.0
-    exec_secs: float = 0.0
+    # the statement's root span (runtime/tracing.py): the split of the
+    # server's clock below is read off its children, on the tracer's clock
+    root: Optional[Any] = field(default=None, repr=False)
 
     @property
     def elapsed(self) -> float:
         end = self.end_time or time.time()
         return end - self.create_time
+
+    def _child_secs(self, *names: str) -> float:
+        from .tracing import child_secs
+
+        if self.root is None:
+            return 0.0
+        return child_secs(self.root._trace, self.root, *names)
+
+    @property
+    def queued_secs(self) -> float:
+        """From creation to admission: the wait for a pool thread and for
+        the resource group's slot (spans ``queue`` and ``admit``)."""
+        return self._child_secs("queue", "admit")
+
+    @property
+    def planning_secs(self) -> float:
+        return self._child_secs("parse", "planner", "optimizer")
+
+    @property
+    def exec_secs(self) -> float:
+        """From admission to the rows in hand: parse to encode."""
+        return self._child_secs(*_EXEC_SPANS)
 
 
 @dataclass
@@ -341,8 +367,12 @@ class QueryManager:
     def _note_done(self, q: QueryExecution) -> None:
         with self._lock:
             self._done_ring.append(q.query_id)
+            expired = []
             while len(self._done_ring) > self._max_history:
-                self._queries.pop(self._done_ring.popleft(), None)
+                expired.append(self._queries.pop(self._done_ring.popleft(), None))
+        for old in expired:
+            if old is not None:  # its client never fetched the last page
+                self.close_statement(old, expired=True)
 
     def _maybe_persist_profile(self, q: QueryExecution) -> None:
         """Cluster observability plane: persist the completed query's
@@ -382,10 +412,23 @@ class QueryManager:
                client_ctx=None, warm_result=None) -> QueryExecution:
         from .metrics import REGISTRY
 
+        from .tracing import STATEMENT, TRACER
+
         query_id = f"q_{uuid.uuid4().hex[:16]}"
         q = QueryExecution(
             query_id=query_id, sql=sql, user=user, source=source,
             data_encoding=data_encoding, client_ctx=client_ctx,
+            trace_id=query_id,
+        )
+        # the statement's timeline starts here, on the caller's thread: the
+        # trace id IS the query id; `queue` runs until a pool thread has the
+        # resource group's slot; the root until the last page is sent
+        # (server/coordinator.py) or the statement is canceled or expires
+        q.stats.root = TRACER.open_span(
+            STATEMENT, None, query_id, query_id=query_id, sql=sql[:200]
+        )
+        q._queue_span = TRACER.open_span(
+            "queue", q.stats.root, cat="protocol", query_id=query_id
         )
         # fleet routing already peeked the warm tier to classify this
         # statement as follower-servable: carry that result into admission
@@ -423,8 +466,18 @@ class QueryManager:
         if q is None:
             raise QueryNotFound(query_id)
         if q.transition(QueryState.CANCELED):
+            self.close_statement(q, canceled=True)
             return CancelResult.CANCELED
         return CancelResult.TERMINAL  # already terminal (or lost the race)
+
+    @staticmethod
+    def close_statement(q: QueryExecution, **attributes) -> None:
+        """Ends the statement's root span (once): its last page has been
+        sent, or its client went away (cancel, expiry from the history)."""
+        from .tracing import TRACER
+
+        if q.stats.root is not None:
+            TRACER.close_span(q.stats.root, **attributes)
 
     def kill(self, query_id: str, message: str = "") -> CancelResult:
         """system.runtime.kill_query semantics (KillQueryProcedure): fail the
@@ -489,6 +542,18 @@ class QueryManager:
         return True
 
     def _run(self, q: QueryExecution) -> None:
+        from .tracing import TRACER
+
+        with TRACER.attach(q.stats.root):
+            try:
+                self._run_queued(q)
+            finally:
+                # rejected, canceled while queued, or served from the cache
+                TRACER.close_span(q._queue_span)
+
+    def _run_queued(self, q: QueryExecution) -> None:
+        from .tracing import TRACER
+
         if q.state.is_done:
             return
         if self._groups is None:
@@ -497,6 +562,7 @@ class QueryManager:
             if getattr(q, "_warm_result", None) is not None \
                     and self._serve_cached(q):
                 return
+            TRACER.close_span(q._queue_span)
             self._run_admitted(q)
             return
         if self._serve_cached(q):
@@ -512,9 +578,7 @@ class QueryManager:
             )
             return
         q.resource_group = ticket.group.path
-        from .hostprof import phase_span
         from .metrics import REGISTRY
-        from .observability import RECORDER
 
         # protocol queue depth: queries parked behind the resource-group
         # gate right now (the host-path plane's saturation signal; rides
@@ -524,23 +588,19 @@ class QueryManager:
             help="queries waiting on a resource-group concurrency slot",
         )
         try:
-            # stays QUEUED until the group grants a concurrency slot; the
-            # proto_queue span + queued_secs make the wait attributable
-            # (queue-wait vs on-cpu is the host-path plane's per-request
-            # split)
-            queued_t0 = time.monotonic()
+            # stays QUEUED until the group grants a concurrency slot: the
+            # `queue` span, open since submit(), makes the wait attributable
             depth.inc()
             try:
-                with phase_span(RECORDER, "queue", query_id=q.query_id):
-                    while not ticket.event.wait(timeout=0.5):
-                        if q.state.is_done:  # canceled while queued
-                            self._groups.cancel(ticket)
-                            return
+                while not ticket.event.wait(timeout=0.5):
+                    if q.state.is_done:  # canceled while queued
+                        self._groups.cancel(ticket)
+                        return
             finally:
                 depth.dec()
-                q.stats.queued_secs = time.monotonic() - queued_t0
             if ticket.canceled:
                 return
+            TRACER.close_span(q._queue_span, resource_group=q.resource_group)
             # the group's scheduling weight rides this thread into the
             # device scheduler: batch admission and launch-gate ordering
             # drain high-priority groups first (runtime/device_scheduler)
@@ -559,16 +619,18 @@ class QueryManager:
         from .hostprof import phase_span
         from .observability import RECORDER
 
-        # proto_admit: the admission edge — slot granted to RUNNING (the
-        # host-path plane's phase between queue-wait and execute-dispatch)
-        with phase_span(RECORDER, "admit", query_id=q.query_id):
+        # the admission edge — slot granted to RUNNING (between the wait in
+        # `queue` and the runner's own spans)
+        with phase_span(
+            RECORDER, "admit", query_id=q.query_id,
+            resource_group=q.resource_group,
+        ):
             q.transition(QueryState.PLANNING)
         running = REGISTRY.gauge(
             "trino_tpu_queries_running", help="queries currently executing"
         )
         running.inc()
-        t0 = time.time()
-        exec_t0 = time.monotonic()
+        cpu0 = time.thread_time()
         from .memory import memory_scope
 
         try:
@@ -587,16 +649,13 @@ class QueryManager:
             # killer dooms by the same id). No pool -> no-op scope. The
             # statstore scope gives operator-stats rows this query's id.
             # The query_exec flight span is the cluster trace plane's
-            # attribution WINDOW: everything nested on this thread belongs
-            # to this query (no-op while the recorder is off).
-            # proto_execute: host-path phase marking execute-dispatch — the
-            # on-cpu half of the queue-wait/on-cpu split (queued_secs vs
-            # exec_secs in QueryStats).
+            # attribution WINDOW (clusterobs.filter_events_for_query):
+            # everything nested on this thread belongs to this query (no-op
+            # while the recorder is off). It times nothing: the runner's
+            # spans under the statement's root do.
             with query_id_scope(q.query_id), memory_scope(
                 q.query_id, self._memory_pool
-            ), RECORDER.span(
-                "query_exec", "query", query_id=q.query_id
-            ), phase_span(RECORDER, "execute", query_id=q.query_id):
+            ), RECORDER.span("query_exec", "query", query_id=q.query_id):
                 if self._wants("split_completed"):
                     from .events import split_events
 
@@ -612,14 +671,13 @@ class QueryManager:
                     result = self._executor_fn(q.sql, **kwargs)
             q.column_names = result.column_names
             q.column_types = getattr(result, "column_types", None)
-            q.trace_id = getattr(result, "trace_id", None)
             q.query_stats = getattr(result, "query_stats", None)
             # cluster trace assembly: a distributed runner's INTERNAL FTE
             # query id (task/attempt spans key on it) aliases this query
             q.fte_query_id = getattr(result, "fte_query_id", None)
             q.rows = result.rows
             q.stats.rows = len(result.rows)
-            q.stats.cpu_time = time.time() - t0
+            q.stats.cpu_time = time.thread_time() - cpu0
             q.transition(QueryState.FINISHED)
             REGISTRY.counter(
                 "trino_tpu_queries_finished_total", help="queries finished"
@@ -628,7 +686,7 @@ class QueryManager:
                 "trino_tpu_rows_produced_total", help="result rows produced"
             ).inc(len(result.rows))
         except Exception as e:  # noqa: BLE001 — error surface is the protocol
-            q.stats.cpu_time = time.time() - t0
+            q.stats.cpu_time = time.thread_time() - cpu0
             # error fields ride the transition so a query already FAILED by
             # kill() keeps its administrative message (transition no-ops)
             q.transition(
@@ -638,7 +696,6 @@ class QueryManager:
                 "trino_tpu_queries_failed_total", help="queries failed"
             ).inc()
         finally:
-            q.stats.exec_secs = time.monotonic() - exec_t0
             if self._memory_pool is not None:
                 # the query-end sweep: whatever its contexts still hold comes
                 # back to the pool (and wakes blocked peers) even when the
@@ -651,4 +708,4 @@ class QueryManager:
                 "trino_tpu_query_duration_secs",
                 help="end-to-end query wall time",
                 buckets=DEFAULT_BUCKETS,
-            ).observe(time.time() - t0)
+            ).observe(q.stats.exec_secs)

@@ -170,7 +170,7 @@ class CoordinatorServer:
 
             # ---------------------------------------------------------- utils
 
-            def _send(self, code: int, payload: Dict, extra_headers=None) -> None:
+            def _send(self, code: int, payload: Dict, extra_headers=None) -> int:
                 body = json.dumps(payload).encode()
                 self.send_response(code)
                 self.send_header("Content-Type", "application/json")
@@ -179,6 +179,33 @@ class CoordinatorServer:
                     self.send_header(k, v)
                 self.end_headers()
                 self.wfile.write(body)
+                return len(body)
+
+            def _stream_results(self, q, token: int) -> None:
+                """One page of the statement's protocol to the client: the
+                `result_stream` span under the statement's root, on this
+                HTTP thread. The page without a nextUri is the last: the
+                statement's timeline ends with it."""
+                from ..runtime.hostprof import phase_span
+                from ..runtime.observability import RECORDER
+                from ..runtime.tracing import TRACER
+
+                with TRACER.attach(q.stats.root), phase_span(
+                    RECORDER, "result_stream", query_id=q.query_id,
+                    token=token,
+                ) as sent:
+                    payload = coordinator._results_payload(
+                        q, token, self._base_uri()
+                    )
+                    sent["rows"] = len(payload.get("data", ())) + sum(
+                        seg["rowCount"] for seg in payload.get("segments", ())
+                    )
+                    sent["bytes"] = self._send(
+                        200, payload,
+                        extra_headers=coordinator._session_headers(q),
+                    )
+                if "nextUri" not in payload:
+                    coordinator.manager.close_statement(q)
 
             def _client_context(self):
                 """Rebuild the client session from protocol headers — the
@@ -411,16 +438,7 @@ class CoordinatorServer:
                             # trip; a slower query falls through to the
                             # usual nextUri sequence when the wait lapses
                             q.wait_done(wait)
-                        with phase_span(
-                            RECORDER, "result_stream", query_id=q.query_id
-                        ):
-                            self._send(
-                                200,
-                                coordinator._results_payload(
-                                    q, 0, self._base_uri()
-                                ),
-                                extra_headers=coordinator._session_headers(q),
-                            )
+                        self._stream_results(q, 0)
                     return
                 self._send(404, {"error": f"not found: {path}"})
 
@@ -745,24 +763,11 @@ class CoordinatorServer:
                     if q is None:
                         self._send(404, {"error": "unknown query"})
                         return
-                    from ..runtime.hostprof import phase_span
-                    from ..runtime.observability import RECORDER
-
                     # long-poll-ish: wait briefly for progress (the reference's
                     # ExecutingStatementResource does the same with maxWait)
                     if not q.state.is_done:
                         q.wait_done(timeout=1.0)
-                    with phase_span(
-                        RECORDER, "result_stream", query_id=query_id,
-                        token=token,
-                    ):
-                        self._send(
-                            200,
-                            coordinator._results_payload(
-                                q, token, self._base_uri()
-                            ),
-                            extra_headers=coordinator._session_headers(q),
-                        )
+                    self._stream_results(q, token)
                     return
                 self._send(404, {"error": f"not found: {path}"})
 
@@ -1233,6 +1238,9 @@ td,th{{border:1px solid #ccc;padding:4px 8px;text-align:left}}</style></head>
             "cpuTime": round(q.stats.cpu_time, 4),
             "rows": q.stats.rows,
             "state": q.state.value,
+            # the server's clock split by the statement's spans
+            "queuedTime": round(q.stats.queued_secs, 6),
+            "executionTime": round(q.stats.exec_secs, 6),
             # warm-path cache plane: which tier served this query
             # ("result" / "fragment" / "plan"), null on a fully cold run —
             # overwritten from the stats snapshot when one exists
@@ -1382,6 +1390,9 @@ td,th{{border:1px solid #ccc;padding:4px 8px;text-align:left}}</style></head>
             "stats": {
                 "state": q.state.value,
                 "elapsedTimeMillis": int(q.stats.elapsed * 1000),
+                # Trino's names, read off the statement's spans
+                "queuedTimeMillis": int(q.stats.queued_secs * 1000),
+                "planningTimeMillis": int(q.stats.planning_secs * 1000),
                 "processedRows": q.stats.rows,
             },
         }
