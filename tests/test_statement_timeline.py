@@ -26,6 +26,10 @@ Q06 = (
     "WHERE l_shipdate >= DATE '1994-01-01' AND l_shipdate < DATE '1995-01-01' "
     "AND l_discount BETWEEN 0.05 AND 0.07 AND l_quantity < 24"
 )
+# q06's filter where the consumer does need dense rows: a sort-path GROUP BY
+Q06_GROUPED = (
+    Q06.replace("SELECT sum(", "SELECT l_orderkey, sum(") + " GROUP BY l_orderkey"
+)
 Q01 = (
     "SELECT l_returnflag, l_linestatus, sum(l_quantity), sum(l_extendedprice), "
     "avg(l_discount), count(*) FROM lineitem WHERE l_shipdate <= DATE '1998-09-02' "
@@ -53,7 +57,7 @@ def client(server):
     from trino_tpu.client.client import StatementClient
 
     c = StatementClient(f"http://{server.address}")
-    for sql in (Q06, Q01):  # compiled before any test looks at a clock
+    for sql in (Q06, Q06_GROUPED, Q01):  # compiled before any test looks at a clock
         c.execute(sql)
     return c
 
@@ -97,6 +101,12 @@ def q06(client):
     res = client.execute(Q06)
     tree = finished_tree(res.query_id)
     return res, tree, programs_launched() - before
+
+
+@pytest.fixture(scope="module")
+def grouped(client):
+    res = client.execute(Q06_GROUPED)
+    return res, finished_tree(res.query_id)
 
 
 class TestOneTree:
@@ -162,22 +172,38 @@ class TestOneTree:
     def test_counts_on_the_root(self, q06):
         res, tree, launched = q06
         root = tree[0].attributes
-        syncs = [s for s in tree if s.name.startswith("sync:")]
-        assert root["host_syncs"] == len(syncs) >= 1
-        assert all(isinstance(s.attributes["value"], int) for s in syncs)
+        # a global sum reduces under the mask: nothing is read back
+        assert root["host_syncs"] == 0
+        assert not [s for s in tree if s.name.startswith("sync:")]
         assert root["launches"] == launched > 0
         assert root["launches"] == sum(
             s.attributes.get("launches", 0) for s in tree[1:]
         )
         assert root["rows"] == len(res.rows) == 1 and root["pages"] == 1
 
-    def test_a_selective_filter_leaves_a_compact_span(self, q06):
-        _, tree, _ = q06
+    def test_host_syncs_on_the_root(self, grouped):
+        res, tree = grouped
+        syncs = [s for s in tree if s.name.startswith("sync:")]
+        assert tree[0].attributes["host_syncs"] == len(syncs) >= 1
+        assert "sync:compact" in {s.name for s in syncs}
+        assert all(isinstance(s.attributes["value"], int) for s in syncs)
+        assert tree[0].attributes["rows"] == len(res.rows) > 1
+
+    def test_a_selective_filter_leaves_a_compact_span(self, grouped):
+        _, tree = grouped
         compact = [s.attributes for s in tree if s.name == "compact"]
         assert compact, [s.name for s in tree]
         for a in compact:
             assert a["live_rows"] <= a["capacity_out"] < a["capacity_in"]
             assert a["live_rows"] * 4 <= a["capacity_in"] and a["columns"] >= 1
+            # under a sixteenth of the page is kept: positions and a gather
+            assert a["capacity_out"] * 16 <= a["capacity_in"]
+            assert a["path"] == "index"
+
+    def test_a_global_sum_under_a_selective_filter_does_not_compact(self, q06):
+        _, tree, _ = q06
+        assert not [s for s in tree if s.name == "compact"]
+        assert tree[0].attributes["host_syncs"] == 0
 
     def test_a_scan_that_keeps_its_rows_does_not_compact(self, client):
         tree = finished_tree(client.execute(Q01).query_id)
@@ -390,7 +416,7 @@ class TestProfilerTimeline:
         options.host_tracer_level = 2
         jax.profiler.start_trace(str(tmp_path), profiler_options=options)
         try:
-            res = client.execute(Q06)
+            res = client.execute(Q06_GROUPED)  # it syncs and compacts
             tree = finished_tree(res.query_id)
         finally:
             jax.profiler.stop_trace()

@@ -175,8 +175,11 @@ def cosort(pass_keys: Sequence[jnp.ndarray], payloads: Sequence[jnp.ndarray]):
 
     ``pass_keys`` are applied least-significant first (the last is primary).
     Returns (sorted_pass_keys, sorted_payloads). Co-sorting avoids separate
-    permutation gathers, which cost ~60ns/element on TPU — the sort itself
-    moves the payload rows. Multi-pass single-key sorts are deliberate: the
+    permutation gathers of every row: on a v5e a gather costs 12 / 20 / 40 ns
+    an element of 1 / 4 / 8 bytes, a 37.7M-row sort 102 ms for its key and 47
+    ms more for each 32-bit operand word (28 ms a mask), so payloads ride the
+    sort when most rows are kept and are gathered when a sixteenth or less is
+    (``live_indices``). Multi-pass single-key sorts are deliberate: the
     variadic lexicographic comparator (num_keys > 1) compiles catastrophically
     slowly in the TPU backend (>9 min for a 16-operand sort)."""
     arrays = list(pass_keys) + list(payloads)
@@ -205,6 +208,51 @@ def last_active_prev(vals: jnp.ndarray, active: jnp.ndarray):
     prev_vals = jnp.roll(inc[0], 1).at[0].set(0)
     prev_has = jnp.roll(inc[1], 1).at[0].set(False)
     return prev_vals, prev_has
+
+
+# live_indices cuts the mask into rows of this many entries: the most whose
+# counts (0..255 live entries before a lane) fit a byte, so that a slot
+# gathers 256 bytes.
+_LIVE_ROW = 256
+# Keeping more than one row in this many, a single sort of the positions is
+# cheaper than 26 ns a slot (v5e, capacity 37.7M: the sort takes 117 ms at any
+# share kept; PERF.md section 6). `executor._compact_path` turns on it too.
+LIVE_INDEX_SHARE = 16
+
+
+def live_indices(active: jnp.ndarray, new_cap: int) -> jnp.ndarray:
+    """Positions (int32) of the first ``new_cap`` True entries of ``active``
+    in row order; slots past the live count hold n = len(active).
+
+    No sort of the page and no scatter over it: the mask is cut into rows of
+    256, one pass counts the live entries before each lane of a row, the rows'
+    totals place every output slot in its row (``expand_probe_slots``: one
+    scatter of the row starts, n/256 updates, and a cummax over the slots),
+    and the lane is the number of the row's counts that do not exceed the
+    slot's ordinal within the row: one gather of a 256-byte row per slot.
+    Cost: one pass over the mask plus work per SLOT, whatever the density
+    and however the live entries cluster. When most of the page is kept the
+    per-slot work loses to a single-operand sort of the positions, which
+    costs the same at any ``new_cap``; the choice is on the static shapes."""
+    n = active.shape[0]
+    if new_cap * LIVE_INDEX_SHARE > n:
+        pos = jnp.where(active, jnp.arange(n, dtype=jnp.int32), jnp.int32(n))
+        idx = jax.lax.sort(pos)[:new_cap]
+        if n < new_cap:
+            idx = jnp.pad(idx, (0, new_cap - n), constant_values=n)
+        return idx
+    pad = (-n) % _LIVE_ROW
+    rows = (jnp.pad(active, (0, pad)) if pad else active).reshape(-1, _LIVE_ROW)
+    ones = rows.astype(jnp.int32)
+    before = jnp.cumsum(ones, axis=1, dtype=jnp.int32) - ones  # live left of each lane
+    per_row = before[:, -1] + ones[:, -1]
+    row_of, ordinal, in_range, _ = expand_probe_slots(per_row, new_cap)
+    # `before` is nondecreasing along a row and reaches `ordinal` at the lane
+    # sought, so that lane is (how many counts are <= ordinal) - 1
+    at_most = before.astype(jnp.uint8)[row_of] <= ordinal.astype(jnp.uint8)[:, None]
+    lane = jnp.sum(at_most, axis=1, dtype=jnp.int32) - 1
+    idx = row_of.astype(jnp.int32) * _LIVE_ROW + lane
+    return jnp.where(in_range, idx, jnp.int32(n))
 
 
 def boundary_positions(new_group: jnp.ndarray, out_cap: int) -> jnp.ndarray:

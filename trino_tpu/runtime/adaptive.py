@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..metadata import Metadata, Session
+from ..ops import kernels as K
 from ..planner.plan import (
     AggregationNode,
     FilterNode,
@@ -84,26 +85,18 @@ def _mask_top_valid(c: Column, keep: jnp.ndarray) -> Column:
 
 def trace_compact(new_cap: int, page: Page) -> Tuple[Page, jnp.ndarray, jnp.ndarray]:
     """Stable in-trace compaction: active rows move to the front of a
-    ``new_cap``-row page. One int32 scatter at source capacity + one gather
-    of ``new_cap`` rows per column — NOT a sort (the cosort-based
-    ``_jit_compact`` moves every payload through a full sort network).
+    ``new_cap``-row page. ``K.live_indices`` + one gather of ``new_cap`` rows
+    per column: the device program of ``executor._jit_compact``'s index path,
+    with the count left on the device.
 
     Returns (page, overflow, true_count); rows past ``new_cap`` are dropped
     and counted in ``overflow`` (the caller retries with a larger capacity).
     """
-    active = page.active
-    n = active.shape[0]
-    slots = jnp.cumsum(active.astype(jnp.int32)) - 1
-    # cumsum yields -1 at the tail when nothing is active -> total 0
-    total = (slots[-1] + 1).astype(jnp.int64)
-    targets = jnp.where(active & (slots < new_cap), slots, new_cap)
-    perm = (
-        jnp.zeros((new_cap,), dtype=jnp.int32)
-        .at[targets]
-        .set(jnp.arange(n, dtype=jnp.int32), mode="drop")
-    )
-    count = jnp.minimum(total, new_cap).astype(jnp.int32)
-    new_active = jnp.arange(new_cap, dtype=jnp.int32) < count
+    n = page.active.shape[0]
+    total = jnp.sum(page.active.astype(jnp.int32)).astype(jnp.int64)
+    idx = K.live_indices(page.active, new_cap)
+    new_active = idx < n
+    perm = jnp.minimum(idx, n - 1)
     cols = tuple(
         _mask_top_valid(_permute_column(c, perm), new_active) for c in page.columns
     )

@@ -33,6 +33,7 @@ from ..metadata import Metadata, Session
 from . import kernelcost
 from .device_scheduler import on_program_launch
 from .failure import FailureInjector
+from .metrics import REGISTRY
 from .observability import on_spill_read, on_spill_write
 from .tracing import OP_PREFIX, SYNC_PREFIX, TRACER
 from ..ops import kernels as K
@@ -1418,9 +1419,7 @@ class PlanExecutor:
             for p in range(nparts):
                 mask = rel.page.active & (pid == p)
                 n = _live_rows(mask, "spill_partition")
-                part = _jit_compact(
-                    _round_capacity(max(n, 1)), Page(rel.page.columns, mask)
-                )
+                part = _compact(Page(rel.page.columns, mask), n)
                 blobs.append(serialize_page(part, compress=True))
         for b in blobs:
             self.spill_count += 1
@@ -1796,35 +1795,66 @@ def _load_splits(provider, splits, col_indexes, session) -> List[Page]:
 def _maybe_compact(rel: Relation, density: int = 4, min_cap: int = 8192) -> Relation:
     """Drop inactive rows when fewer than 1/``density`` of capacity is live.
 
-    One stable single-key sort pass (active rows first, no gathers) replacing
-    the many full-capacity sort passes a sparse group-by/sort would otherwise
-    pay. Host-syncs the active count — callers are pipeline breakers that
-    already host-sync their output capacity."""
+    One compaction (``_compact``: its cost follows the rows and columns kept)
+    in place of the many full-capacity sort passes a sparse group-by, join or
+    sort would otherwise pay. Host-syncs the active count: callers are
+    pipeline breakers that already host-sync their output capacity."""
     cap = rel.capacity
     if cap <= min_cap:
         return rel
     n = _live_rows(rel.page.active, "compact")
     if n * density > cap:
         return rel
-    new_cap = _round_capacity(max(n, 1))
-    # what is sorted (capacity_in rows of `columns`) against what is kept
-    with TRACER.span(
-        "compact", capacity_in=cap, live_rows=n, capacity_out=new_cap,
-        columns=len(rel.page.columns),
-    ):
-        page = _jit_compact(new_cap, rel.page)
     # compaction is a stable partition by activity — order preserved
-    return Relation(page, rel.symbols, rel.sorted_by)
+    return Relation(_compact(rel.page, n), rel.symbols, rel.sorted_by)
+
+
+COMPACTIONS_COUNTER = "trino_tpu_compactions_total"
+COMPACTIONS_HELP = (
+    "pages made dense, by path: index (live-row positions and a gather of the "
+    "rows kept) or sort (one stable sort that carries every column)"
+)
+
+
+def _compact(page: Page, live_rows: int) -> Page:
+    """The page's ``live_rows`` active rows, in row order, at the front of a
+    page of the next capacity class. One `compact` span under the operator
+    that asked, and one tick of ``trino_tpu_compactions_total{path}``."""
+    new_cap = min(_round_capacity(max(live_rows, 1)), page.capacity)
+    path = _compact_path(new_cap, page)
+    # what was scanned (capacity_in rows of `columns`) against what is kept
+    with TRACER.span(
+        "compact", capacity_in=page.capacity, live_rows=live_rows,
+        capacity_out=new_cap, columns=len(page.columns), path=path,
+    ):
+        REGISTRY.counter(
+            COMPACTIONS_COUNTER, {"path": path}, help=COMPACTIONS_HELP
+        ).inc()
+        return _jit_compact(new_cap, page)
+
+
+def _compact_path(new_cap: int, page: Page) -> str:
+    """``index`` or ``sort``, from the static shapes alone. A gather costs per
+    row KEPT (v5e: 12 / 20 / 40 ns an element of 1 / 4 / 8 bytes, and 26 ns a
+    row for its position), a sort per row SCANNED (capacity 37.7M: 102 ms the
+    key, 47 ms each 32-bit operand word, 28 ms each mask). Measured at that
+    capacity with one and three bigint columns: an eighteenth kept, 164 and
+    421 ms by index against 252 and 505 by sort; a ninth kept, 327 and 857
+    (PERF.md section 6). Nested and multi-lane columns cannot ride
+    ``lax.sort`` and take the index path at any density."""
+    flat = not any(c.children or c.data.ndim > 1 for c in page.columns)
+    if flat and new_cap * K.LIVE_INDEX_SHARE > page.capacity:
+        return "sort"
+    return "index"
 
 
 @partial(kernelcost.jit, static_argnums=(0,))
 def _jit_compact(new_cap: int, page: Page) -> Page:
-    if any(c.children or c.data.ndim > 1 for c in page.columns):
-        # nested lanes can't ride lax.sort payloads (shape mismatch) —
-        # permutation-gather instead
-        perm = jnp.argsort((~page.active).astype(jnp.int8))
-        cols = tuple(_slice_column(_permute_column(c, perm), new_cap) for c in page.columns)
-        return Page(cols, page.active[perm][:new_cap])
+    if _compact_path(new_cap, page) == "index":
+        idx = K.live_indices(page.active, new_cap)
+        rows = jnp.minimum(idx, page.capacity - 1)  # padding slots: any row
+        cols = tuple(_permute_column(c, rows) for c in page.columns)
+        return Page(cols, idx < page.capacity)
     key = (~page.active).astype(jnp.int8)
     payloads: List[jnp.ndarray] = []
     for c in page.columns:
@@ -1867,6 +1897,10 @@ _DIRECT_AGG_FUNCS = frozenset(
         "stddev_pop", "variance", "var_samp", "var_pop", "$fsum", "$fsumsq",
     }
 )
+# What a global aggregation computes as one masked reduction over the page
+# (`K.segment_reduce` at capacity 1): the others scatter, sort or gather per
+# row and want few rows.
+_MASKED_REDUCE_AGGS = _DIRECT_AGG_FUNCS - {"arbitrary", "any_value"}
 # Above this many candidate groups the [G, n] broadcast reduction loses to the
 # sort path (each extra group re-reads the data lane-parallel).
 DIRECT_GROUP_LIMIT = 256
@@ -1913,7 +1947,7 @@ def aggregate_relation(
     - direct-indexed (small static key domains): gid computed elementwise from
       dictionary codes, one fused bandwidth-bound pass — no sort, no host sync.
     - sort-based: (1) co-sort the needed columns by the group keys inside
-      lax.sort (no permutation gathers — they cost ~60ns/element on TPU),
+      lax.sort (no permutation gathers of every row: K.cosort has the costs),
       host-sync the group count, (2) reduction program with a bucketed static
       output capacity, segment sums via cumsum-at-boundaries."""
     domains = _direct_agg_domains(rel, node)
@@ -1927,7 +1961,13 @@ def aggregate_relation(
     # every multi-pass sort — compact first (this path host-syncs anyway).
     # ref: Trino pages are always dense (PageProcessor compacts per batch);
     # our mask design defers compaction to exactly these pipeline breakers.
-    rel = _maybe_compact(rel)
+    # A global aggregation of plain reductions is no such breaker: it reads
+    # each column once under the mask, which costs less than the compaction.
+    if node.group_keys or not all(
+        a.function in _MASKED_REDUCE_AGGS and not a.ordering
+        for _, a in node.aggregations
+    ):
+        rel = _maybe_compact(rel)
     # aggregate ORDER BY (array_agg(x ORDER BY y), listagg WITHIN GROUP): the
     # group sort is stable, so pre-sorting the whole relation by the aggregate
     # ordering fixes each group's element order (ref: AggregationNode
@@ -2043,8 +2083,7 @@ def _force_dense(rel: Relation) -> Relation:
     n = _live_rows(rel.page.active, "force_dense")
     if n == rel.capacity or _sync_int(jnp.all(rel.page.active[:n]), "force_dense"):
         return rel
-    page = _jit_compact(_round_capacity(max(n, 1)), rel.page)
-    return Relation(page, rel.symbols, rel.sorted_by)
+    return Relation(_compact(rel.page, n), rel.symbols, rel.sorted_by)
 
 
 def _finalize_listagg(col: Column, sep: str) -> Column:
