@@ -22,7 +22,7 @@ import subprocess
 import sys
 import threading
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from pathlib import Path
 
 from benchmark import reference as ref
@@ -135,20 +135,22 @@ class Compilations:
 
 
 class Served:
-    """The coordinator in this process, over the configuration's runner."""
+    """The coordinator in this process, over the configuration's runner:
+    `runners/<config["runner"]>.py` gives `start(config)`, the object the
+    server is built over, and `load(served)`, which fills `table_rows` and
+    `column_types`; it may give `check(served, records)`, the numbers of its own
+    that `compared` holds beside their limits: {name: (value, limit, what)}."""
 
     def __init__(self, config: dict):
         from trino_tpu import native
-        from trino_tpu.connectors.memory import MemoryConnector
-        from trino_tpu.runtime import LocalQueryRunner
         from trino_tpu.server import CoordinatorServer
 
         if not native.native_available():
             raise RuntimeError(f"native page codec unavailable: {native.load_error()}")
         self.config = config
         os.environ.update(config.get("environment", {}))  # the deployment's own settings
-        self.runner = LocalQueryRunner.tpch(scale=config["scale_factor"])
-        self.runner.register_catalog("memory", MemoryConnector())
+        self.module = importlib.import_module(f"benchmark.runners.{config['runner']}")
+        self.runner = self.module.start(config)
         self.server = CoordinatorServer(self.runner).start()
         self.url = f"http://{self.server.address}"
         self.table_rows: dict = {}
@@ -161,15 +163,7 @@ class Served:
         return StatementClient(self.url, timeout=1000.0)
 
     def load(self) -> None:
-        """CREATE TABLE AS into the memory catalog, the tables device-resident."""
-        source = self.runner.session.schema
-        for table in self.config["tables"]:
-            res = self.runner.execute(
-                f"CREATE TABLE memory.default.{table} AS SELECT * FROM tpch.{source}.{table}"
-            )
-            self.table_rows[table] = int(res.rows[0][0])
-            described = self.runner.execute(f"DESCRIBE memory.default.{table}").rows
-            self.column_types[table] = {name: kind for name, kind in described}
+        self.module.load(self)
 
     def stop(self) -> None:
         self.server.stop()
@@ -346,6 +340,7 @@ class Run:
     peaks: dict = None       # of this device kind, from peaks.json
     type_bytes: dict = None
     trace: object = None     # benchmark.trace.Reduced, in a traced run
+    notes: dict = field(default_factory=dict)   # what a reader prints beside its metric, under `notes`
 
     def latencies_by_template(self) -> dict:
         """{template: latencies of its completed statements}, every template of the mix."""
@@ -466,6 +461,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, t0: float,
     comparison, right = judge(
         [("warm-up", r) for r in warm] + [("window", r) for r in records], traffic, config
     )
+    check = getattr(served.module, "check", None)  # what the runner itself guarantees
+    for name, (value, limit, what) in (check(served, warm + records) if check else {}).items():
+        comparison.hold(name, value, limit, what)
     completed = [r for i, r in enumerate(records, start=len(warm)) if i in right]
     result = Run(
         cell=cell, config=config, traffic=traffic, device=device, setup_s=setup_s,
@@ -504,6 +502,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, t0: float,
         line["breakdown"] = result.trace.breakdown()
     else:
         line["metrics"] = report(result, "end_to_end")
+    if result.notes:
+        line["notes"] = result.notes
     line["compared"] = comparison.report()  # each number beside its limit, last
     for name, entry in line["compared"].items():
         print(f"compared: {name} {json.dumps(entry)}", file=sys.stderr)

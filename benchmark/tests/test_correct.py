@@ -2,6 +2,7 @@
 SF0.01 for several seeds' literals; a whole run of the harness without the look
 for a chip; the float32 control; and the timed path broken underneath."""
 
+import importlib
 import io
 import json
 import time
@@ -14,8 +15,11 @@ from benchmark import reference as ref
 from benchmark.tests.conftest import SCALE
 from benchmark.traffic import Traffic, load_mix
 
-CELLS = [w["name"] for w in harness.manifest()["workloads"]]
-CONCURRENT = next(w["name"] for w in harness.manifest()["workloads"] if w["traffic"] != "analytic_stream")
+BENCH = harness.manifest()
+CELLS = [w["name"] for w in BENCH["workloads"]]
+ONE_CLIENT = [w["name"] for w in BENCH["workloads"] if load_mix(w["traffic"])["clients"] == 1]
+CONCURRENT = next(c for c in CELLS if c not in ONE_CLIENT)
+MESH = [w["name"] for w in BENCH["workloads"] if w["chips"] > 1]
 SMALL = {"scale_factor": SCALE}
 
 
@@ -25,9 +29,6 @@ def drive(cell, seed, **kwargs):
                      config_overrides=SMALL, out=out, **kwargs)
     assert rc == 0
     return json.loads(out.getvalue().strip().splitlines()[-1])
-
-
-BENCH = harness.manifest()
 
 
 @pytest.fixture(scope="module", params=[c["name"] for c in BENCH["configs"]])
@@ -61,6 +62,9 @@ def test_a_whole_run_is_correct_and_prints_the_contract_line(cell):
     wanted = {m["name"] for m in harness.metrics_of(cell, "end_to_end")} - {"peak_hbm_bytes"}
     assert set(line["metrics"]) == wanted  # the CPU reports no device memory
     assert all(v["value"] > 0 for v in line["metrics"].values())
+    held = {k: v for k, v in line["compared"].items() if "limit" in v}
+    assert all(v["value"] <= v["limit"] for v in held.values())
+    assert ("off_tier" in held) == (cell in MESH)  # the mesh runner's own number, by statement
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -70,7 +74,7 @@ def test_the_float32_control_fails(cell, capsys):
         seen = json.loads(text)
         assert seen["correct"] is False
         assert seen["compared"]["exact_cells_wrong"]["value"] > 0  # q06's and q01's sums
-        if "q14" in harness.find_cell(cell)[1]["query_set"]:  # its answer is a double
+        if any(t.startswith("q14") for t in harness.find_cell(cell)[1]["query_set"]):  # a double
             assert seen["compared"]["double_rel_gap"]["value"] > 3 * ref.DOUBLE_REL_LIMIT
 
 
@@ -88,34 +92,40 @@ def broken(monkeypatch):
     answers as it should, from then on one value of each answer is altered
     where it is produced; with `half`, every other order's lines are left out
     of lineitem when it is loaded."""
+    from trino_tpu.parallel.runner import DistributedQueryRunner
     from trino_tpu.runtime import LocalQueryRunner
-
-    real = LocalQueryRunner.execute
 
     def install(after=None, half=False):
         seen = [0]
 
-        def execute(self, sql, *args, **kwargs):
-            if half and sql.startswith("CREATE TABLE memory.default.lineitem "):
-                sql += " WHERE l_orderkey % 2 = 0"
-            result = real(self, sql, *args, **kwargs)
-            if after is not None and sql.lstrip().startswith("SELECT"):
-                seen[0] += 1
-                if seen[0] > after and result.rows:
-                    first = list(result.rows[0])
-                    at = next(i for i, v in enumerate(first) if not isinstance(v, str))
-                    first[at] = _alter(first[at])
-                    result.rows[0] = type(result.rows[0])(first)
-            return result
+        def breaking(real):
+            def execute(self, sql, *args, **kwargs):
+                if half and sql.startswith("CREATE TABLE memory.default.lineitem "):
+                    sql += " WHERE l_orderkey % 2 = 0"
+                result = real(self, sql, *args, **kwargs)
+                if after is not None and sql.lstrip().startswith("SELECT"):
+                    seen[0] += 1
+                    if seen[0] > after and result.rows:
+                        first = list(result.rows[0])
+                        at = next(i for i, v in enumerate(first) if not isinstance(v, str))
+                        first[at] = _alter(first[at])
+                        result.rows[0] = type(result.rows[0])(first)
+                return result
 
-        monkeypatch.setattr(LocalQueryRunner, "execute", execute)
+            return execute
+
+        for runner in (LocalQueryRunner, DistributedQueryRunner):  # whichever the cell serves
+            monkeypatch.setattr(runner, "execute", breaking(runner.execute))
 
     return install
 
 
-def test_an_answer_altered_in_the_window_is_not_correct(broken):
-    broken(after=14)  # warm-up's 12 statements and the window's first 2 stay right
-    line = drive("resident_analytic_stream", 2**31 + 12)
+@pytest.mark.parametrize("cell", ONE_CLIENT)
+def test_an_answer_altered_in_the_window_is_not_correct(broken, cell):
+    cell_, config = harness.find_cell(cell)
+    warm = len(Traffic(load_mix(cell_["traffic"]), 2**31 + 12, config["schema"]).statements)
+    broken(after=warm + 2)  # the warm-up's statements and the window's first 2 stay right
+    line = drive(cell, 2**31 + 12)
     assert line["correct"] is False and line["failed"] == 0 and line["attempted"] > 2
     wrong = line["compared"]
     assert wrong["exact_cells_wrong"]["value"] > 0 or wrong["double_rel_gap"]["value"] > 1e-9
@@ -124,8 +134,30 @@ def test_an_answer_altered_in_the_window_is_not_correct(broken):
     assert sum(t["n"] for t in line["by_template"].values()) == 2
 
 
-def test_half_of_the_rows_left_out_is_not_correct(broken):
+@pytest.mark.parametrize("cell", [CONCURRENT] + MESH)
+def test_half_of_the_rows_left_out_is_not_correct(broken, cell):
     broken(half=True)
-    line = drive(CONCURRENT, 2**31 + 13)
+    line = drive(cell, 2**31 + 13)
     assert line["correct"] is False
     assert line["compared"]["exact_cells_wrong"]["value"] > 0
+
+
+@pytest.mark.parametrize("cell", MESH)
+def test_a_statement_off_the_mesh_tier_is_not_correct(monkeypatch, cell):
+    """With `use_ici_exchange` off the staged tier answers, and answers right:
+    only the runner's own number, `off_tier`, refuses the run."""
+    module = importlib.import_module(f"benchmark.runners.{harness.find_cell(cell)[1]['runner']}")
+    real = module.start
+
+    def start(config):
+        runner = real(config)
+        runner.session.set("use_ici_exchange", False)
+        return runner
+
+    monkeypatch.setattr(module, "start", start)
+    line = drive(cell, 2**31 + 14)
+    assert line["correct"] is False and line["failed"] == 0
+    wrong = line["compared"]
+    assert wrong["off_tier"]["value"] == wrong["statements_compared"]["value"] > 0
+    assert wrong["exact_cells_wrong"]["value"] == 0 and wrong["unanswered"]["value"] == 0
+    assert "not on tier ici" in wrong["first_wrong"]

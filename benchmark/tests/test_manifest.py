@@ -39,6 +39,9 @@ def test_configuration_file(config):
     assert all(key in held for key in config["reduced"])
     assert held["chips"] in (1, 4)
     assert any(w["config"] == config["name"] for w in BENCH["workloads"])
+    runner = importlib.import_module(f"benchmark.runners.{held['runner']}")  # found by name
+    assert callable(runner.start) and callable(runner.load)
+    assert callable(getattr(runner, "check", len))
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
@@ -52,10 +55,11 @@ def test_cell_files(cell):
     assert len(traffic.streams) == traffic.clients
     mine = [{s.index for v in stream.by_template.values() for s in v} for stream in traffic.streams]
     assert sorted(i for m in mine for i in m) == list(range(len(traffic.statements)))  # its own each
-    for c, stream in enumerate(traffic.streams):  # two passes hold every statement of the stream
-        width = len(stream.order or traffic.templates)
+    for c, stream in enumerate(traffic.streams):
+        # a seeded cycle holds every statement of the stream once, whatever each template's k;
+        # k passes in a fixed order hold them all too
         k = len(mine[c]) // len(traffic.templates)
-        sent = [traffic.next(c) for _ in range(width * k)]
+        sent = [traffic.next(c) for _ in range(len(stream.order) * k if stream.order else len(mine[c]))]
         assert {s.index for s in sent} == mine[c]
         if stream.order:
             assert [s.template for s in sent] == stream.order * k

@@ -17,6 +17,7 @@ from benchmark.layer_metrics import (
     compact_live_pct,
     exec_host_pct,
     host_syncs_per_query,
+    mesh_reshard_pct,
     plan_pct,
     queue_wait_pct,
 )
@@ -104,6 +105,54 @@ def test_no_compaction_reads_as_nothing():
     assert compact_live_pct.of([tree]) is None
 
 
+def mesh_by_hand():
+    """One statement of 200 ms on the mesh tier with two scans: the first
+    table's pages concatenated in 30 ms and put on the mesh in 20 (1000 bytes),
+    the second's in 5 and 5 (500 bytes); the program 100, the gather 10."""
+    return [
+        span("statement", "s", None, 0, 200, query_id="q_2", host_syncs=0, launches=9),
+        span("mesh:load_scan", "l0", "s", 10, 40, table="default.lineitem", rows=64, bytes=900),
+        span("mesh:shard", "h0", "s", 40, 60, h2d_bytes=1000),
+        span("mesh:load_scan", "l1", "s", 60, 65, table="default.part", rows=8, bytes=400),
+        span("mesh:shard", "h1", "s", 65, 70, h2d_bytes=500),
+        span("mesh:program", "m", "s", 70, 170, attempt=0, cached=True),
+        span("mesh:gather", "g", "s", 170, 180, rows=1),
+    ]
+
+
+def test_mesh_reshard_by_hand():
+    assert mesh_reshard_pct.of([mesh_by_hand()]) == pytest.approx(30.0)   # 30 + 20 + 5 + 5 of 200 ms
+    assert mesh_reshard_pct.h2d_bytes_per_statement([mesh_by_hand()]) == 1500
+    # beside a statement of another tier the share is over both, the bytes a mean
+    both = [mesh_by_hand(), by_hand(at_ms=300)]
+    assert mesh_reshard_pct.of(both) == pytest.approx(20.0)
+    assert mesh_reshard_pct.h2d_bytes_per_statement(both) == 750
+    assert mesh_reshard_pct.of([by_hand()]) is None     # no statement ran on the mesh tier
+    assert plan_pct.of([mesh_by_hand()]) is None        # the distributed runner plans outside any span
+    assert plan_pct.of(both) == pytest.approx(2.0)      # parse 1 + planner 3 + optimizer 2 of 300 ms
+
+
+def test_off_tier_is_read_statement_by_statement_from_the_spans():
+    from benchmark.runners import mesh_memory
+
+    on = mesh_by_hand()
+    retries_ran_out = [s for s in mesh_by_hand() if s["name"] != "mesh:gather"]   # the staged tier answered
+    assert mesh_memory.off_tier([on, on], 2) == (0, None)
+    off, what = mesh_memory.off_tier([on, retries_ran_out, by_hand()], 3)
+    assert off == 2 and "q_2: not on tier ici" in what and "mesh:program" in what
+    off, what = mesh_memory.off_tier([on], 3)   # two statements whose trees the ring has lost
+    assert off == 2 and "no tree" in what
+
+
+def test_mesh_reshard_on_the_recorded_ring():
+    recorded = json.loads((Path(__file__).parent / "recorded_mesh.json").read_text())
+    trees, want = recorded["trees"], recorded["by_hand"]
+    assert [t[0]["name"] for t in trees] == ["statement"] * 6
+    assert mesh_reshard_pct.of(trees) == pytest.approx(want["mesh_reshard_pct"], rel=1e-9)
+    assert mesh_reshard_pct.h2d_bytes_per_statement(trees) == want["h2d_bytes_per_statement"]
+    assert plan_pct.of(trees) is None and exec_host_pct.of(trees) == 0.0
+
+
 def test_select_wants_one_root_per_record_of_the_window():
     ring = [by_hand(at_ms=-500), by_hand(at_ms=10), by_hand(at_ms=200)]   # the first is warm-up's
     window = records((5, 120), (190, 310))
@@ -183,10 +232,15 @@ def test_a_traced_run_reports_the_span_metrics(cell, traced_on_the_cpu, capfd):
     line = json.loads(out.getvalue().strip().splitlines()[-1])
     assert line["correct"] is True and line["failed"] == 0
     mine = {m["name"] for m in harness.metrics_of(cell, "per_layer")
-            if m["name"].split(".")[0] in READERS}
+            if m["name"].split(".")[0] in set(READERS) | {"mesh_reshard_pct"}}
     assert mine and mine <= set(line["metrics"])
     for name in mine:
         assert line["metrics"][name]["value"] >= 0
     assert f"{line['attempted']} roots in the window for {line['attempted']} records" in capfd.readouterr().err
+    if harness.find_cell(cell)[0]["chips"] > 1:   # the mesh tier's spans, and the bytes beside them
+        assert 0 < line["metrics"]["mesh_reshard_pct.mesh"]["value"] < 100
+        assert line["notes"]["h2d_bytes_per_statement"] > 0
+    else:
+        assert not any(name.startswith("mesh_reshard_pct") for name in line["metrics"])
     # the program's spans lie in the profiler's trace: the longest gap names one
     assert "trino:" in line["breakdown"]["idle_gaps"][-1][0]

@@ -4,7 +4,10 @@ lists, so the tests drive it from a small recorded trace kept as JSON.
 
 Layout of a trace taken on a TPU (looked at by hand, PERF.md): one plane per
 chip, `/device:TPU:<n>`, whose line `XLA Modules` holds one event per program
-execution and whose line `XLA Ops` holds one event per operation inside them;
+execution and whose line `XLA Ops` holds one event per operation inside them
+(`Async XLA Ops` holds what is in flight beside them, from a `-start` to its
+`-done`: copies, slices, and a collective that the compiler made asynchronous;
+of that line only the collectives are kept, and they count as no busy time);
 host threads are lines of the plane `/host:CPU`, and the spans this benchmark
 writes (`bench_window`, `stmt:<template>`) are events there, on the same clock,
 beside the runtime's own (`np.asarray(jax.Array)` where the host waits for the
@@ -21,6 +24,11 @@ from dataclasses import dataclass, field
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 MODULES_LINE = "XLA Modules"
 OPS_LINE = "XLA Ops"
+ASYNC_LINE = "Async XLA Ops"
+COLLECTIVE = re.compile(
+    r"^%?(all-to-all|all-reduce|all-gather|reduce-scatter|collective-permute|async-collective)"
+    r"(-start|-done)?[.\d]*$"
+)
 HOST_PLANE = "/host:CPU"
 WINDOW_SPAN = "bench_window"
 STATEMENT_SPAN = "stmt:"
@@ -39,9 +47,11 @@ def load(path: str) -> list:
         if not device and plane.name != HOST_PLANE:
             continue
         for line in plane.lines:
-            if device and line.name not in (MODULES_LINE, OPS_LINE):
+            if device and line.name not in (MODULES_LINE, OPS_LINE, ASYNC_LINE):
                 continue
             for e in line.events:
+                if line.name == ASYNC_LINE and not COLLECTIVE.match(short(e.name)):
+                    continue
                 if (device or e.name == WINDOW_SPAN or e.name.startswith(STATEMENT_SPAN)
                         or e.duration_ns >= LONG_HOST_EVENT_NS):
                     events.append(
@@ -81,10 +91,15 @@ class Device:
     busy: list = field(default_factory=list)     # merged (start, end) of operations, in the window
     launches: int = 0                            # program executions begun in the window
     op_seconds: dict = field(default_factory=dict)   # "<program> <operation>" -> seconds in the window
+    collective: list = field(default_factory=list)   # merged (start, end) of collectives, in the window
 
     @property
     def busy_s(self) -> float:
         return sum(e - s for s, e in self.busy) / 1e9
+
+    @property
+    def collective_s(self) -> float:
+        return sum(e - s for s, e in self.collective) / 1e9
 
 
 @dataclass
@@ -134,19 +149,26 @@ class Reduced:
 
     def longest_gap(self) -> tuple:
         """(seconds, what the host was doing) for the one longest idle gap of
-        the fullest device: the statement in flight, and the three host events
-        that cover most of the gap, each with the seconds of it they cover."""
+        the fullest device: the statement in flight, and three host events
+        with the seconds of the gap each covers. First those that cover half
+        of the gap or more, the innermost (latest start) first: nested spans
+        all cover it, and the innermost says most. Then the others, by the
+        seconds they cover."""
         edges = [self.window[0]] + [t for iv in self.fullest.busy for t in iv] + [self.window[1]]
         lo, hi = max(zip(edges[0::2], edges[1::2]), key=lambda g: g[1] - g[0])
         inside = [(s, name) for name, s, e, _ in self.spans if s <= (lo + hi) / 2 < e]
-        covers: dict = {}
+        covers: dict = {}    # name -> (seconds of the gap covered, start) of its widest event
         for name, s, e in self.host_events:
             if min(e, hi) > max(s, lo):
-                covers[name] = max(covers.get(name, 0.0), (min(e, hi) - max(s, lo)) / 1e9)
-        doing = ", ".join(
-            f"{name[:40]} {seconds:.3f}"
-            for name, seconds in sorted(covers.items(), key=lambda kv: -kv[1])[:3]
-        )
+                covers[name] = max(covers.get(name, (0.0, 0.0)), ((min(e, hi) - max(s, lo)) / 1e9, s))
+        half = (hi - lo) / 2e9
+
+        def rank(item):
+            seconds, start = item[1]
+            return (0, -start) if seconds >= half else (1, -seconds)
+
+        ranked = sorted(covers.items(), key=rank)
+        doing = ", ".join(f"{name[:40]} {seconds:.3f}" for name, (seconds, _) in ranked[:3])
         where = f"in {min(inside)[1]}" if inside else "no statement in flight"
         return (hi - lo) / 1e9, f"longest gap, {where}; host: {doing or 'no long event'}"
 
@@ -179,9 +201,13 @@ def reduce(events: list) -> Reduced:
     for runs in programs.values():
         runs.sort()
     for plane, line, name, s, d in events:
-        if not (DEVICE_PLANE.match(plane) and line == OPS_LINE):
+        if not (DEVICE_PLANE.match(plane) and line in (OPS_LINE, ASYNC_LINE)):
             continue
         dev = devices.setdefault(plane, Device(plane))
+        if COLLECTIVE.match(name):
+            dev.collective += clip([(s, s + d)], lo, hi)
+        if line == ASYNC_LINE:
+            continue
         runs = programs.get(plane, [])
         at = bisect.bisect_right(runs, (s, math.inf, "")) - 1  # the program the operation began in
         if at >= 0 and s < runs[at][1]:
@@ -192,7 +218,7 @@ def reduce(events: list) -> Reduced:
     if not devices:
         raise ValueError("the trace holds no /device:TPU plane: nothing ran on the device")
     for dev in devices.values():
-        dev.busy = union(dev.busy)
+        dev.busy, dev.collective = union(dev.busy), union(dev.collective)
     host_events = [
         (name, s, s + d) for plane, _, name, s, d in events
         if plane == HOST_PLANE and name != WINDOW_SPAN and not name.startswith(STATEMENT_SPAN)
