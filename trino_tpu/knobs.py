@@ -482,8 +482,9 @@ SESSION_PROPERTIES: Tuple[SessionProperty, ...] = (
     ),
     SessionProperty(
         "mesh_join_capacity_factor", "double", 1.0,
-        "single-program ICI execution: initial join output capacity as a "
-        "multiple of probe capacity (overflow retries double it)",
+        "single-program ICI execution: output capacity of a join the "
+        "estimator gives no hint for, as a multiple of probe capacity (an "
+        "overflow grows it to the measured count)",
     ),
     SessionProperty(
         "use_ici_exchange", "boolean", True,

@@ -98,6 +98,30 @@ def all_to_all_page(
     return Page(tuple(cols), recv_active.reshape(num_partitions * bucket_cap)), overflow
 
 
+def bucket_demand(
+    page: Page, target: jnp.ndarray, num_partitions: int, axis_name: str
+) -> jnp.ndarray:
+    """The bucket capacity this exchange needs: the most rows any shard holds
+    for one destination (the same on every shard). The capacity an
+    overflowing exchange is retried at. In 32 bits: the TPU reduces 64-bit
+    integers by sum alone, and a shard's rows fit."""
+    per_dest = jnp.zeros(num_partitions, dtype=jnp.int32).at[target].add(
+        page.active.astype(jnp.int32), mode="drop"
+    )
+    return jax.lax.pmax(jnp.max(per_dest), axis_name).astype(jnp.int64)
+
+
+def hash_targets(
+    page: Page, key_indexes: Sequence[int], num_partitions: int
+) -> jnp.ndarray:
+    """Row -> destination shard by the hash of the key columns
+    (FIXED_HASH_DISTRIBUTION); no keys: everything to shard 0."""
+    keys = hash_key_columns([page.columns[i] for i in key_indexes])
+    if not keys:
+        return jnp.zeros(page.capacity, dtype=jnp.int32)
+    return partition_ids(keys, num_partitions)
+
+
 def repartition_by_keys(
     page: Page,
     key_indexes: Sequence[int],
@@ -106,25 +130,25 @@ def repartition_by_keys(
     bucket_cap: Optional[int] = None,
 ) -> Tuple[Page, jnp.ndarray]:
     """Hash-repartition a page by key columns (FIXED_HASH_DISTRIBUTION).
+    ``bucket_cap`` is the caller's: the mesh tier sizes it from the
+    producer's page as traced, so a narrowed producer makes a narrow exchange.
 
     Returns (page, overflow): see all_to_all_page for the overflow contract."""
-    keys = hash_key_columns([page.columns[i] for i in key_indexes])
-    target = partition_ids(keys, num_partitions)
+    target = hash_targets(page, key_indexes, num_partitions)
     return all_to_all_page(page, target, num_partitions, axis_name, bucket_cap)
 
 
-def repartition_by_range(
+def range_targets(
     page: Page,
     key_index: int,
     ascending: bool,
     nulls_first: bool,
     num_partitions: int,
     axis_name: str,
-    bucket_cap: Optional[int] = None,
     samples_per_shard: int = 64,
-) -> Tuple[Page, jnp.ndarray]:
-    """Range-repartition by the leading sort key: shard i receives keys below
-    shard i+1's — local sort per shard then yields GLOBAL order when shards
+) -> jnp.ndarray:
+    """Row -> destination shard by the leading sort key: shard i receives keys
+    below shard i+1's — local sort per shard then yields GLOBAL order when shards
     are concatenated in shard-index order. This is the distributed sort's
     shuffle (ref: docs admin/dist-sort.md + MergeOperator.java — Trino merges
     sorted streams instead; on a mesh, sampled range boundaries + all_to_all
@@ -149,8 +173,28 @@ def repartition_by_range(
     allsamp = jax.lax.all_gather(sample, axis_name, axis=0, tiled=True)
     g = jnp.sort(allsamp)
     boundaries = g[jnp.arange(1, num_partitions) * samples_per_shard]
-    target = jnp.sum(
+    return jnp.sum(
         (key[:, None] >= boundaries[None, :]).astype(jnp.int32), axis=1
+    )
+
+
+def repartition_by_range(
+    page: Page,
+    key_index: int,
+    ascending: bool,
+    nulls_first: bool,
+    num_partitions: int,
+    axis_name: str,
+    bucket_cap: Optional[int] = None,
+    samples_per_shard: int = 64,
+) -> Tuple[Page, jnp.ndarray]:
+    """Range-repartition by the leading sort key (``range_targets``), then
+    the all_to_all; ``bucket_cap`` as in ``repartition_by_keys``.
+
+    Returns (page, overflow): see all_to_all_page for the overflow contract."""
+    target = range_targets(
+        page, key_index, ascending, nulls_first, num_partitions, axis_name,
+        samples_per_shard,
     )
     return all_to_all_page(page, target, num_partitions, axis_name, bucket_cap)
 

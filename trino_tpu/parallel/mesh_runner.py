@@ -14,17 +14,25 @@ No host round-trip between stages: stage outputs never leave HBM, the exchange
 rides ICI, and XLA overlaps the collectives with compute — the role Trino's
 pull/ack HTTP streams play between JVM workers (DirectExchangeClient.java:270).
 
-Static-shape discipline: joins get a fixed output capacity and the program
-returns a summed OVERFLOW scalar (join emits beyond capacity + all_to_all
-bucket overflow). The runner host-checks it and retries with doubled
-capacities — degrade to recompile, never to wrong answers.
+Static-shape discipline: every page between two stages is sized by what it
+holds. Each fragment runs on runtime/adaptive.py's narrowing executor: per
+shard, a filter's, scan's or aggregation's output is compacted to its hint, a
+join allocates its hinted capacity, a grouped aggregation computes into a
+group capacity, and an exchange's bucket is twice the even share of the
+producer's page as traced. The program returns, in one small vector, the
+summed OVERFLOW scalar and every point's overflow and true count (the most
+over the shards). The runner reads it once per attempt: on overflow the
+points grow to what was measured and the program is rebuilt; a program that
+ran over-provisioned is rebuilt once at the measured sizes; the capacities a
+statement settles at are kept (runtime/capstore), so its next execution runs
+one cached program — degrade to recompile, never to wrong answers.
 """
 
 from __future__ import annotations
 
+import hashlib
 import math
-from dataclasses import dataclass
-from functools import partial
+from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -37,18 +45,26 @@ from ..planner import LogicalPlanner, optimize
 from ..planner.fragmenter import (
     ExchangeType,
     Partitioning,
-    PlanFragment,
     RemoteSourceNode,
     SubPlan,
     add_exchanges,
     create_fragments,
 )
 from ..planner.plan import LogicalPlan, OutputNode, PlanNode, TableScanNode, visit_plan
-from ..runtime import kernelcost
+from ..planner.stats import StatsEstimator
+from ..runtime import capstore, kernelcost
+from ..runtime.adaptive import (
+    _AdaptiveTracedExecutor,
+    candidate_nodes,
+    plan_capacities,
+    settled_capacity,
+    tight_capacity,
+)
 from ..runtime.executor import Relation, _concat_pages, _round_capacity
 from ..runtime.local import QueryResult
 from ..runtime.memory import page_bytes
-from ..runtime.traced import _TracedExecutor, is_traceable
+from ..runtime.metrics import REGISTRY
+from ..runtime.traced import is_traceable
 from ..runtime.tracing import TRACER
 from ..spi.page import Column, Page
 from ..sql import parse_statement
@@ -85,9 +101,31 @@ class _ScanSpec:
     symbols: Tuple[str, ...]
 
 
-class _MeshFragmentExecutor(_TracedExecutor):
-    """Executes one fragment per-shard inside shard_map. Scans read this
-    shard's block of the sharded page; RemoteSources turn into collectives."""
+# a fragment of these partitionings runs whole, the same on every shard
+_REPLICATED = (Partitioning.SINGLE, Partitioning.COORDINATOR_ONLY)
+# attempts a statement's first execution may take before the tier gives it up
+_MAX_ATTEMPTS = 6
+# the estimator's margin (plan_capacities' own), over a shard's even share
+_SEED_MARGIN = 2.0
+
+ATTEMPTS_COUNTER = "trino_tpu_mesh_program_attempts_total"
+ATTEMPTS_HELP = (
+    "runs of a mesh tier program: one a statement whose capacities held, "
+    "and one more for each overflow retry and for the re-run at measured sizes"
+)
+RETRIES_COUNTER = "trino_tpu_mesh_overflow_retries_total"
+RETRIES_HELP = (
+    "mesh tier programs rebuilt because a narrowing point or an exchange "
+    "bucket overflowed"
+)
+
+
+class _MeshFragmentExecutor(_AdaptiveTracedExecutor):
+    """Executes one fragment per-shard inside shard_map, on the narrowing
+    executor's capacities and records. Scans read this shard's block of the
+    sharded page; RemoteSources turn into collectives, and a repartitioning
+    one is a narrowing point of its own: its bucket capacity, the overflow of
+    its all_to_all and the bucket it would have needed."""
 
     def __init__(
         self,
@@ -96,40 +134,48 @@ class _MeshFragmentExecutor(_TracedExecutor):
         session,
         staged: Dict[int, Tuple[Page, Partitioning]],
         scan_pages: List[Page],
-        frag_by_id: Dict[int, PlanFragment],
         num_partitions: int,
         axis_name: str,
-        bucket_caps: Dict[int, int],
+        capacities: Dict[int, int],
+        records: list,
         join_capacity_factor: float,
     ):
         super().__init__(
             plan, metadata, session, dict(enumerate(scan_pages)),
-            join_capacity_factor=join_capacity_factor,
+            capacities, records, join_capacity_factor,
         )
         self._staged = staged
-        self._frag_by_id = frag_by_id
         self._n = num_partitions
         self._axis = axis_name
-        self._bucket_caps = bucket_caps
+
+    def _exchange(self, node: RemoteSourceNode, page: Page, target) -> Page:
+        """all_to_all of ``page`` by ``target``. The bucket is the hint a
+        retry measured, else twice the even share of the producer's page AS
+        TRACED: a narrowed producer makes a narrow exchange."""
+        unhinted = _round_capacity(max(2 * page.capacity // self._n, 8), base=8)
+        bucket_cap = self.capacities.get(id(node)) or unhinted
+        out, overflow = exchange.all_to_all_page(
+            page, target, self._n, self._axis, bucket_cap=bucket_cap
+        )
+        demand = exchange.bucket_demand(page, target, self._n, self._axis)
+        self._record(id(node), overflow, demand, bucket_cap, unhinted)
+        return out
 
     def _exec_RemoteSourceNode(self, node: RemoteSourceNode) -> Relation:
         page, producer_part = self._staged[node.fragment_id]
-        single_producer = producer_part in (
-            Partitioning.SINGLE,
-            Partitioning.COORDINATOR_ONLY,
-        )
+        single_producer = producer_part in _REPLICATED
+        me = jax.lax.axis_index(self._axis).astype(jnp.int32)
         if node.exchange_type == ExchangeType.REPARTITION_RANGE:
             o = node.orderings[0]
             key_idx = node.symbols.index(o.symbol)
             if single_producer:
                 # replicated producer: each shard keeps its key range — same
                 # sample-sort boundaries, no collective needed
-                me = jax.lax.axis_index(self._axis).astype(jnp.int32)
                 c = page.columns[key_idx]
                 from ..ops import kernels as K
 
                 # sorted dictionary codes are order keys (see
-                # exchange.repartition_by_range)
+                # exchange.range_targets)
                 key = K.encode_sort_column(c.data, c.valid, o.ascending, o.nulls_first)
                 skey = jnp.sort(jnp.where(page.active, key, jnp.int64(K.INT64_MAX)))
                 cnt = jnp.sum(page.active.astype(jnp.int64))
@@ -140,33 +186,20 @@ class _MeshFragmentExecutor(_TracedExecutor):
                 )
                 out = Page(page.columns, page.active & (target == me))
             else:
-                bucket_cap = self._bucket_caps[node.fragment_id]
-                out, overflow = exchange.repartition_by_range(
-                    page, key_idx, o.ascending, o.nulls_first,
-                    self._n, self._axis, bucket_cap=bucket_cap,
+                target = exchange.range_targets(
+                    page, key_idx, o.ascending, o.nulls_first, self._n, self._axis
                 )
-                self.overflows.append(overflow)
+                out = self._exchange(node, page, target)
             return Relation(out, node.symbols)
         if node.exchange_type == ExchangeType.REPARTITION:
+            key_idx = [node.symbols.index(k) for k in node.partition_keys]
+            target = exchange.hash_targets(page, key_idx, self._n)
             if single_producer:
                 # replicated producer: repartitioning needs NO collective —
                 # each shard keeps exactly the rows that hash to it
-                keys = exchange.hash_key_columns(
-                    [page.columns[node.symbols.index(k)] for k in node.partition_keys]
-                )
-                if keys:
-                    target = exchange.partition_ids(keys, self._n)
-                else:
-                    target = jnp.zeros(page.capacity, dtype=jnp.int32)
-                me = jax.lax.axis_index(self._axis).astype(jnp.int32)
                 out = Page(page.columns, page.active & (target == me))
             else:
-                key_idx = [node.symbols.index(k) for k in node.partition_keys]
-                bucket_cap = self._bucket_caps[node.fragment_id]
-                out, overflow = exchange.repartition_by_keys(
-                    page, key_idx, self._n, self._axis, bucket_cap=bucket_cap
-                )
-                self.overflows.append(overflow)
+                out = self._exchange(node, page, target)
             return Relation(out, node.symbols)
         # GATHER / BROADCAST: consumers need the complete producer output.
         # A replicated producer already satisfies that without a collective.
@@ -174,6 +207,20 @@ class _MeshFragmentExecutor(_TracedExecutor):
             return Relation(page, node.symbols)
         gathered = _all_gather_page(page, self._axis)
         return Relation(gathered, node.symbols)
+
+
+@dataclass
+class _MeshProgram:
+    """One compiled shard_map program and what its trace found: the points
+    that reported (their ordinals in ``MeshQueryRunner._points`` order), the
+    static capacity each ran at, the one it would run at unhinted, and which
+    of them no hint can narrow."""
+
+    fn: object
+    ordinals: List[int] = field(default_factory=list)
+    ran: List[int] = field(default_factory=list)
+    unhinted: List[int] = field(default_factory=list)
+    fixed: List[bool] = field(default_factory=list)
 
 
 def _all_gather_page(page: Page, axis_name: str) -> Page:
@@ -263,14 +310,20 @@ class MeshQueryRunner:
     def execute_subplan(self, subplan: SubPlan) -> Tuple[List[str], Page]:
         """Spans `mesh:load_scan`, `mesh:shard` (per scan) and `mesh:program`
         (per attempt) under the caller's statement root; `gather` adds
-        `mesh:gather`."""
+        `mesh:gather`.
+
+        A statement's first execution settles its capacities: seeded from the
+        estimator (a shard's share, with its margin), grown where a point
+        overflowed to what it measured, and rebuilt once where a point did
+        not run at the capacity its count asks for (`settled_capacity`). What
+        it settles at is kept by the plan's fingerprint (runtime/capstore),
+        so the next execution is one attempt of a cached program."""
         self._check_lowerable(subplan)
         scan_specs, scan_counts = self._shard_scans(subplan)
         root = subplan.root_fragment.root
         assert isinstance(root, OutputNode)
 
         join_factor = float(self.session.get("mesh_join_capacity_factor") or 1.0)
-        bucket_caps = self._initial_bucket_caps(subplan, scan_specs)
         flat_pages = [s.page for s in scan_specs]
 
         from ..runtime import observability as obs
@@ -279,47 +332,82 @@ class MeshQueryRunner:
         plan_key = repr(
             [(f.fragment_id, f.partitioning, f.root) for f in subplan.fragments]
         )
-        for attempt in range(4):
-            cache_key = (
-                plan_key,
-                tuple(p.capacity for p in flat_pages),
-                tuple(sorted(bucket_caps.items())),
-                join_factor,
-            )
+        shapes = (self.n, tuple(p.capacity for p in flat_pages), join_factor)
+        points = self._points(subplan)
+        fingerprint = hashlib.sha256(repr((plan_key, shapes)).encode()).hexdigest()
+        kept = capstore.load(fingerprint)
+        settled = kept is not None and len(kept) == len(points)
+        caps = list(kept) if settled else self._seed_capacities(subplan, points)
+        resized = False
+        for attempt in range(_MAX_ATTEMPTS):
+            cache_key = (plan_key, shapes, tuple(caps))
             program = self._program_cache.get(cache_key)
             cached = program is not None
             if program is None:
-                program = self._build_program(
-                    subplan, scan_counts, bucket_caps, join_factor
-                )
+                program = self._build_program(subplan, scan_counts, caps, join_factor)
                 self._program_cache[cache_key] = program
             elif collector is not None:
                 collector.add_count("compile_cache_hits")
-            # the one shard_map program and the read of its overflow flag
+            # the one shard_map program and the one read of what it measured
             # (the flight recorder keeps the span under the category `mesh`)
             with TRACER.span(
-                "mesh:program", cat="mesh", attempt=attempt,
-                join_factor=join_factor, cached=cached,
+                "mesh:program", cat="mesh", attempt=attempt, cached=cached,
             ) as ran, obs.compile_window() as cw:
-                out_page, overflow = program(*flat_pages)
-                done = int(overflow) == 0
+                out_page, measured = program.fn(*flat_pages)
+                measured = np.asarray(measured)
+                k = len(program.ordinals)
+                overflow, actual = measured[1 : 1 + k], measured[1 + k :]
+                held = int(measured[0]) == 0
+                ran.attributes.update(
+                    narrow_points=k,
+                    narrow_rows=int(actual.sum()),
+                    narrow_capacity=sum(program.ran),
+                    overflowed=int((overflow > 0).sum()),
+                )
+            REGISTRY.counter(ATTEMPTS_COUNTER, help=ATTEMPTS_HELP).inc()
             if collector is not None:
                 collector.add_time(
                     "device_busy_secs",
                     max(ran.duration_secs - cw.seconds, 0.0),
                 )
-            if done:
-                break
-            # degrade to recompile, never to wrong answers
+            if held:
+                if settled or resized:
+                    break
+                # once, the program this statement keeps is rebuilt at the
+                # measured sizes: where a point ran wider than its count
+                # needs, or was narrowed where that does not halve it
+                keep = [
+                    None if fixed else settled_capacity(a, unhinted)
+                    for a, unhinted, fixed in zip(
+                        actual, program.unhinted, program.fixed
+                    )
+                ]
+                if all(
+                    (hint or unhinted) == cap
+                    for hint, unhinted, cap in zip(keep, program.unhinted, program.ran)
+                ):
+                    break
+                for o, hint in zip(program.ordinals, keep):
+                    caps[o] = hint
+                resized = True
+                continue
+            # degrade to recompile, never to wrong answers: the points that
+            # overflowed grow to what they measured (the first one's count is
+            # exact; one downstream of it may need another attempt)
+            REGISTRY.counter(RETRIES_COUNTER, help=RETRIES_HELP).inc()
             if collector is not None:
                 collector.add_count("overflow_retries")
             obs.RECORDER.instant(
                 "mesh_overflow_retry", "mesh", attempt=attempt
             )
-            join_factor *= 2.0
-            bucket_caps = {k: v * 2 for k, v in bucket_caps.items()}
+            for o, a, over in zip(program.ordinals, actual, overflow):
+                if over > 0:
+                    caps[o] = tight_capacity(a)
+            settled = False
         else:
             raise MeshLoweringError("capacity retry limit exceeded")
+        if caps != kept:
+            capstore.save(fingerprint, caps)
 
         # out_specs P(axis) stacks each shard's (replicated) root block; the
         # root fragment is SINGLE so shard 0's block is the complete answer
@@ -405,9 +493,12 @@ class MeshQueryRunner:
                 per_shard = _round_capacity(
                     max(math.ceil(page.capacity / self.n), 1), base=8
                 )
+                # awaited here: the program then starts on every device at
+                # once, with no collective left waiting for a shard still on
+                # its way, and the span covers the transfer it names
                 with TRACER.span("mesh:shard") as sharding_span:
                     padded = _pad_page(page, per_shard * self.n)
-                    sharded = jax.device_put(padded, sharding)
+                    sharded = jax.block_until_ready(jax.device_put(padded, sharding))
                     sharding_span.attributes["h2d_bytes"] = page_bytes(padded)
                 symbols = tuple(s for s, _ in node.assignments)
                 scan_specs.append(_ScanSpec(frag.fragment_id, sharded, symbols))
@@ -433,35 +524,61 @@ class MeshQueryRunner:
             raise MeshLoweringError("empty scan (fully pruned) on mesh path")
         return _concat_pages(pages)
 
-    def _initial_bucket_caps(self, subplan, scan_specs) -> Dict[int, int]:
-        """bucket_cap per REPARTITION producer fragment: 2x the even share of
-        the producer's (estimated) per-shard capacity, pow2-rounded. Overflow
-        is detected and retried, so this is a bandwidth/memory tradeoff, not a
-        correctness knob."""
-        caps: Dict[int, int] = {}
-        frag_caps: Dict[int, int] = {}
-        for s in scan_specs:
-            frag_caps[s.fragment_id] = max(
-                frag_caps.get(s.fragment_id, 0), s.page.capacity // self.n
+    @staticmethod
+    def _points(subplan: SubPlan) -> List[PlanNode]:
+        """Every node a capacity may be chosen for, fragment by fragment in
+        canonical preorder: the narrowing executor's candidates and the
+        remote sources (a repartitioning one sizes its bucket). A capacity
+        vector is a list over these, None where nothing is hinted."""
+        return [
+            node
+            for frag in subplan.fragments
+            for node in candidate_nodes(
+                LogicalPlan(frag.root, subplan.types), extra=(RemoteSourceNode,)
             )
-        for frag in subplan.fragments:
-            base = frag_caps.get(frag.fragment_id, 0)
-            for fid in frag.input_fragments:
-                base = max(base, frag_caps.get(fid, 0))
-            frag_caps[frag.fragment_id] = max(base, 8)
-            caps[frag.fragment_id] = _round_capacity(
-                max(2 * frag_caps[frag.fragment_id] // self.n, 8), base=8
-            )
-        return caps
+        ]
 
-    def _build_program(self, subplan, scan_counts, bucket_caps, join_factor):
+    def _seed_capacities(self, subplan: SubPlan, points) -> List[Optional[int]]:
+        """The estimator's capacities, per shard: a fragment that runs on its
+        own part of the rows gets an ``n``-th of each estimate (with the
+        margin), a replicated one the whole. A remote source stands for its
+        producer's root; an exchange's bucket is not seeded (its default
+        follows the producer's page)."""
+        est = StatsEstimator(self.metadata, subplan.types)
         frag_by_id = {f.fragment_id: f for f in subplan.fragments}
+        seeded: Dict[int, int] = {}
+        for frag in subplan.fragments:  # producers first
+
+            def assume(node: PlanNode):
+                if isinstance(node, RemoteSourceNode):
+                    try:
+                        est.assume(node, est.stats(frag_by_id[node.fragment_id].root))
+                    except Exception:  # estimator gaps must never kill execution
+                        pass
+
+            visit_plan(frag.root, assume)
+            shards = 1 if frag.partitioning in _REPLICATED else self.n
+            seeded.update(
+                plan_capacities(
+                    LogicalPlan(frag.root, subplan.types), self.metadata,
+                    margin=_SEED_MARGIN / shards, estimator=est,
+                )
+            )
+        return [seeded.get(id(node)) for node in points]
+
+    def _build_program(self, subplan, scan_counts, caps, join_factor) -> _MeshProgram:
         root_id = subplan.root_fragment.fragment_id
         n, axis = self.n, self.axis
+        ordinal = {id(node): i for i, node in enumerate(self._points(subplan))}
+        hints = {key: caps[i] for key, i in ordinal.items() if caps[i] is not None}
+        program = _MeshProgram(None)
 
         def body(*flat_scan_pages: Page):
             staged: Dict[int, Tuple[Page, Partitioning]] = {}
-            overflows: List[jnp.ndarray] = []
+            records: list = []
+            ran: Dict[int, Tuple[int, int]] = {}
+            fixed: set = set()
+            unkeyed: List[jnp.ndarray] = []
             it = iter(flat_scan_pages)
             for frag in subplan.fragments:
                 frag_scans = [next(it) for _ in range(scan_counts[frag.fragment_id])]
@@ -471,10 +588,10 @@ class MeshQueryRunner:
                     self.session,
                     staged,
                     frag_scans,
-                    frag_by_id,
                     n,
                     axis,
-                    bucket_caps,
+                    hints,
+                    records,
                     join_factor,
                 )
                 if isinstance(frag.root, OutputNode):
@@ -492,17 +609,34 @@ class MeshQueryRunner:
                         rel.page.active,
                     )
                 staged[frag.fragment_id] = (page, frag.partitioning)
-                overflows.extend(executor.overflows)
+                ran.update(executor.ran)
+                fixed.update(executor._read_masked)
+                unkeyed.extend(executor.overflows)
             root_page = staged[root_id][0]
+            keys = [key for key, _, _ in records]
+            overflows = [over.astype(jnp.int64) for _, over, _ in records]
+            counts = [count.astype(jnp.int64) for _, _, count in records]
             total = jnp.int64(0)
-            for o in overflows:
+            for o in overflows + unkeyed:
                 total = total + o.astype(jnp.int64)
             # psum makes the indicator globally visible (values already psum'd
-            # just scale by n — the host only tests > 0)
-            total = jax.lax.psum(total, axis)
-            return root_page, total
+            # just scale by n — the host only tests > 0); a point's overflow
+            # and count are the most over the shards, which share one shape
+            # (the TPU reduces 64-bit integers by sum alone: the most is taken
+            # in 32 bits, which hold any shard's row count)
+            measured = [jax.lax.psum(total, axis)[None]]
+            if records:
+                narrow = jnp.clip(
+                    jnp.stack(overflows + counts), 0, jnp.iinfo(jnp.int32).max
+                ).astype(jnp.int32)
+                measured.append(jax.lax.pmax(narrow, axis).astype(jnp.int64))
+            program.ordinals = [ordinal[key] for key in keys]
+            program.ran = [ran[key][0] for key in keys]
+            program.unhinted = [ran[key][1] for key in keys]
+            program.fixed = [key in fixed for key in keys]
+            return root_page, jnp.concatenate(measured)
 
-        return kernelcost.jit(
+        program.fn = kernelcost.jit(
             jax.shard_map(
                 body,
                 mesh=self.mesh,
@@ -510,3 +644,4 @@ class MeshQueryRunner:
                 out_specs=(P(axis), P()),
             )
         )
+        return program

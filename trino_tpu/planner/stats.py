@@ -92,6 +92,11 @@ class StatsEstimator:
     def rows(self, node: PlanNode) -> Optional[float]:
         return self.stats(node).rows
 
+    def assume(self, node: PlanNode, stats: PlanStats) -> None:
+        """Take ``stats`` as ``node``'s estimate: a node this plan cannot see
+        behind (a remote source stands for its producer fragment's root)."""
+        self._memo[id(node)] = stats
+
     # ------------------------------------------------------------------ nodes
 
     def _estimate(self, node: PlanNode) -> PlanStats:

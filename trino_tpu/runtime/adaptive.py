@@ -47,15 +47,21 @@ from ..planner.plan import (
     JoinNode,
     LogicalPlan,
     PlanNode,
+    ProjectNode,
     TableScanNode,
     UnnestNode,
     visit_plan,
 )
 from ..planner.stats import StatsEstimator
 from ..spi.page import Column, Page
+from ..spi.types import BOOLEAN
+from ..sql.ir import Reference
 from . import capstore
 from . import kernelcost
 from .executor import (
+    _DIRECT_AGG_FUNCS,
+    _MASKED_REDUCE_AGGS,
+    DIRECT_GROUP_LIMIT,
     ExecutionError,
     Relation,
     _permute_column,
@@ -104,11 +110,102 @@ def trace_compact(new_cap: int, page: Page) -> Tuple[Page, jnp.ndarray, jnp.ndar
     return Page(cols, new_active), overflow, total
 
 
+def tight_capacity(actual: int) -> int:
+    """The capacity class a point settles at once its true count is known:
+    the count and a quarter more."""
+    actual = int(actual)
+    return _round_capacity(actual + (actual >> 2) + 16)
+
+
+def settled_capacity(actual: int, unhinted: int) -> Optional[int]:
+    """The hint a point keeps once its true count is known: its tight class
+    where that at least halves the capacity it would run at unhinted
+    (narrowing that does not halve does not pay for itself) or where the
+    unhinted capacity cannot hold the count (a join that expands, a skewed
+    exchange); else none."""
+    tight = max(tight_capacity(actual), _MIN_CAP)
+    if tight * _MIN_SHRINK <= unhinted or int(actual) > unhinted:
+        return tight
+    return None
+
+
+def masked_readers(plan: LogicalPlan, scan_pages: Dict[int, Page]) -> frozenset:
+    """Ids of the scans and filters whose rows reach, through projections
+    alone, an aggregation that reads each column once under the mask: a
+    global one of plain reductions, or a direct-indexed grouped one (every
+    group key a column of the scan with a small static domain). Making the
+    page dense first costs more than that one pass (the rule of
+    ``executor.aggregate_relation``). ``scan_pages``: by scan, in plan order."""
+    scans: List[PlanNode] = []
+    visit_plan(
+        plan.root, lambda n: scans.append(n) if isinstance(n, TableScanNode) else None
+    )
+    found = set()
+
+    def direct(node: AggregationNode, chain: List[PlanNode]) -> bool:
+        """``executor._direct_agg_domains``, from the scan's page."""
+        if not chain or not isinstance(chain[-1], TableScanNode):
+            return False
+        scan = chain[-1]
+        page = scan_pages.get(scans.index(scan))
+        if page is None or any(
+            a.function not in _DIRECT_AGG_FUNCS or a.distinct
+            for _, a in node.aggregations
+        ):
+            return False
+        total = 1
+        for symbol in node.group_keys:
+            for below in chain[:-1]:
+                if isinstance(below, ProjectNode):
+                    expr = dict(below.assignments).get(symbol)
+                    if not isinstance(expr, Reference):
+                        return False
+                    symbol = expr.symbol
+            names = [s for s, _ in scan.assignments]
+            if symbol not in names:
+                return False
+            c = page.columns[names.index(symbol)]
+            if c.dictionary is not None:
+                total *= len(c.dictionary) + 1
+            elif c.type == BOOLEAN:
+                total *= 3
+            else:
+                return False
+        return 1 <= total <= DIRECT_GROUP_LIMIT
+
+    def visit(node: PlanNode):
+        if not isinstance(node, AggregationNode):
+            return
+        chain: List[PlanNode] = []
+        below = node.source
+        while isinstance(below, (ProjectNode, FilterNode, TableScanNode)):
+            chain.append(below)
+            if isinstance(below, TableScanNode):
+                break
+            below = below.source
+        if node.group_keys:
+            reads_masked = direct(node, chain)
+        else:
+            reads_masked = all(
+                a.function in _MASKED_REDUCE_AGGS and not a.ordering and not a.distinct
+                for _, a in node.aggregations
+            )
+        if reads_masked:
+            found.update(id(n) for n in chain if not isinstance(n, ProjectNode))
+
+    visit_plan(plan.root, visit)
+    return frozenset(found)
+
+
 class _AdaptiveTracedExecutor(_TracedExecutor):
     """Traced executor with per-node capacity hints: joins allocate their
-    hinted output capacity directly; scan/filter/agg/unnest outputs compact
-    to their hint when that at least halves the buffer. Every candidate
-    point records (key, overflow, true_count) for the host-side tuner."""
+    hinted output capacity directly; a grouped aggregation computes into its
+    hinted group capacity; scan/filter/agg/unnest outputs compact to their
+    hint when that at least halves the buffer (not under a global
+    aggregation that reads them once: ``masked_readers``). Every candidate
+    point records (key, overflow, true_count) for the host-side tuner, and
+    ``ran[key]`` keeps the static capacity the point ran at beside the one it
+    would run at unhinted."""
 
     def __init__(
         self,
@@ -118,30 +215,48 @@ class _AdaptiveTracedExecutor(_TracedExecutor):
         scan_pages: Dict[int, Page],
         capacities: Dict[int, int],
         records: List[Tuple[int, jnp.ndarray, jnp.ndarray]],
+        join_capacity_factor: float = 1.0,
     ):
-        super().__init__(plan, metadata, session, scan_pages)
+        super().__init__(plan, metadata, session, scan_pages, join_capacity_factor)
         self.capacities = capacities
         self.records = records
+        self.ran: Dict[int, Tuple[int, int]] = {}
         self._join_key: Optional[int] = None
+        self._read_masked = masked_readers(plan, scan_pages)
+
+    def _record(self, key, overflow, actual, capacity: int, unhinted: int) -> None:
+        self.records.append((key, overflow, actual))
+        self.ran[key] = (capacity, unhinted)
 
     def eval(self, node: PlanNode) -> Relation:
         rel = super().eval(node)
-        if isinstance(node, _COMPACT_NODES):
-            key = id(node)
-            actual = jnp.sum(rel.page.active.astype(jnp.int64))
+        key = id(node)
+        # a grouped aggregation sized by _choose_group_capacity is on record
+        if isinstance(node, _COMPACT_NODES) and key not in self.ran:
             hint = self.capacities.get(key)
             cap = rel.capacity
             if (
                 hint is not None
+                and key not in self._read_masked
                 and max(hint, _MIN_CAP) * _MIN_SHRINK <= cap
             ):
                 new_cap = max(hint, _MIN_CAP)
                 page, ovf, total = trace_compact(new_cap, rel.page)
-                self.records.append((key, ovf, total))
+                self._record(key, ovf, total, new_cap, cap)
                 rel = Relation(page, rel.symbols, rel.sorted_by)
             else:
-                self.records.append((key, jnp.int64(0), actual))
+                actual = jnp.sum(rel.page.active.astype(jnp.int64))
+                self._record(key, jnp.int64(0), actual, cap, cap)
         return rel
+
+    def _choose_group_capacity(self, node, num_groups, in_cap: int) -> int:
+        hint = self.capacities.get(id(node))
+        cap = in_cap
+        if hint is not None:
+            cap = min(_round_capacity(max(hint, _MIN_CAP)), in_cap)
+        actual = num_groups.astype(jnp.int64)
+        self._record(id(node), jnp.maximum(actual - cap, 0), actual, cap, in_cap)
+        return cap
 
     def _join_relations(self, node: JoinNode, left: Relation, right: Relation,
                         allow_fusion: bool = True):
@@ -157,26 +272,25 @@ class _AdaptiveTracedExecutor(_TracedExecutor):
     def _choose_join_capacity(self, emit, probe_cap: int, build_cap: int) -> int:
         key = self._join_key
         hint = self.capacities.get(key) if key is not None else None
-        if hint is not None:
-            cap = _round_capacity(max(hint, _MIN_CAP))
-        else:
-            cap = _round_capacity(max(probe_cap, 1))
+        unhinted = _round_capacity(max(int(probe_cap * self.join_capacity_factor), 1))
+        cap = unhinted if hint is None else _round_capacity(max(hint, _MIN_CAP))
         actual = jnp.sum(emit).astype(jnp.int64)
         ovf = jnp.maximum(actual - cap, 0)
         # always keyed (key is the JoinNode id, set by _join_relations for
         # every join) so the tuner can grow ANY overflowing join — an
         # unkeyed overflow could never converge
-        self.records.append((key, ovf, actual))
+        self._record(key, ovf, actual, cap, unhinted)
         return cap
 
 
-def candidate_nodes(plan: LogicalPlan) -> List[PlanNode]:
+def candidate_nodes(plan: LogicalPlan, extra: tuple = ()) -> List[PlanNode]:
     """Narrowing candidates in canonical preorder — the cross-process-stable
-    ordering the persisted capacity vector (runtime/capstore) is keyed by."""
+    ordering the persisted capacity vector (runtime/capstore) is keyed by.
+    ``extra``: further node types a tier sizes (the mesh tier's exchanges)."""
     nodes: List[PlanNode] = []
 
     def visit(node: PlanNode):
-        if isinstance(node, _COMPACT_NODES + (JoinNode,)):
+        if isinstance(node, _COMPACT_NODES + (JoinNode,) + extra):
             nodes.append(node)
 
     visit_plan(plan.root, visit)
@@ -184,11 +298,17 @@ def candidate_nodes(plan: LogicalPlan) -> List[PlanNode]:
 
 
 def plan_capacities(
-    plan: LogicalPlan, metadata: Metadata, margin: float = 2.0
+    plan: LogicalPlan,
+    metadata: Metadata,
+    margin: float = 2.0,
+    estimator: Optional[StatsEstimator] = None,
 ) -> Dict[int, int]:
     """CBO-estimated output capacity per narrowing candidate (keyed by node
-    identity — stable for the lifetime of the plan object)."""
-    est = StatsEstimator(metadata, plan.types)
+    identity — stable for the lifetime of the plan object). ``estimator``:
+    one that already knows nodes this plan cannot estimate by itself (the
+    mesh tier's remote sources); a shard's part of an estimate is asked for
+    as a smaller ``margin``."""
+    est = estimator or StatsEstimator(metadata, plan.types)
     caps: Dict[int, int] = {}
 
     for node in candidate_nodes(plan):
@@ -311,7 +431,7 @@ class AdaptiveQuery:
             ovf = int(np.asarray(overflow))
             tuned: Dict[int, int] = {}
             for key, act in zip(self.keys, np.asarray(actuals)):
-                tuned[key] = _round_capacity(int(act + (act >> 2)) + 16)
+                tuned[key] = tight_capacity(act)
             if ovf == 0:
                 # tight already? keep; otherwise one shrink recompile
                 if all(self.caps.get(k) == c for k, c in tuned.items()):

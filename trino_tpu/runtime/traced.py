@@ -85,7 +85,11 @@ class _TracedExecutor(PlanExecutor):
     the entire eval happens inside one outer trace. Joins get a STATIC output
     capacity (probe capacity x ``join_capacity_factor``) and report overflow
     in ``self.overflows`` instead of host-syncing exact sizes — callers check
-    the summed overflow after the run and retry with a larger factor."""
+    the summed overflow after the run and retry with a larger factor. A
+    grouped aggregation computes into a STATIC group capacity chosen the same
+    way (``_choose_group_capacity``): here its input's, which cannot overflow;
+    runtime/adaptive.py hands it a hint and counts the groups that did not
+    fit."""
 
     allow_host_sync = False
 
@@ -110,6 +114,12 @@ class _TracedExecutor(PlanExecutor):
         )
         return cap
 
+    def _choose_group_capacity(self, node, num_groups, in_cap: int) -> int:
+        """Static capacity of a grouped aggregation's output and of the
+        segment temporaries it computes into. Groups never outnumber rows, so
+        the input's capacity needs no overflow entry."""
+        return in_cap
+
     def _exec_TableScanNode(self, node: TableScanNode) -> Relation:
         page = self._scan_pages[self._scan_counter]
         self._scan_counter += 1
@@ -117,7 +127,8 @@ class _TracedExecutor(PlanExecutor):
         return Relation(page, symbols)
 
     def _exec_AggregationNode(self, node: AggregationNode):
-        # no host sync for output capacity under tracing: use input capacity
+        # no host sync for output capacity under tracing: a static group
+        # capacity (_choose_group_capacity)
         import jax.numpy as jnp
 
         from .executor import (
@@ -147,7 +158,7 @@ class _TracedExecutor(PlanExecutor):
             sorted_page, new_group, num_groups = _jit_group_sort.__wrapped__(
                 node.group_keys, needed, rel.symbols, rel.page
             )
-            out_cap = rel.capacity
+            out_cap = self._choose_group_capacity(node, num_groups, rel.capacity)
         else:
             cols = tuple(rel.column_for(s) for s in needed)
             sorted_page = Page(cols, rel.page.active)
