@@ -139,7 +139,7 @@ class MemoryConnector(Connector):
                 raise ValueError(
                     f"column count mismatch: {page.num_columns} vs {len(table.columns)}"
                 )
-            rows = int(np.asarray(page.active).sum())
+            rows = int(page.num_rows())  # counted on the device: one integer read
             self._bump(name)
             if not table.bucketed_by:
                 table.pages.append(page)

@@ -41,7 +41,7 @@ from ..spi.connector import (
     TableMetadata,
     TableStatistics,
 )
-from ..spi.page import Column, Dictionary, Page
+from ..spi.page import Column, Dictionary, Page, capacity_class
 from ..spi.predicate import TupleDomain
 from ..spi.types import parse_type
 
@@ -1210,11 +1210,7 @@ class _Pages(ConnectorPageSourceProvider):
             first = (n_chunks * s) // total
             end = (n_chunks * (s + 1)) // total
             max_rows = max(max_rows, min(end * chunk, n) - first * chunk)
-        cap = 64
-        while cap < max_rows and cap < (1 << 20):
-            cap *= 2
-        if cap < max_rows:
-            cap = math.ceil(max_rows / (1 << 20)) << 20
+        cap = capacity_class(max_rows)
         schema = _TABLES[table]
         cols = []
         for idx in column_indexes:

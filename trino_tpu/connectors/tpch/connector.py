@@ -25,7 +25,7 @@ from ...spi.connector import (
     TableMetadata,
     TableStatistics,
 )
-from ...spi.page import Column, Dictionary, Page
+from ...spi.page import Column, Dictionary, Page, capacity_class
 from ...spi.predicate import TupleDomain
 from ...spi.types import parse_type
 from . import generator as g
@@ -155,11 +155,7 @@ class TpchConnector(Connector):
             for s in range(total_splits):
                 first, end, chunk, _ = g.chunk_range_for_split(n, s, total_splits)
                 rows = max(rows, min(end * chunk, n) - first * chunk)
-        cap = 64
-        while cap < rows and cap < (1 << 20):
-            cap *= 2
-        if cap < rows:  # beyond 1M: multiples of 1M, not powers of two
-            cap = math.ceil(rows / (1 << 20)) << 20
+        cap = capacity_class(rows)
         self._capacities[key] = cap
         return cap
 

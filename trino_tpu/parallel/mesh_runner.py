@@ -60,7 +60,12 @@ from ..runtime.adaptive import (
     settled_capacity,
     tight_capacity,
 )
-from ..runtime.executor import Relation, _concat_pages, _round_capacity
+from ..runtime.executor import (
+    Relation,
+    _concat_scan_pages,
+    _load_splits,
+    _round_capacity,
+)
 from ..runtime.local import QueryResult
 from ..runtime.memory import page_bytes
 from ..runtime.metrics import REGISTRY
@@ -515,14 +520,12 @@ class MeshQueryRunner:
         meta = self.metadata.get_table_metadata(node.table)
         col_indexes = [meta.column_index(c) for _, c in node.assignments]
         provider = connector.page_source_provider()
-        from ..runtime.executor import _load_splits
-
         pages = _load_splits(provider, splits, col_indexes, self.session)
         if not pages:
             # fully pruned scan: the staged (DCN) path handles it; keep the
             # mesh program's scan layout uniform instead of special-casing
             raise MeshLoweringError("empty scan (fully pruned) on mesh path")
-        return _concat_pages(pages)
+        return _concat_scan_pages(pages)
 
     @staticmethod
     def _points(subplan: SubPlan) -> List[PlanNode]:

@@ -39,7 +39,7 @@ from .tracing import OP_PREFIX, SYNC_PREFIX, TRACER
 from ..ops import kernels as K
 from ..ops.compiler import CVal, ColumnLayout, CompileError, compile_expression
 from ..spi.connector import Split
-from ..spi.page import Column, Dictionary, Page
+from ..spi.page import Column, Dictionary, Page, capacity_class
 from ..spi.types import (
     BIGINT,
     BOOLEAN,
@@ -180,6 +180,98 @@ def _concat_pages(pages: List[Page]) -> Page:
     ]
     active = jnp.concatenate([p.active for p in pages])
     return Page(tuple(cols), active)
+
+
+SCAN_CONCATS_COUNTER = "trino_tpu_scan_concats_total"
+SCAN_CONCATS_HELP = (
+    "scans that made one page of several split pages, by path: packed (the "
+    "live rows of every split at the front of a page of their capacity "
+    "class) or plain (a concatenation that keeps each split's padding)"
+)
+
+
+def _concat_scan_pages(pages: List[Page]) -> Page:
+    """A scan's split pages as one page, packed where that is copies and
+    nothing else (`_pack_pages`), else the plain concatenation. Rows keep
+    their order within and across splits either way. One tick of
+    ``trino_tpu_scan_concats_total{path}`` per scan of more than one page."""
+    if len(pages) == 1:
+        return pages[0]
+    packed = _pack_pages(pages)
+    REGISTRY.counter(
+        SCAN_CONCATS_COUNTER,
+        {"path": "plain" if packed is None else "packed"},
+        help=SCAN_CONCATS_HELP,
+    ).inc()
+    return _concat_pages(pages) if packed is None else packed
+
+
+def _pack_pages(pages: List[Page]) -> Optional[Page]:
+    """The live rows of page 0, then of page 1, and so on, at the front of a
+    page of their capacity class (`capacity_class`, the connectors' own); None
+    where that is not whole-page copies: a nested column, a page whose live
+    rows are not a prefix of it (observed, one read for all pages: the span
+    `sync:scan_pack`), or a class no smaller than the capacities together.
+    A split of a generated table is padded to its table's longest split, so
+    18 splits of `lineitem` at SF3 hold 18.0M rows in 37.7M of capacity, and
+    every later operator would run over the padding."""
+    flat = all(
+        not c.children and c.lengths is None and c.elem_valid is None
+        and (c.data.dtype, c.data.shape[1:]) == (c0.data.dtype, c0.data.shape[1:])
+        for p in pages for c, c0 in zip(p.columns, pages[0].columns)
+    )
+    if not flat:
+        return None
+    capacity_in = sum(p.capacity for p in pages)
+    stats = jnp.stack([_jit_live_prefix(p.active) for p in pages])
+    with TRACER.span(SYNC_PREFIX + "scan_pack") as sync:
+        stats = np.asarray(stats)
+        live_rows = sync.attributes["value"] = int(stats[:, 0].sum())
+    capacity_out = capacity_class(live_rows)
+    prefix_live = bool((stats[:, 0] == stats[:, 1]).all())
+    if not prefix_live or capacity_out >= capacity_in:
+        return None
+    with TRACER.span(
+        "scan_pack", pages=len(pages), capacity_in=capacity_in,
+        live_rows=live_rows, capacity_out=capacity_out,
+    ):
+        offsets = np.cumsum(stats[:, 0]) - stats[:, 0]
+        # room for the last page's padding, cut off at the end
+        room = capacity_out + max(p.capacity for p in pages)
+
+        def pack(arrays):
+            out = jnp.zeros((room,) + arrays[0].shape[1:], arrays[0].dtype)
+            for a, at in zip(arrays, offsets):
+                out = _jit_pack_page(out, a, np.int32(at))
+            return out[:capacity_out]
+
+        cols = []
+        for i, c0 in enumerate(pages[0].columns):
+            chunks = [p.columns[i] for p in pages]
+            dictionary, datas = _unify_dictionaries(chunks)
+            cols.append(Column(
+                c0.type, pack(datas), pack([c.valid for c in chunks]), dictionary
+            ))
+        active = jnp.arange(capacity_out, dtype=jnp.int32) < live_rows
+    return Page(tuple(cols), active)
+
+
+@kernelcost.jit
+def _jit_live_prefix(active):
+    """(rows live, position after the last live row): equal when the live
+    rows are a prefix of the page."""
+    position = jnp.arange(1, active.shape[0] + 1, dtype=jnp.int32)
+    return jnp.stack([
+        jnp.sum(active.astype(jnp.int32)),
+        jnp.max(jnp.where(active, position, 0)),
+    ])
+
+
+@partial(kernelcost.jit, donate_argnums=(0,))
+def _jit_pack_page(out, page, at):
+    """``page`` written whole into ``out`` from row ``at``: its padding lands
+    where the next page's rows will. One program per pair of shapes."""
+    return jax.lax.dynamic_update_slice_in_dim(out, page, at, axis=0)
 
 
 class _KeyView:
@@ -653,7 +745,7 @@ class PlanExecutor:
             if sym is None:
                 break
             sorted_by.append(sym)
-        return Relation(_concat_pages(pages), symbols, tuple(sorted_by))
+        return Relation(_concat_scan_pages(pages), symbols, tuple(sorted_by))
 
     def _exec_FilterNode(self, node: FilterNode) -> Relation:
         rel = self.eval(node.source)
@@ -3309,31 +3401,37 @@ def _string_key_luts(node, probe: Relation, build: Relation):
     return tuple(luts)
 
 
+def _unify_dictionaries(cols: List[Column]):
+    """(dictionary, data of every chunk in its code space): string chunks
+    whose dictionaries differ are re-encoded into one merged sorted
+    dictionary (codes are only comparable within one dictionary)."""
+    dicts = [c.dictionary for c in cols]
+    real = [d for d in dicts if d is not None]
+    if not real or not (
+        len({id(d) for d in dicts}) > 1 and len({d.fingerprint() for d in real}) > 1
+    ):
+        return next(iter(real), None), [c.data for c in cols]
+    merged_values = sorted(set().union(*[list(d.values) for d in real]))
+    dictionary = Dictionary(np.asarray(merged_values, dtype=object))
+    code_of = {s: c for c, s in enumerate(merged_values)}
+    datas = []
+    for c in cols:
+        if c.dictionary is None:
+            # dictionary-less string chunk (e.g. all-NULL branch of a
+            # grouping-sets union): codes are meaningless, map to 0
+            datas.append(jnp.zeros_like(c.data))
+            continue
+        lut = np.array([code_of[s] for s in c.dictionary.values], dtype=np.int32)
+        datas.append(jnp.asarray(lut)[jnp.clip(c.data, 0, len(lut) - 1)])
+    return dictionary, datas
+
+
 def _concat_cols(cols: List[Column], type_: Type) -> Column:
     """Concatenate column chunks: merges differing string dictionaries, pads
     array lanes to the widest W, and recurses into map/row children."""
     from ..spi.types import ArrayType as _At, MapType as _Mt, RowType as _Rt
 
-    dicts = [c.dictionary for c in cols]
-    real = [d for d in dicts if d is not None]
-    if real and (
-        len({id(d) for d in dicts}) > 1 and len({d.fingerprint() for d in real}) > 1
-    ):
-        merged_values = sorted(set().union(*[list(d.values) for d in real]))
-        dictionary = Dictionary(np.asarray(merged_values, dtype=object))
-        code_of = {s: c for c, s in enumerate(merged_values)}
-        datas = []
-        for c in cols:
-            if c.dictionary is None:
-                # dictionary-less string chunk (e.g. all-NULL branch of a
-                # grouping-sets union): codes are meaningless, map to 0
-                datas.append(jnp.zeros_like(c.data))
-                continue
-            lut = np.array([code_of[s] for s in c.dictionary.values], dtype=np.int32)
-            datas.append(jnp.asarray(lut)[jnp.clip(c.data, 0, len(lut) - 1)])
-    else:
-        dictionary = next((d for d in dicts if d is not None), None)
-        datas = [c.data for c in cols]
+    dictionary, datas = _unify_dictionaries(cols)
     valids = [c.valid for c in cols]
 
     if isinstance(type_, _At):

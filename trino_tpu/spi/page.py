@@ -610,6 +610,19 @@ def _scalar_from_pylist(
     return Column(type_, jnp.asarray(conv), jnp.asarray(valid))
 
 
+def capacity_class(rows: int) -> int:
+    """The capacity a stored page of ``rows`` rows takes: a power of two from
+    64 up to 2^20, a multiple of 2^20 above it. Pages of different tables then
+    share shapes, so a compiled operator program is a cache hit, and a large
+    table is not padded to twice its rows."""
+    cap = 64
+    while cap < rows and cap < (1 << 20):
+        cap *= 2
+    if cap < rows:
+        cap = -(-rows // (1 << 20)) << 20
+    return cap
+
+
 def compact_indices(active: np.ndarray) -> np.ndarray:
     """Host helper: indices of active rows (used at materialization boundaries)."""
     return np.nonzero(np.asarray(active))[0]
