@@ -92,8 +92,8 @@ class TestDisabledPath:
 
 
 def _replay(runner, baselines, n_clients=16, per_client=3):
-    """The BENCH_r09-shaped mixed replay on raw threads; asserts every
-    result equals its serial baseline."""
+    """The mixed replay (many clients, several templates) on raw threads;
+    asserts every result equals its serial baseline."""
     errors = []
     barrier = threading.Barrier(n_clients)
 

@@ -16,15 +16,9 @@ What this suite pins down:
   lowers) still attributes from the store (cache-hit-no-lowering path);
 - acceptance: EXPLAIN ANALYZE VERBOSE on TPC-H Q3 AND a vector top-k
   query renders per-operator FLOPs/HBM/roofline lines, while the
-  ``kernel_cost``-off path stays byte-identical;
-- the regression ladder: ``bench.run_ladder`` emits a hardware-labeled
-  schema-v3 record, ``tools/bench_regress.py`` passes an identical re-run
-  and flags a synthetically slowed run, and ``tools/bench_schema.py``
-  holds every checked-in BENCH_*.json to the audit rules.
+  ``kernel_cost``-off path stays byte-identical.
 """
 
-import copy
-import importlib.util
 import json
 import os
 
@@ -38,17 +32,6 @@ from trino_tpu.runtime.local import LocalQueryRunner
 from trino_tpu.runtime.metrics import REGISTRY
 
 SCALE = 0.001
-
-_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load_tool(name):
-    path = os.path.join(_ROOT, "tools", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"_kc_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
 
 def _unavailable(reason: str) -> float:
     # read via collect() — counter() would REGISTER the series (with empty
@@ -503,88 +486,3 @@ class TestExplainVerboseAcceptance:
         assert off_rows == on_rows
         assert kernelcost.ledger_rows() == []
 
-
-class TestLadderAndRegress:
-    @pytest.fixture(autouse=True)
-    def _bench_env(self, monkeypatch, tmp_path):
-        """bench._make_runner setdefault()s a repo-level TRINO_TPU_CAP_STORE
-        into os.environ, which would leak past this class into the rest of
-        the pytest session. Pre-set the env to a tmp path (so the setdefault
-        is a no-op monkeypatch undoes)."""
-        monkeypatch.setenv("TRINO_TPU_CAP_STORE", str(tmp_path / "caps.json"))
-
-    def _micro_ladder(self, **kw):
-        import bench
-
-        kw.setdefault("scale", 0.001)
-        kw.setdefault("runs", 2)
-        kw.setdefault("queries", ("q6", "q1"))
-        return bench.run_ladder(**kw)
-
-    def test_ladder_emits_hardware_labeled_schema_v3(self):
-        bench_schema = _load_tool("bench_schema")
-        record = self._micro_ladder()
-        assert record["bench"] == "ladder"
-        assert record["schema_version"] >= 3
-        assert record["platform"] == jax.default_backend()
-        assert record["device"] and isinstance(record["device"], str)
-        assert isinstance(record["hardware_verified"], bool)
-        assert record["git_sha"]
-        for name in ("q6", "q1"):
-            r = record["results"][name]
-            assert r["median_secs"] > 0 and r["mad_secs"] >= 0
-            assert len(r["samples"]) == 2
-            assert r["fingerprint"] and len(r["fingerprint"]) == 16
-        assert bench_schema.validate_record(record) == []
-
-    def test_regress_passes_identical_and_flags_slowed(self, tmp_path):
-        """The acceptance pair: an identical re-run is clean; a
-        synthetically slowed run is a regression (noise-aware: the
-        +250ms synthetic delta dwarfs any micro-ladder MAD)."""
-        bench_regress = _load_tool("bench_regress")
-        base = self._micro_ladder(queries=("q6",))
-        identical = copy.deepcopy(base)
-        report = bench_regress.compare(base, identical)
-        assert report["overall"] == "ok"
-        assert all(
-            v["verdict"] in ("ok", "improvement")
-            for v in report["queries"].values()
-        )
-
-        slowed = self._micro_ladder(queries=("q6",), slowdown_secs=0.25)
-        report = bench_regress.compare(base, slowed)
-        assert report["overall"] == "regression"
-        assert report["queries"]["q6"]["verdict"] == "regression"
-
-        # the CLI contract: exit 0 clean, exit 1 on regression
-        b, s = tmp_path / "base.json", tmp_path / "slow.json"
-        b.write_text(json.dumps(base))
-        s.write_text(json.dumps(slowed))
-        assert bench_regress.main([str(b), str(b)]) == 0
-        assert bench_regress.main([str(b), str(s)]) == 1
-
-    def test_regress_result_changed_outranks_timing(self):
-        bench_regress = _load_tool("bench_regress")
-        base = self._micro_ladder(queries=("q6",))
-        cand = copy.deepcopy(base)
-        cand["results"]["q6"]["fingerprint"] = "0" * 16
-        report = bench_regress.compare(base, cand)
-        assert report["queries"]["q6"]["verdict"] == "result-changed"
-        assert report["overall"] == "regression"
-
-    def test_regress_platform_mismatch_incomparable(self):
-        bench_regress = _load_tool("bench_regress")
-        base = self._micro_ladder(queries=("q6",))
-        cand = copy.deepcopy(base)
-        cand["platform"] = "tpu"
-        report = bench_regress.compare(base, cand)
-        assert report["overall"] == "incomparable"
-
-    def test_every_checked_in_bench_json_validates(self):
-        bench_schema = _load_tool("bench_schema")
-        files = bench_schema.bench_files(_ROOT)
-        assert files, "no BENCH_*.json found at repo root"
-        problems = []
-        for path in files:
-            problems.extend(bench_schema.validate_file(path))
-        assert problems == [], problems

@@ -31,6 +31,15 @@ FROM lineitem WHERE l_shipdate <= DATE '1998-09-02'
 GROUP BY l_returnflag, l_linestatus ORDER BY l_returnflag, l_linestatus
 """
 
+Q6 = """
+SELECT sum(l_extendedprice * l_discount) AS revenue
+FROM lineitem
+WHERE l_shipdate >= DATE '1994-01-01'
+  AND l_shipdate < DATE '1994-01-01' + INTERVAL '1' YEAR
+  AND l_discount BETWEEN 0.06 - 0.01 AND 0.06 + 0.01
+  AND l_quantity < 24
+"""
+
 Q3 = """
 SELECT l_orderkey, sum(l_extendedprice * (1 - l_discount)) AS revenue,
        o_orderdate, o_shippriority
@@ -96,7 +105,9 @@ def _assert_matches(got, ref):
 
 class TestParity:
     @pytest.mark.parametrize(
-        "sql", [Q1, Q3, Q5, Q18, LEFT_JOIN], ids=["q1", "q3", "q5", "q18", "leftjoin"]
+        "sql",
+        [Q1, Q6, Q3, Q5, Q18, LEFT_JOIN],
+        ids=["q1", "q6", "q3", "q5", "q18", "leftjoin"],
     )
     def test_matches_in_core(self, runner, sql):
         ref = [tuple(r) for r in runner.execute(sql).rows]

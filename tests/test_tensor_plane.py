@@ -540,9 +540,8 @@ def _walk_nodes(node):
 
 @pytest.mark.slow
 class TestFusedTopKSweep:
-    """The bench-shaped sweep (slow tier): larger row counts, the dim x k
-    grid, fused vs serial bit-identity + strictly-fewer-launches on every
-    cell (bench.py vector_ab measures the same shape at 150k rows)."""
+    """The wide sweep (slow tier): larger row counts, the dim x k grid,
+    fused vs serial bit-identity + strictly-fewer-launches on every cell."""
 
     @pytest.mark.parametrize("dim", [1, 2, 7, 32, 64])
     @pytest.mark.parametrize("k", [1, 17, 100])

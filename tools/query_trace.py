@@ -50,7 +50,7 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _ROOT not in sys.path:
     sys.path.insert(0, _ROOT)
 
-# canned TPC-H queries for --q (kept tiny; bench.py owns the full ladder)
+# canned TPC-H queries for --q (kept tiny)
 QUERIES = {
     "q6": """
 SELECT sum(l_extendedprice * l_discount) AS revenue

@@ -32,7 +32,7 @@ class StatementResult:
     rows: List[list]
     stats: dict = field(default_factory=dict)
     # the serving coordinator's /v1/query/{id} URL — in a fleet this names
-    # the OWNER host (the bench fetches per-query attribution from it)
+    # the OWNER host (per-query attribution is fetched from it)
     info_uri: str = ""
 
 

@@ -19,7 +19,7 @@ mechanism that matters on this storage stack:
 
 Builds on the lake connector's partitioned-Parquet writer/metastore; the
 schema evolution/delete-file/compaction surface of real iceberg is out of
-scope and recorded as such in STATUS.md.
+scope.
 """
 
 from __future__ import annotations

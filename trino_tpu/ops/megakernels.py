@@ -87,7 +87,7 @@ DEFAULT_BUCKET_CAP = 32
 # would dwarf the fused win, so the fragment falls back to the sort path
 TABLE_ENTRY_LIMIT = 1 << 22
 
-# fused-op labels carried on flight spans and the bench per-fragment reports
+# fused-op labels carried on flight spans
 OP_JOIN = "hash_join"
 OP_AGG = "partial_agg"
 OP_REPART = "repartition"

@@ -49,7 +49,7 @@ DEVICE_REPARTITION_ENV = "TRINO_TPU_DEVICE_REPARTITION"
 
 
 def device_repartition_enabled() -> bool:
-    """Env kill-switch (default ON): the A/B bench and the bit-identity tests
+    """Env kill-switch (default ON): the bit-identity tests
     flip this to force the legacy host path."""
     return knobs.env_flag(DEVICE_REPARTITION_ENV, True)
 
@@ -246,7 +246,7 @@ def repartition_to_host(page: Page, key_idx: Sequence[int], n_parts: int):
     - TPU: the whole epilogue (hash -> stable cosort -> offsets/counts) runs
       in-program and ONE D2H fetches the contiguous page — host touches
       nothing per-partition.
-    - host-backed backends (CPU/GPU bench + test tiers): only the compiled
+    - host-backed backends (CPU/GPU test tiers): only the compiled
       elementwise hash runs in-program; contiguity is a numpy grouping pass
       (per-partition flatnonzero + one take per buffer, O(n_parts * n) with
       branch-free constants). Measured on XLA CPU, its sort/scatter

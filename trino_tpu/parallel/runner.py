@@ -335,13 +335,11 @@ class DistributedQueryRunner:
 
     def _execute_once(self, sql: str) -> QueryResult:
         if self._cluster_obs_enabled():
-            # planning phase measured for the profile's sums-to-wall
-            # contract (the FTE breakdown folds it in as a named phase)
-            t0 = time.monotonic()
-            subplan = self.plan_distributed(sql)
-            self._obs_planning_secs = time.monotonic() - t0
-        else:
-            subplan = self.plan_distributed(sql)
+            # the profile's planning phase opens here; _execute_fte closes
+            # it where its own first phase begins, so that the phases are
+            # contiguous (the sums-to-wall contract)
+            self._obs_planning_t0 = time.monotonic()
+        subplan = self.plan_distributed(sql)
         # per-query observability (stale entries from a previous query must
         # not leak into this one's fragment-width report)
         self.last_partition_counts = {}
@@ -643,11 +641,11 @@ class DistributedQueryRunner:
             from ..runtime.clusterobs import StageBreakdown
 
             obs_stages = StageBreakdown()
-            planning = getattr(self, "_obs_planning_secs", 0.0)
-            if planning:
-                obs_stages.add_phase("planning", planning)
-                self._obs_planning_secs = 0.0
             obs_enter = time.monotonic()
+            planning_t0 = getattr(self, "_obs_planning_t0", None)
+            if planning_t0 is not None:
+                obs_stages.add_phase("planning", obs_enter - planning_t0)
+                self._obs_planning_t0 = None
         self.last_stage_breakdown = obs_stages
         self.last_task_attempts: Dict[tuple, int] = {}
         # exchange payload routed through this coordinator (range edges only)
@@ -733,6 +731,10 @@ class DistributedQueryRunner:
         # function's wall time (the profile's 5% contract)
         obs_prev_fid: Optional[int] = None
         obs_mark = 0.0
+        # the result's attached phases once root_read has closed: what
+        # follows it (the journal's copy, the exchange directory's removal)
+        # is the phase "cleanup", closed in the finally below
+        obs_phases = None
         try:
             if obs_stages is not None:
                 obs_mark = time.monotonic()
@@ -944,7 +946,9 @@ class DistributedQueryRunner:
                 # so the bundle's copy is the surviving postmortem artifact)
                 journal.finished()
             if obs_stages is not None:
-                obs_stages.add_phase("root_read", time.monotonic() - obs_mark)
+                now = time.monotonic()
+                obs_stages.add_phase("root_read", now - obs_mark)
+                obs_mark = now
                 from ..runtime.fte_scheduler import attempt_log
 
                 snap = obs_stages.snapshot()
@@ -963,6 +967,7 @@ class DistributedQueryRunner:
                     qs["journal"], _ = _DJ.read(journal.path)
                 result.query_stats = qs
                 result.fte_query_id = query_id
+                obs_phases = qs["phases"]
             return result
         except BaseException as e:
             if ha_on:
@@ -988,6 +993,9 @@ class DistributedQueryRunner:
         finally:
             if not preserve:
                 mgr.remove_query(query_id)
+            if obs_phases is not None:
+                obs_phases["cleanup"] = time.monotonic() - obs_mark
+                obs_stages.add_phase("cleanup", obs_phases["cleanup"])
 
     def _fte_read_recovering(self, scheduler, read):
         """Coordinator-side exchange read under the same quarantine-and-rerun

@@ -90,7 +90,7 @@ class SanityContext:
     """What the checkers may consult beyond the plan tree itself. Memoizes
     the (node, path) walk so a full checker pass costs ONE traversal — the
     always-on final validation must stay invisible next to the optimizer's
-    own cost (BENCH_r12_sanity_ab.json)."""
+    own cost."""
 
     def __init__(self, types: Dict[str, Type], session=None, estimator=None):
         self.types = types or {}

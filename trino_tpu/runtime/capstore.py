@@ -1,8 +1,7 @@
 """Cross-query/cross-session persistence of adaptively tuned capacities.
 
-Round-4 verdict: AdaptiveQuery re-tunes per instance — Q18 paid 683 s of
-tuning for a 34 s steady state, and every bench child process re-ran the
-same grow/shrink compiles. The reference amortizes the analogous cost by
+AdaptiveQuery re-tunes per instance, so every new process re-ran the same
+grow/shrink compiles. The reference amortizes the analogous cost by
 caching generated classes per expression (sql/gen/PageFunctionCompiler.java:103
 result cache) and by reusing runtime stats across executions of a prepared
 statement; we amortize by persisting the tuned per-node capacities keyed by
@@ -20,7 +19,7 @@ a structural plan fingerprint:
   cached compile instead of a tuning loop.
 
 The store is a single JSON file written via atomic rename (tempfile +
-os.replace); concurrent bench children merge-on-write (read latest, update
+os.replace); concurrent processes merge-on-write (read latest, update
 own key, replace). Lost updates between two simultaneous writers cost a
 re-tune later, never corruption. Location: $TRINO_TPU_CAP_STORE, else an
 in-process dict (still deduplicates tuning within one session).

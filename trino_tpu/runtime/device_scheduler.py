@@ -1,8 +1,7 @@
 """Device batching plane: ragged multi-query packing of compatible fragments.
 
-BENCH_r09_concurrency.json is the motivating cliff: the mixed Q1/Q3/Q6/Q13
-replay saturates at ~6.5 qps with 2 clients and DEGRADES toward 4 qps at 16
-— the chip runs one fragment program at a time, so admission control merely
+The motivating case is a mixed Q1/Q3/Q6/Q13 replay from many clients: the
+chip runs one fragment program at a time, so admission control merely
 reorders a serial queue. The LLM-serving literature supplies the fix
 ("Ragged Paged Attention", arXiv:2604.15464: continuous batching of ragged,
 shape-heterogeneous requests into one kernel; "Query Processing on Tensor
@@ -136,7 +135,7 @@ _programs_counter = None
 
 def on_program_launch(n: int = 1) -> None:
     """One device program launch at the operator/fragment boundary — the
-    counter the batching A/B bench reads (fewer launches is the win).
+    counter the batching tests read (fewer launches is the win).
     Ticked per operator program on the serial path (executor._eval_node)
     and ONCE per packed ragged launch here; the counter object is memoized
     — the hot-path cost is one lock-guarded float add."""

@@ -1,10 +1,9 @@
 """Active-active coordinator fleet: partitioned admission, follower
 reads, and a multi-process protocol front.
 
-Round 19 measured the ceiling this plane removes: at 16 clients, p99 is
-8.5% device / 90.7% protocol-host with the GIL-contention probe showing
-38ms p99 against a 5ms sleep (BENCH_r19_hostpath_ab.json) — the chip is
-idle while ONE Python process's protocol loop serializes every client.
+The ceiling this plane is meant to remove: with many clients the chip is
+idle while ONE Python process's protocol loop serializes every client
+(not measured on the chip; no cell runs the fleet).
 "Accelerating Presto with GPUs" (PAPERS.md) names the pattern: once the
 device path is fast, the host/protocol path must scale OUT. The round-16
 serving fabric (runtime/ha.py) already made a query outlive its
@@ -42,7 +41,7 @@ coordinator; this module makes the standby fleet *serve*:
   targets), so concurrent client protocol loops stop convoying on one
   GIL. Each front process is a FULL coordinator in the lease/journal
   protocol. ``python -m trino_tpu.runtime.fleet`` serves one such process
-  (bench.py fleet_ab and deployments fork N of them).
+  (deployments fork N of them).
 
 Everything is gated off by default: with ``$TRINO_TPU_FLEET_DIR`` unset
 no membership object, no heartbeat thread, and no routing branch exists —
@@ -247,7 +246,7 @@ class FleetMember:
         this node or the owner's record is unreadable). Also the
         reassignment observation point: a member that left the live set
         since the last look is counted and marked in the flight recorder —
-        the smoke and the bench read failover off this signal."""
+        the smoke reads failover off this signal."""
         live = self.live_members()
         departed = self._known_live - set(live) - {self.node_id}
         self._known_live = set(live)
@@ -404,7 +403,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     binds the shared client-facing port with SO_REUSEPORT (kernel
     load-balances accepts across the forked siblings) plus a unique
     per-node port that membership advertises as the redirect/proxy
-    target. bench.py fleet_ab forks N of these."""
+    target. A deployment forks N of these."""
     import argparse
     import signal
     import sys

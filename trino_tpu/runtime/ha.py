@@ -578,7 +578,8 @@ def resume_fte_query(runner, journal_path: str):
 
     # profile breakdown contract: everything from handoff entry to the
     # stage loop counts as the resumed query's planning phase
-    obs_t0 = time.monotonic() if _obs_enabled(runner.session) else None
+    if _obs_enabled(runner.session):
+        runner._obs_planning_t0 = time.monotonic()
     state = ResumeState.load(journal_path)
     if not state.sql:
         raise ValueError(f"journal {journal_path!r} has no begin record")
@@ -601,8 +602,6 @@ def resume_fte_query(runner, journal_path: str):
         runner.last_partition_counts = {}
         runner.last_tier, runner.last_tier_reason = "fte", None
         subplan = runner.plan_distributed(state.sql)
-        if obs_t0 is not None:
-            runner._obs_planning_secs = time.monotonic() - obs_t0
         result = runner._execute_fte(subplan, sql=state.sql, resume=state)
         end["outcome"] = "resumed"
         end["adopted"] = getattr(runner, "last_fte_adopted", 0)

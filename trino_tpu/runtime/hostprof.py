@@ -37,8 +37,7 @@ host/GIL contention" from a hand diagnosis into three measurements:
   federated cluster tables for free.
 
 ``system.runtime.host_profile`` (connectors/system.py) serves the live
-collapsed-stack aggregation; ``bench.py hostpath_ab`` is the capstone
-consumer (BENCH_r19_hostpath_ab.json).
+collapsed-stack aggregation.
 """
 
 from __future__ import annotations
@@ -481,8 +480,7 @@ class ContentionProbe:
             return list(self._buf)
 
     def summary(self) -> dict:
-        """p50/p99/max lateness (seconds) over the ring — the number the
-        hostpath bench quotes next to p99 latency."""
+        """p50/p99/max lateness (seconds) over the ring."""
         js = sorted(self.jitters())
         if not js:
             return {"samples": 0, "p50_secs": 0.0, "p99_secs": 0.0,

@@ -1,8 +1,8 @@
 """Out-of-core execution for ARBITRARY fragment trees — joins included.
 
-Round-4 verdict: `runtime/streaming.py` streams exactly one plan shape
-(scan -> filter/project -> one aggregation), so no join had ever executed
-above SF1. The reference streams *any* operator pipeline over
+A split-at-a-time aggregation handles exactly one plan shape (scan ->
+filter/project -> one aggregation), so no join runs over data larger than
+the device. The reference streams *any* operator pipeline over
 larger-than-memory data (operator/Driver.java:372 page pull;
 operator/join/spilling/HashBuilderOperator.java:68 partitioned spill state
 machine; SpillableHashAggregationBuilder). This module is the TPU-first
@@ -434,12 +434,12 @@ class OutOfCoreRunner:
         self.stores: Dict[int, BucketStore] = {}
         # observability plane: the runner's stats collector (joins an
         # enclosing query collector when one is active — e.g. a server-side
-        # query whose plan routed out-of-core). bench.py and the trace
-        # tooling read the plane via collector.snapshot().
+        # query whose plan routed out-of-core). The trace tooling reads
+        # the plane via collector.snapshot().
         self.collector = obs.current_collector() or obs.QueryStatsCollector()
         self.stats: Dict[str, object] = {
             "fragments": len(self.subplan.fragments),
-            # pipeline overlap evidence (bench reads these): seconds the
+            # pipeline overlap evidence: seconds the
             # main loop spent inside device dispatch+sync vs blocked on
             # prefetch results, plus prefetch hit/miss and shape-class counts
             "device_busy_secs": 0.0,
@@ -769,8 +769,8 @@ class OutOfCoreRunner:
                     page, overflow, actuals = fn(scan_page, remote_pages)
                     ovf = int(np.asarray(overflow))  # blocks until device done
                 elapsed = time.perf_counter() - t0
-                # attribute trace+compile time separately so the bench's
-                # device_busy_frac reflects actual overlap, not cold compiles
+                # attribute trace+compile time separately so device_busy_secs
+                # reflects actual overlap, not cold compiles
                 try:
                     compiled = n_compiled is not None and fn._cache_size() > n_compiled
                 except Exception:
@@ -833,7 +833,7 @@ class OutOfCoreRunner:
         with RECORDER.span("unit_fallback", "bucket", fragment=fid):
             page = run_fragment_partition(ex, frag.root)
         # host-synced op-at-a-time execution, NOT device-saturating work —
-        # booked separately so device_busy_frac stays honest
+        # booked separately so device_busy_secs stays honest
         dt = time.perf_counter() - t0
         self.stats["fallback_secs"] += dt
         self.collector.add_time("fallback_secs", dt, fragment=fid)

@@ -3,10 +3,10 @@
 Reference blueprint: the end state of PageFunctionCompiler-style codegen taken to
 its XLA conclusion — instead of operator-at-a-time programs, an entire join-free
 fragment (scan -> filter -> project -> aggregate -> topn) traces into ONE fused
-XLA program. This is the hot path bench.py times and the unit __graft_entry__
-exposes. Joins need a host sync to size their output (see executor.py), so plans
-containing joins fall back to the operator-at-a-time executor; fixed-capacity
-join tracing is a later-round extension.
+XLA program. This is the unit __graft_entry__ exposes. Joins need a host sync
+to size their output (see executor.py), so compile_query refuses plans that
+contain joins; runtime/adaptive.py and parallel/mesh_runner.py trace joins at
+a static capacity with an overflow count (_TracedExecutor).
 """
 
 from __future__ import annotations
@@ -219,37 +219,5 @@ def compile_query(
         rel = executor.eval(root.source)
         cols = [rel.column_for(s) for s in root.symbols]
         return Page(tuple(cols), rel.page.active)
-
-    return run, example_pages, list(root.column_names)
-
-
-def compile_query_joins(
-    plan: LogicalPlan,
-    metadata: Metadata,
-    session: Session,
-    join_capacity_factor: float = 1.0,
-) -> Tuple[Callable[..., Tuple[Page, jnp.ndarray]], List[Page], List[str]]:
-    """Whole-query tracing INCLUDING joins/semijoins: one XLA program for the
-    entire plan, static join capacities (probe_cap x factor), and a summed
-    overflow scalar the caller must host-check (retry with a larger factor on
-    overflow — the single-chip analogue of mesh_runner's retry loop).
-
-    This collapses a join query's dozens of operator programs (each its
-    own compile and host sync) into ONE compile and ZERO mid-plan host
-    syncs."""
-    if not is_traceable(plan, allow_joins=True):
-        raise ExecutionError("plan contains non-traceable nodes")
-    example_pages, root = _prepare_traced(plan, metadata, session)
-
-    def run(*pages: Page):
-        executor = _TracedExecutor(
-            plan, metadata, session, dict(enumerate(pages)), join_capacity_factor
-        )
-        rel = executor.eval(root.source)
-        cols = [rel.column_for(s) for s in root.symbols]
-        overflow = jnp.int64(0)
-        for o in executor.overflows:
-            overflow = overflow + o.astype(jnp.int64)
-        return Page(tuple(cols), rel.page.active), overflow
 
     return run, example_pages, list(root.column_names)
