@@ -16,6 +16,7 @@ import pytest
 from trino_tpu.runtime.tracing import (
     DROPPED_COUNTER,
     STATEMENT,
+    STATS_FEEDBACK,
     TRACER,
     Tracer,
     children,
@@ -333,7 +334,11 @@ class TestRootLifetime:
         tree = TRACER.spans(res.trace_id)
         root = tree[0]
         assert root.name == STATEMENT and root.end_ns is not None
-        assert [s.name for s in tree if s.parent_id == root.span_id] == IN_ROOT[2:]
+        # nobody offered a place to run the statistics feedback later: it is
+        # inline, the root's last child (tests/test_feedback_deferral.py)
+        assert [s.name for s in tree if s.parent_id == root.span_id] == (
+            IN_ROOT[2:] + [STATS_FEEDBACK]
+        )
         assert root.attributes["host_syncs"] == sum(
             s.name.startswith("sync:") for s in tree
         )

@@ -39,11 +39,13 @@ class _StoredTable:
     # first-class fixture): rows are hash-split on write, split i == bucket i
     bucketed_by: Tuple[str, ...] = ()
     bucket_count: int = 0
+    # live rows of ``pages``, kept by whoever writes ``pages`` (insert,
+    # replace_pages: under the connector's lock, counted on the device when
+    # the rows change), so that statistics read no page
+    rows: int = 0
 
     def row_count(self) -> int:
-        return sum(
-            int(np.asarray(p.active).sum()) for p in self.pages if p is not None
-        )
+        return self.rows
 
 
 class MemoryConnector(Connector):
@@ -141,6 +143,7 @@ class MemoryConnector(Connector):
                 )
             rows = int(page.num_rows())  # counted on the device: one integer read
             self._bump(name)
+            table.rows += rows
             if not table.bucketed_by:
                 table.pages.append(page)
                 return rows
@@ -194,8 +197,12 @@ class MemoryConnector(Connector):
             self._bump(name)
             if not table.bucketed_by:
                 table.pages = list(pages)
+                counts = [p.num_rows() for p in table.pages if p is not None]
+                # one integer read for all the pages
+                table.rows = int(jnp.stack(counts).sum()) if counts else 0
                 return
             table.pages = []
+            table.rows = 0  # each insert below adds what it writes
             for p in pages:
                 if p is not None:
                     self.insert(name, p)

@@ -318,14 +318,18 @@ class DistributedQueryRunner:
         from ..runtime import statstore
 
         query_id = statstore.current_query_id() or ""
-        for frag in subplan.fragments:
-            if frag.fragment_id in skip_fragments:
-                continue
-            statstore.observe_query(
-                LogicalPlan(frag.root, subplan.types), self.metadata,
-                self.session, collector, node_actuals, query_id=query_id,
-                fragment=frag.fragment_id,
-            )
+        # inline whoever calls: the staged paths read the collector's
+        # planNodes as soon as this returns
+        with statstore.feedback_span(query_id) as span:
+            span.attributes["nodes"] = len(node_actuals)
+            for frag in subplan.fragments:
+                if frag.fragment_id in skip_fragments:
+                    continue
+                statstore.observe_query(
+                    LogicalPlan(frag.root, subplan.types), self.metadata,
+                    self.session, collector, node_actuals, query_id=query_id,
+                    fragment=frag.fragment_id,
+                )
 
     def _cluster_obs_enabled(self) -> bool:
         try:

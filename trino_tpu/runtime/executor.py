@@ -323,6 +323,55 @@ class OperatorStats:
     compile_secs: float = 0.0
 
 
+def resolve_actuals(
+    actuals: Dict[int, dict], dyn_filters: Dict[int, tuple]
+) -> Dict[int, dict]:
+    """Resolve an executor's deferred per-node actuals (``actuals``,
+    ``dyn_filters``) to plain ints, once the query has drained
+    (statstore.observe_query's input). A function of the two dicts and not
+    of the executor: the served path runs it after the statement is
+    FINISHED (statstore.Feedback), holding the 4-byte counts and the
+    bounded masks and neither the executor nor its pages. Counting
+    runs in NUMPY on the host (np.asarray of a drained mask is free on
+    the CPU backend, one small D2H elsewhere) — jnp reductions here
+    would dispatch a fresh XLA program per mask and dominate the plane's
+    cost (the Q6 A/B regression that numpy counting removes)."""
+    import numpy as np
+
+    out: Dict[int, dict] = {}
+    for key, ent in actuals.items():
+        rows = sum(int(np.asarray(c)) for c in ent["counts"])
+        null_frac = None
+        if ent["valids"] and rows > 0:
+            nulls = cells = 0
+            for active, valids in ent["valids"]:
+                a = np.asarray(active)
+                page_rows = int(np.count_nonzero(a))
+                for v in valids:
+                    nulls += int(np.count_nonzero(a & ~np.asarray(v)))
+                    cells += page_rows  # THIS page's rows, not the total
+            null_frac = (nulls / cells) if cells else None
+        out[key] = {
+            "rows": rows,
+            "capacity": ent["capacity"],
+            "bytes": ent["bytes"],
+            "null_frac": null_frac,
+        }
+    # dynamic-filter hit rate resolves HERE, per executor: the synthetic
+    # filter node only exists in this executor's lifetime, and pre/post
+    # rows from different partitions must pair up before any summing
+    # (post[last partition] / pre[all partitions] would understate the
+    # selectivity by the partition count)
+    for join_id, (fnode_id, probe_id) in dyn_filters.items():
+        ent = out.get(join_id)
+        post = out.get(fnode_id)
+        pre = out.get(probe_id)
+        if ent is not None and post is not None and pre is not None:
+            ent["dyn_post"] = post["rows"]
+            ent["dyn_pre"] = pre["rows"]
+    return out
+
+
 class PlanExecutor:
     """Evaluates a LogicalPlan bottom-up. One instance per query execution."""
 
@@ -614,46 +663,8 @@ class PlanExecutor:
             )
 
     def finalize_actuals(self) -> Dict[int, dict]:
-        """Resolve the deferred per-node actuals to plain ints — called once
-        after the query drained (statstore.observe_query's input). Counting
-        runs in NUMPY on the host (np.asarray of a drained mask is free on
-        the CPU backend, one small D2H elsewhere) — jnp reductions here
-        would dispatch a fresh XLA program per mask and dominate the plane's
-        cost (the Q6 A/B regression that numpy counting removes)."""
-        import numpy as np
-
-        out: Dict[int, dict] = {}
-        for key, ent in self.actuals.items():
-            rows = sum(int(np.asarray(c)) for c in ent["counts"])
-            null_frac = None
-            if ent["valids"] and rows > 0:
-                nulls = cells = 0
-                for active, valids in ent["valids"]:
-                    a = np.asarray(active)
-                    page_rows = int(np.count_nonzero(a))
-                    for v in valids:
-                        nulls += int(np.count_nonzero(a & ~np.asarray(v)))
-                        cells += page_rows  # THIS page's rows, not the total
-                null_frac = (nulls / cells) if cells else None
-            out[key] = {
-                "rows": rows,
-                "capacity": ent["capacity"],
-                "bytes": ent["bytes"],
-                "null_frac": null_frac,
-            }
-        # dynamic-filter hit rate resolves HERE, per executor: the synthetic
-        # filter node only exists in this executor's lifetime, and pre/post
-        # rows from different partitions must pair up before any summing
-        # (post[last partition] / pre[all partitions] would understate the
-        # selectivity by the partition count)
-        for join_id, (fnode_id, probe_id) in self.dyn_filters.items():
-            ent = out.get(join_id)
-            post = out.get(fnode_id)
-            pre = out.get(probe_id)
-            if ent is not None and post is not None and pre is not None:
-                ent["dyn_post"] = post["rows"]
-                ent["dyn_pre"] = pre["rows"]
-        return out
+        """This executor's actuals resolved (:func:`resolve_actuals`)."""
+        return resolve_actuals(self.actuals, self.dyn_filters)
 
     def _account(self, node: PlanNode, rel: Relation) -> None:
         """Memory accounting per operator output (lib/trino-memory-context)."""

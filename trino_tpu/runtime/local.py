@@ -9,6 +9,7 @@ fixture every engine test builds on.
 from __future__ import annotations
 
 import datetime
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
@@ -20,7 +21,7 @@ from ..sql import parse_statement
 from ..sql import tree as t
 from ..planner import LogicalPlanner, optimize, format_plan
 from ..planner.plan import LogicalPlan
-from .executor import PlanExecutor
+from .executor import PlanExecutor, resolve_actuals
 
 
 def _exclusive_times(executor, node, s):
@@ -866,19 +867,28 @@ class LocalQueryRunner:
                             CACHES.result.store(rkey, entry, self.session)
                     # statistics feedback plane: fold per-node actuals into
                     # the collector, flag mis-estimates, feed the history
-                    # store (runtime/statstore.py). Post-drain, off the hot
-                    # path; a feedback failure must never fail the query.
+                    # store (runtime/statstore.py). Where the caller offers
+                    # a place to run it later (the QueryManager: after
+                    # FINISHED, once the statement's root has closed) it is
+                    # handed over below and costs the statement nothing;
+                    # everywhere else it runs here, post-drain and before
+                    # this returns. It holds the executor's actuals, not the
+                    # executor; a feedback failure never fails the query.
+                    feedback = None
                     if executor.collect_actuals:
-                        try:
-                            from . import statstore
+                        from . import statstore
 
-                            statstore.observe_query(
-                                plan, self.metadata, self.session, collector,
-                                executor.finalize_actuals(),
-                                query_id=self._feedback_query_id(root),
-                            )
-                        except Exception:  # noqa: BLE001 — observability only
-                            pass
+                        feedback = statstore.Feedback(
+                            plan, self.metadata, self.session, collector,
+                            functools.partial(
+                                resolve_actuals, executor.actuals,
+                                executor.dyn_filters,
+                            ),
+                            query_id=self._feedback_query_id(root),
+                        )
+                        if not statstore.feedback_is_deferred():
+                            feedback.run()
+                            feedback = None
             except BaseException:
                 if rkey is not None:
                     # a shared-tier single-flight lease claimed at lookup
@@ -937,6 +947,9 @@ class LocalQueryRunner:
                     set(executor.cache_provenance.values())
                 )
             result.query_stats = snap
+            if feedback is not None:
+                # the run writes its planNodes into this snapshot
+                feedback.defer(snap, hold_recorder=recorder_held)
             return result
 
         from .failure import execute_with_retry
@@ -1397,16 +1410,14 @@ class LocalQueryRunner:
         estimator = make_estimator(self.metadata, plan.types, self.session)
 
         # the analyzed run feeds the same history/misestimate plane a plain
-        # execution does (Presto HBO records from analyze too)
-        try:
-            collector = obs.current_collector() or obs.QueryStatsCollector()
-            statstore.observe_query(
-                plan, self.metadata, self.session, collector,
-                executor.finalize_actuals(),
-                query_id=statstore.current_query_id() or "",
-            )
-        except Exception:  # noqa: BLE001 — observability only
-            pass
+        # execution does (Presto HBO records from analyze too); inline
+        # whoever calls
+        statstore.Feedback(
+            plan, self.metadata, self.session,
+            obs.current_collector() or obs.QueryStatsCollector(),
+            executor.finalize_actuals,
+            query_id=statstore.current_query_id() or "",
+        ).run()
 
         def fmt_rows(v) -> str:
             if v is None:

@@ -138,6 +138,11 @@ class QueryExecution:
     rows: Optional[List[tuple]] = None
     error: Optional[str] = None
     error_type: Optional[str] = None
+    # the statement's statistics feedback (runtime/statstore.Feedback), which
+    # the runner handed over and did not run: run once the root has closed
+    # (QueryManager.close_statement), or by a reader that joins it
+    feedback: List[Any] = field(default_factory=list, repr=False)
+    _feedback_sent: int = field(default=0, repr=False)  # how many went to the pool
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
     _done: threading.Event = field(default_factory=threading.Event, repr=False)
     _state_listeners: List[Callable] = field(default_factory=list, repr=False)
@@ -391,6 +396,7 @@ class QueryManager:
         try:
             from .clusterobs import maybe_persist_profile
 
+            self.join_feedback(q)  # the bundle holds the plan's planNodes
             maybe_persist_profile(
                 sess,
                 query_id=q.query_id,
@@ -470,14 +476,35 @@ class QueryManager:
             return CancelResult.CANCELED
         return CancelResult.TERMINAL  # already terminal (or lost the race)
 
-    @staticmethod
-    def close_statement(q: QueryExecution, **attributes) -> None:
+    def close_statement(self, q: QueryExecution, **attributes) -> None:
         """Ends the statement's root span (once): its last page has been
-        sent, or its client went away (cancel, expiry from the history)."""
+        sent, or its client went away (cancel, expiry from the history).
+        Nobody waits for this statement any more, so its statistics feedback
+        goes to the pool from here: never before FINISHED, and not at
+        FINISHED either, where its plain Python would take the interpreter
+        from the HTTP thread that is about to send the answer."""
         from .tracing import TRACER
 
         if q.stats.root is not None:
             TRACER.close_span(q.stats.root, **attributes)
+        self._schedule_feedback(q)
+
+    def _schedule_feedback(self, q: QueryExecution) -> None:
+        with q._lock:
+            # sent once; `feedback` keeps them for readers to join
+            pending, q._feedback_sent = q.feedback[q._feedback_sent:], len(q.feedback)
+        for fb in pending:
+            try:
+                self._pool.submit(fb.run)
+            except RuntimeError:  # the pool is shut down
+                fb.run()
+
+    @staticmethod
+    def join_feedback(q: QueryExecution) -> None:
+        """For readers of ``q.query_stats["planNodes"]``: the statement's
+        feedback has run when this returns (by this thread, if by no other)."""
+        for fb in list(q.feedback):
+            fb.run()
 
     def kill(self, query_id: str, message: str = "") -> CancelResult:
         """system.runtime.kill_query semantics (KillQueryProcedure): fail the
@@ -642,7 +669,7 @@ class QueryManager:
                 kwargs["user"] = q.user
             if self._fn_accepts_client and q.client_ctx is not None:
                 kwargs["client"] = q.client_ctx
-            from .statstore import query_id_scope
+            from .statstore import deferring_feedback, query_id_scope
 
             # memory scope: executor contexts built on this thread attach to
             # the pool under this query's id (blocking reservations; the
@@ -653,9 +680,12 @@ class QueryManager:
             # everything nested on this thread belongs to this query (no-op
             # while the recorder is off). It times nothing: the runner's
             # spans under the statement's root do.
+            # deferring_feedback: the place the runner's statistics
+            # feedback is handed to, to be run after FINISHED.
             with query_id_scope(q.query_id), memory_scope(
                 q.query_id, self._memory_pool
-            ), RECORDER.span("query_exec", "query", query_id=q.query_id):
+            ), RECORDER.span("query_exec", "query", query_id=q.query_id), \
+                    deferring_feedback() as feedback:
                 if self._wants("split_completed"):
                     from .events import split_events
 
@@ -678,7 +708,14 @@ class QueryManager:
             q.rows = result.rows
             q.stats.rows = len(result.rows)
             q.stats.cpu_time = time.thread_time() - cpu0
+            # before FINISHED: from then on the last page can go out and
+            # close the root, which is what sends the feedback to the pool
+            q.feedback = feedback
             q.transition(QueryState.FINISHED)
+            if q.stats.root is None or q.stats.root.end_ns is not None:
+                # canceled while it ran: the root closed before the
+                # feedback was here to be sent
+                self._schedule_feedback(q)
             REGISTRY.counter(
                 "trino_tpu_queries_finished_total", help="queries finished"
             ).inc()

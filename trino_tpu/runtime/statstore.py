@@ -10,6 +10,17 @@ closes the loop on *cardinality*:
   mask (one dict store per operator per page — no device op, no host sync on
   the hot path); :func:`observe_query` folds them into the per-query
   ``QueryStatsCollector`` once the query has drained.
+- **when the feedback runs** (:class:`Feedback`): on the served path, where
+  the ``QueryManager`` offers a place (:class:`deferring_feedback`), after
+  the statement is FINISHED and its root span has closed, on the manager's
+  pool: between a statement's submission and its answer the plane costs
+  the row-count scalars the executor launches and nothing else. A caller
+  that offers no place (a direct ``LocalQueryRunner.execute``, ``EXPLAIN
+  ANALYZE``, the distributed runner's fragments) gets it inline, after the
+  drain and before ``execute`` returns. Whatever reads what the feedback
+  writes (:func:`operator_stats_log`, :func:`load_history`, :func:`lookup`,
+  a query's ``planNodes``) joins the pending feedback first
+  (:func:`join_pending`), so no reader sees less than it did inline.
 - **history store**: per-node estimate-vs-actual records persisted under the
   capstore structural plan fingerprint (``$TRINO_TPU_STATS_HISTORY`` file,
   atomic-rename merge-on-write; bounded in-process dict otherwise). Entries
@@ -40,7 +51,8 @@ import tempfile
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional, Sequence
+from contextlib import contextmanager
+from typing import Callable, Dict, List, Optional, Sequence
 
 from .. import knobs
 
@@ -71,6 +83,156 @@ class query_id_scope:
     def __exit__(self, *exc):
         _qid_tls.qid = self._prev
         return False
+
+# ------------------------------------------------- when the feedback runs
+
+_feedback_tls = threading.local()
+# feedback handed to a caller and not yet run, in the order the statements
+# finished: a run takes its elders first, so records land in that order
+_PENDING: "Dict[Feedback, None]" = {}
+_PENDING_LOCK = threading.Lock()
+
+
+class deferring_feedback:
+    """Offer this thread's statements a place to run their feedback later:
+    inside the scope a runner hands each :class:`Feedback` over (the list
+    ``with`` gives) and does not run it; the caller runs them once the
+    statement's answer is out. Outside any scope the feedback runs inline."""
+
+    def __enter__(self) -> "List[Feedback]":
+        self._prev = getattr(_feedback_tls, "sink", None)
+        sink = _feedback_tls.sink = []
+        return sink
+
+    def __exit__(self, *exc):
+        _feedback_tls.sink = self._prev
+        return False
+
+
+def feedback_is_deferred() -> bool:
+    """Whether this thread's caller has offered a place to run feedback."""
+    return getattr(_feedback_tls, "sink", None) is not None
+
+
+@contextmanager
+def feedback_span(query_id: str, deferred: bool = False):
+    """One statement's feedback on the statement timeline and in the metrics
+    registry: the span ``stats_feedback`` (a root of its own tree when
+    deferred, since its statement's root has closed; under the current span
+    when inline) and ``trino_tpu_stats_feedback_total{path}`` /
+    ``trino_tpu_stats_feedback_seconds``. A thread inside one joins no
+    pending feedback: it is one, and would wait for itself."""
+    from .tracing import STATS_FEEDBACK, TRACER
+
+    scope = TRACER.root if deferred else TRACER.span
+    prev = getattr(_feedback_tls, "running", False)
+    _feedback_tls.running = True
+    t0 = time.perf_counter()
+    try:
+        with scope(STATS_FEEDBACK, query_id=query_id, deferred=deferred) as span:
+            yield span
+    finally:
+        _feedback_tls.running = prev
+        _metric_feedback("deferred" if deferred else "inline").inc()
+        _metric_feedback_seconds().observe(time.perf_counter() - t0)
+
+
+class Feedback:
+    """One executed statement's feedback, run once: the executor's actuals
+    resolved to integers and :func:`observe_query`. It holds the plan, the
+    collector and ``finalize`` (a closure over the executor's actuals: 4-byte
+    device scalars and masks of at most ``_NULL_FRAC_CAP`` rows), not the
+    executor or its pages. ``run()`` inline; ``defer()`` hands it to the
+    place the caller offered, and then the first of the manager's pool and
+    any reader to get to it runs it. Failures are swallowed: observability
+    never fails a statement."""
+
+    def __init__(self, plan, metadata, session, collector,
+                 finalize: Callable[[], Dict[int, dict]], query_id: str = ""):
+        self.plan = plan
+        self.metadata = metadata
+        self.session = session
+        self.collector = collector
+        self.finalize = finalize
+        self.query_id = query_id
+        # the query's stats snapshot, taken before a deferred run: the run
+        # writes the collector's planNodes into it
+        self.snapshot: Optional[dict] = None
+        self.deferred = False
+        self._holds_recorder = False
+        self._claim = threading.Lock()
+        self._done = threading.Event()
+
+    def defer(self, snapshot: Optional[dict] = None,
+              hold_recorder: bool = False) -> None:
+        """Hands it to the place the caller offered. ``hold_recorder``: the
+        statement recorded its flight (``flight_recorder``), so the recorder
+        stays on until the feedback's events are in it too."""
+        from .observability import RECORDER
+
+        self.deferred = True
+        self.snapshot = snapshot
+        if hold_recorder:
+            RECORDER.acquire()
+            self._holds_recorder = True
+        with _PENDING_LOCK:
+            _PENDING[self] = None
+        _feedback_tls.sink.append(self)
+
+    def run(self) -> None:
+        """Runs the feedback if nobody has, else waits for who did. A
+        deferred one first takes those handed over before it."""
+        if self.deferred and not self._done.is_set():
+            _join(upto=self)
+        self._run_once()
+
+    def _run_once(self) -> None:
+        if not self._claim.acquire(blocking=False):
+            self._done.wait()
+            return
+        try:
+            with feedback_span(self.query_id, self.deferred) as span:
+                actuals = self.finalize()
+                span.attributes["nodes"] = len(actuals)
+                observe_query(
+                    self.plan, self.metadata, self.session, self.collector,
+                    actuals, query_id=self.query_id,
+                )
+            if self.snapshot is not None:
+                self.snapshot["planNodes"] = (
+                    self.collector.snapshot()["planNodes"]
+                )
+        except Exception:  # noqa: BLE001 — observability only
+            pass
+        finally:
+            with _PENDING_LOCK:
+                _PENDING.pop(self, None)
+            if self._holds_recorder:
+                from .observability import RECORDER
+
+                RECORDER.release()
+            # what it held of the statement goes with it
+            self.plan = self.collector = self.finalize = None
+            self._done.set()
+
+
+def _join(upto: "Optional[Feedback]" = None) -> None:
+    if getattr(_feedback_tls, "running", False):
+        return
+    with _PENDING_LOCK:
+        pending = list(_PENDING)
+    for fb in pending:
+        if fb is upto:
+            break
+        fb._run_once()
+
+
+def join_pending() -> None:
+    """Readers of what the feedback writes call this first: every feedback
+    handed over so far has run when it returns. One not yet started is run
+    here, so nothing waits on a thread that may never come."""
+    _join()
+
 
 # in-process fallback store, bounded (oldest fingerprints evicted) so a
 # long-lived coordinator recording every query shape cannot grow unbounded
@@ -285,6 +447,7 @@ def _read_file_locked(path: str) -> Dict[str, dict]:
 def load_history() -> Dict[str, dict]:
     """Full key -> entry map (the overlay estimator and the system table
     both read it). A snapshot: mutations go through :func:`record_history`."""
+    join_pending()
     path = history_path()
     with _lock:
         if path is None:
@@ -295,6 +458,7 @@ def load_history() -> Dict[str, dict]:
 def lookup(key: str) -> Optional[dict]:
     if not key:
         return None
+    join_pending()
     path = history_path()
     with _lock:
         if path is None:
@@ -382,7 +546,9 @@ def record_history(entries: Dict[str, dict]) -> None:
 
 def clear_memory() -> None:
     """Test hook: drop the in-process store, read cache, and the
-    operator-stats ring."""
+    operator-stats ring (what is pending writes into them first, and not
+    later into the next test's)."""
+    join_pending()
     with _lock:
         _memory_store.clear()
         _file_cache.clear()
@@ -396,6 +562,7 @@ def clear_memory() -> None:
 
 
 def operator_stats_log() -> List[dict]:
+    join_pending()
     with _OP_STATS_LOCK:
         return list(_OP_STATS)
 
@@ -466,8 +633,9 @@ def observe_query(
     for multi-partition runs). ``fragment``: distributed callers observe
     once per fragment (actuals pre-aggregated across partitions and FTE
     attempts — only the winning attempt of a speculative pair was folded
-    in). Runs once per query AFTER the result drained; never on the hot
-    path.
+    in). Runs once per query AFTER the result drained: on the served path
+    after the statement is FINISHED (:class:`Feedback`, deferred), and
+    inline, between the drain and the caller's return, everywhere else.
     """
     from ..planner.plan import JoinNode, visit_plan
     from ..planner.stats import make_estimator
@@ -488,7 +656,12 @@ def observe_query(
     misestimates = 0
     plan_fp = node_fingerprint(plan.root)
 
-    with RECORDER.span("stats_feedback", "stats", query=query_id):
+    # `query_id` makes the span a window of the cluster trace's attribution
+    # (clusterobs.filter_events_for_query): a deferred run is outside its
+    # statement's `query_exec` window
+    with RECORDER.span(
+        "stats_feedback", "stats", query=query_id, query_id=query_id
+    ):
         for idx, node in enumerate(ordered):
             ent = actuals.get(id(node))
             if ent is None:
@@ -598,5 +771,31 @@ def _metric_histogram():
             "trino_tpu_cardinality_qerror",
             help="per-node cardinality q-error (estimate vs actual)",
             buckets=(1.0, 1.5, 2.0, 4.0, 8.0, 16.0, 64.0, 256.0, 1024.0),
+        )
+    return m
+
+
+def _metric_feedback(path: str):
+    m = _metric_cache.get("feedback_" + path)
+    if m is None:
+        from .metrics import REGISTRY
+
+        m = _metric_cache["feedback_" + path] = REGISTRY.counter(
+            "trino_tpu_stats_feedback_total", labels={"path": path},
+            help="statements' statistics feedback run, by when: deferred "
+                 "(after FINISHED, off the statement's path) or inline",
+        )
+    return m
+
+
+def _metric_feedback_seconds():
+    m = _metric_cache.get("feedback_seconds")
+    if m is None:
+        from .metrics import DEFAULT_BUCKETS, REGISTRY
+
+        m = _metric_cache["feedback_seconds"] = REGISTRY.histogram(
+            "trino_tpu_stats_feedback_seconds",
+            help="seconds one statement's statistics feedback took",
+            buckets=DEFAULT_BUCKETS,
         )
     return m

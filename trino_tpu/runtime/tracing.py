@@ -37,6 +37,7 @@ from jax.profiler import TraceAnnotation
 # the span names of the served path (ISSUE 26's table; PERF.md section 3
 # names the metric each is for)
 STATEMENT = "statement"
+STATS_FEEDBACK = "stats_feedback"
 OP_PREFIX = "op:"
 SYNC_PREFIX = "sync:"
 
@@ -244,6 +245,16 @@ class Tracer:
         parent = stack[-1] if stack else None
         return _Scope(
             self, self._new(name, parent, trace_id, root, cat, attributes), stack
+        )
+
+    def root(self, name: str, trace_id: Optional[str] = None, **attributes):
+        """A span that starts a tree of its own whatever this thread's
+        current span is: work done on a statement's behalf once its own
+        root has closed (the deferred statistics feedback), which a reader
+        may run from inside another statement."""
+        return _Scope(
+            self, self._new(name, None, trace_id, True, None, attributes),
+            self._stack(),
         )
 
     def statement(self, sql: str = ""):

@@ -1249,6 +1249,7 @@ td,th{{border:1px solid #ccc;padding:4px 8px;text-align:left}}</style></head>
         # observability plane: Trino-parity attribution fields
         # (QueryStats.java naming — device/host/compile time, spill and
         # exchange byte counts) when the runner produced a stats snapshot
+        self.manager.join_feedback(q)  # planNodeStats is its to write
         plane = getattr(q, "query_stats", None)
         if plane is not None:
             from ..runtime.observability import query_stats_fields
