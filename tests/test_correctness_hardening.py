@@ -83,7 +83,7 @@ class TestKeyPackOverflow:
         )
         assert res.rows == [(1, 10), (2, 20), (3, 30)]
 
-    def test_pack_key_pair_distinctness_adversarial(self):
+    def test_multi_column_join_keys_distinctness_adversarial(self):
         from trino_tpu.ops import kernels as K
 
         rng = np.random.default_rng(0)
@@ -96,18 +96,19 @@ class TestKeyPackOverflow:
         cols[0][10] = cols[0][20]
         cols[1][10] = cols[1][20]
         cols[2][10] = cols[2][20] + 1
+        # and two rows equal in all three
+        for c in cols:
+            c[30] = c[40]
         valid = np.ones(n, dtype=bool)
         pairs = [(jnp.asarray(c), jnp.asarray(valid)) for c in cols]
-        p, pv, b, bv = K.pack_key_pair(pairs, pairs)
-        p = np.asarray(p)
+        p, pv, b, bv = K.join_keys(pairs, pairs)
+        perm_b, lo, hi, count = K.join_match(b, bv, p, pv)
+        perm_b, lo, count = np.asarray(perm_b), np.asarray(lo), np.asarray(count)
         tuples = list(zip(*[c.tolist() for c in cols]))
         for i in range(n):
-            for j in range(i + 1, n):
-                if tuples[i] == tuples[j]:
-                    assert p[i] == p[j]
-                else:
-                    assert p[i] != p[j], f"rows {i},{j} alias: {tuples[i]} {tuples[j]}"
-        np.testing.assert_array_equal(np.asarray(b), p)
+            matched = sorted(perm_b[lo[i] : lo[i] + count[i]].tolist())
+            assert matched == [j for j in range(n) if tuples[j] == tuples[i]], i
+        assert count[10] == count[20] == 1 and count[30] == count[40] == 2
 
 
 class TestRepartitionNullFloatKeys:

@@ -1,0 +1,477 @@
+"""The join deployment (`tpch_joins_1chip`: TPC-H Q3, Q5, Q10, Q18) on the CPU at
+SF0.01: the statements in the specification's text against the templates' plain
+reference, the sort family's kernels against numpy, the shape of the programs
+the TPU's compiler is handed (how many sorts, how many operands each), the
+operators' span attributes and counters, and the program names the benchmark's
+readers look for."""
+
+import random
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import reference as ref
+from benchmark.layer_metrics import _operators as readers
+from benchmark.templates import q03, q05, q10, q18
+from benchmark.traffic import draw_params
+from trino_tpu.connectors.memory import MemoryConnector
+from trino_tpu.ops import int128 as i128
+from trino_tpu.ops import kernels as K
+from trino_tpu.runtime import LocalQueryRunner
+from trino_tpu.runtime import executor as E
+from trino_tpu.runtime.metrics import REGISTRY
+from trino_tpu.runtime.tracing import TRACER
+from trino_tpu.spi.page import Column, Page
+from trino_tpu.spi.types import BIGINT, DATE, DOUBLE, INTEGER, decimal_type
+
+SCALE = 0.01
+TEMPLATES = {"q03": q03, "q05": q05, "q10": q10, "q18": q18}
+TABLES = ("lineitem", "orders", "customer", "supplier", "nation", "region")
+
+
+@pytest.fixture(scope="module")
+def runner():
+    r = LocalQueryRunner.tpch(scale=SCALE)
+    r.memory = MemoryConnector()
+    r.register_catalog("memory", r.memory)
+    for table in TABLES:
+        r.execute(f"CREATE TABLE memory.default.{table} AS SELECT * FROM tpch.{r.session.schema}.{table}")
+    return r
+
+
+@pytest.fixture(scope="module")
+def host():
+    wanted: dict = {}
+    for module in TEMPLATES.values():
+        for table, columns in module.COLUMNS.items():
+            wanted.setdefault(table, [])
+            wanted[table] += [c for c in columns if c not in wanted[table]]
+    return ref.host_columns(SCALE, wanted)
+
+
+@pytest.fixture(scope="module")
+def client(runner):
+    """The served path, as the benchmark drives it: decimals arrive as exact strings."""
+    from trino_tpu.client import StatementClient
+    from trino_tpu.server import CoordinatorServer
+
+    server = CoordinatorServer(runner).start()
+    yield StatementClient(f"http://{server.address}", timeout=600.0)
+    server.stop()
+
+
+def run(runner, module, params):
+    return runner.execute(module.SQL.format(schema="memory.default", **module.literals(params)))
+
+
+# --------------------------------------------- the system against the reference
+
+
+@pytest.mark.parametrize("seed", [1, 2**31 + 7, 4_000_000_000])
+@pytest.mark.parametrize("name", sorted(TEMPLATES))
+def test_statement_equals_the_plain_reference(client, host, name, seed):
+    module = TEMPLATES[name]
+    for params in draw_params(module.DOMAIN, random.Random(f"{seed}:params:{name}"), 2):
+        want = module.expect(host, params, ref.EXACT)
+        comparison = ref.Comparison()
+        got = run(client, module, params).rows
+        assert comparison.rows(f"{name}{params}", got, want, ref.as_client(want)), comparison.report()
+        assert comparison.correct
+        assert not module.ties(host, params)  # else the row-for-row comparison is not decided
+
+
+def test_q18_with_rows_to_return(client, host):
+    # at SF0.01 no order passes the specification's quantities: one that some pass
+    params = {"quantity": 250}
+    want = q18.expect(host, params, ref.EXACT)
+    assert 10 < len(want) <= 100
+    assert run(client, q18, params).rows == ref.as_client(want)
+
+
+# ---------------------------------------------------- the kernels against numpy
+
+
+def _random_key(rng, kind: str, n: int):
+    """(values, what numpy orders them by) of one sort key with duplicates."""
+    if kind == "small":
+        v = rng.integers(-3, 4, size=n).astype(np.int64)
+    elif kind == "wide":
+        v = rng.integers(-(2**62), 2**62, size=n, dtype=np.int64)
+        v[::5] = v[0]
+    elif kind == "int32":
+        v = rng.integers(-(2**31), 2**31, size=n, dtype=np.int64).astype(np.int32)
+        v[::4] = 7
+    elif kind == "bool":
+        v = rng.random(n) < 0.5
+    else:  # a double, zeros of both signs and NaNs among them
+        v = rng.normal(size=n)
+        v[::7], v[1::7], v[2::7] = 0.0, -0.0, np.nan
+    order = np.asarray(K.order_key(jnp.asarray(v))) if v.dtype.kind == "f" else v
+    return v, order
+
+
+KINDS = ["wide", "small", "int32", "double", "bool", "small", "wide"]
+
+
+@pytest.mark.parametrize("keys", [1, 3, 5, 7])
+def test_cosort_is_numpys_stable_lexsort(keys):
+    rng = np.random.default_rng(keys)
+    n = 3001
+    drawn = [_random_key(rng, kind, n) for kind in KINDS[:keys]]
+    payloads = [
+        rng.integers(0, 100, size=n).astype(np.int32), rng.random(n) < 0.5, rng.normal(size=n),
+        rng.integers(-(2**62), 2**62, size=n), rng.integers(-100, 100, size=n).astype(np.int8),
+        rng.integers(0, 5, size=(n, 2)), rng.random(n) < 0.1,
+    ]
+    sorted_keys, sorted_payloads = jax.jit(K.cosort)(
+        [jnp.asarray(v) for v, _ in drawn], [jnp.asarray(p) for p in payloads]
+    )
+    order = np.lexsort([o for _, o in drawn])  # least significant first, as cosort takes them
+    for got, (v, _) in zip(sorted_keys, drawn):
+        assert got.dtype == v.dtype and np.array_equal(np.asarray(got), v[order], equal_nan=True)
+    for got, p in zip(sorted_payloads, payloads):
+        assert got.dtype == p.dtype and np.array_equal(np.asarray(got), p[order], equal_nan=True)
+
+
+def _group_page(rng, keys: int, n: int):
+    """A page of `keys` group keys (a bigint, dictionary codes, a date, Int128
+    limbs, a double, ...) with nulls, inactive rows and duplicates, and one
+    aggregated column; beside it what numpy groups by."""
+    cols, by = [], []
+    for j in range(keys):
+        valid = rng.random(n) > 0.15
+        kind = j % 5
+        if kind == 0:
+            v = rng.integers(0, 6, size=n).astype(np.int64) * (2**33)
+            cols.append(Column.from_numpy(BIGINT, v, valid))
+        elif kind == 1:
+            words = [f"w{k:02d}" for k in rng.integers(0, 9, size=n)]
+            c = Column.from_strings([w if ok else None for w, ok in zip(words, valid)])
+            v = np.asarray(c.data).astype(np.int64)
+            cols.append(c)
+        elif kind == 2:
+            v = rng.integers(-5, 5, size=n).astype(np.int32)
+            cols.append(Column.from_numpy(DATE, v, valid))
+        elif kind == 3:
+            ints = [int(x) * (2**70) + int(y) for x, y in zip(rng.integers(-2, 3, size=n), rng.integers(0, 2, size=n))]
+            cols.append(Column.from_numpy(decimal_type(38, 2), i128.np_from_ints(ints), valid))
+            v = np.array(ints, dtype=object)
+        else:
+            v = rng.integers(-2, 3, size=n).astype(np.float64)
+            cols.append(Column.from_numpy(DOUBLE, v, valid))
+        by.append((v, valid))
+    cols.append(Column.from_numpy(INTEGER, np.arange(n, dtype=np.int32)))
+    active = rng.random(n) > 0.2
+    return Page(tuple(cols), jnp.asarray(active)), by, active
+
+
+@pytest.mark.parametrize("keys", [1, 3, 5, 7])
+def test_group_sort_orders_and_bounds_groups_as_numpy_does(keys):
+    rng = np.random.default_rng(100 + keys)
+    n = 2048
+    page, by, active = _group_page(rng, keys, n)
+    symbols = tuple(f"k{j}" for j in range(keys)) + ("x",)
+    out, new_group, num_groups = E._jit_group_sort(symbols[:-1], symbols, symbols, page)
+    # numpy: active rows first; key by key nulls before values, values ascending; stable
+    sort_by = []
+    for v, valid in reversed(by):
+        ranks = np.zeros(n, dtype=np.int64)
+        distinct = sorted(set(v[valid].tolist()))
+        ranks[valid] = [distinct.index(x) for x in v[valid].tolist()]
+        sort_by += [ranks, valid]
+    order = np.lexsort(sort_by + [~active])
+    live = int(active.sum())
+    assert np.array_equal(np.asarray(out.active), active[order])
+    assert np.array_equal(np.asarray(out.columns[-1].data)[:live], order[:live])
+    tuples = [
+        tuple((bool(valid[i]), v[i] if valid[i] else None) for v, valid in by) for i in order[:live]
+    ]
+    starts = [i for i in range(live) if i == 0 or tuples[i] != tuples[i - 1]]
+    assert int(num_groups) == len(starts) == len(set(tuples))
+    assert np.flatnonzero(np.asarray(new_group)).tolist() == starts
+
+
+@pytest.mark.parametrize("columns", [1, 2])
+def test_join_match_counts_and_places_matches_as_numpy_does(columns):
+    rng = np.random.default_rng(columns)
+    n, m = 700, 300
+    probe = [rng.integers(0, 40, size=n).astype(np.int64) for _ in range(columns)]
+    build = [rng.integers(0, 40, size=m).astype(np.int64) for _ in range(columns)]
+    probe[0][:3], build[0][:3] = K.INT64_MAX, K.INT64_MAX  # the old sentinel is a key like any other
+    pa, ba = rng.random(n) > 0.2, rng.random(m) > 0.3
+    perm_b, lo, hi, count = jax.jit(K.join_match)(
+        [jnp.asarray(b) for b in build], jnp.asarray(ba), [jnp.asarray(p) for p in probe], jnp.asarray(pa)
+    )
+    perm_b, lo, count = np.asarray(perm_b), np.asarray(lo), np.asarray(count)
+    assert perm_b.min() >= 0 and perm_b.max() < m
+    for i in range(n):
+        want = [j for j in range(m) if ba[j] and all(b[j] == p[i] for b, p in zip(build, probe))]
+        assert count[i] == (len(want) if pa[i] else 0)
+        if pa[i]:
+            assert perm_b[lo[i]: lo[i] + count[i]].tolist() == want  # ties in row order
+
+
+def test_gather_rows_is_a_gather_of_each_array():
+    rng = np.random.default_rng(9)
+    n = 1000
+    arrays = [
+        rng.integers(-(2**62), 2**62, size=n), rng.normal(size=n), rng.random(n) < 0.5,
+        rng.integers(-100, 100, size=n).astype(np.int8), rng.integers(0, 9, size=n).astype(np.int32),
+        rng.normal(size=n).astype(np.float32), rng.integers(0, 9, size=(n, 2)),
+    ] + [rng.random(n) < 0.5 for _ in range(40)]  # more flags than one word holds
+    for idx in (rng.permutation(n), rng.integers(0, n, size=5), rng.integers(0, n, size=3000)):
+        moved = jax.jit(K.gather_rows)([jnp.asarray(a) for a in arrays], jnp.asarray(idx.astype(np.int32)))
+        for got, a in zip(moved, arrays):
+            assert got.dtype == a.dtype and np.array_equal(np.asarray(got), a[idx])
+
+
+# -------------------------------- what the TPU's compiler is handed (PERF.md, PR 34)
+
+
+def _sorts(lowered_text: str) -> list:
+    """Operand count of every sort instruction in a lowered program."""
+    return [len(re.findall(r"%", m.group(1))) for m in re.finditer(r'"stablehlo\.sort"\(([^)]*)\)', lowered_text)]
+
+
+def test_q10s_group_sort_is_one_sort_of_three_operands(runner):
+    """Seven group keys and fifteen columns were 15 sorts of about 30 operands
+    each in one program (ISSUE 34); the compile wall cannot come back unseen."""
+    calls = []
+    real = E._jit_group_sort._jit
+
+    def spy(*args, **kwargs):
+        calls.append(args)
+        return real(*args, **kwargs)
+
+    E._jit_group_sort._jit = spy
+    try:
+        run(runner, q10, {"month": "1993-10"})
+    finally:
+        E._jit_group_sort._jit = real
+    (args,) = calls
+    assert len(args[0]) == 7  # the specification's seven keys
+    text = E._jit_group_sort.lower(*args).as_text()
+    assert _sorts(text) == [3]
+    assert text.count("stablehlo.while") == 1  # the passes are a loop, not copies
+
+
+PROGRAM_SHAPES = {
+    # program: (sort instructions at most, operands of all of them together at most)
+    # a join's match: the merge (key words and the tag), the ranks back in probe order (two
+    # operands), and the builds' places where they are dense among the queries (two)
+    "join_match": (3, 7),
+    "join_match_two_columns": (3, 9),  # two bigint keys of unknown range are four words
+    "compact": (1, 2),                 # the positions, and an operand nothing reads (K.live_indices)
+    "order_by": (1, 3),
+    "semijoin": (3, 7),
+}
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAM_SHAPES))
+def test_sort_family_programs_hold_few_small_sorts(program):
+    n, m = 4096, 1024
+    big, small = jnp.zeros(n, jnp.int64), jnp.zeros(m, jnp.int64)
+    on, som = jnp.ones(n, bool), jnp.ones(m, bool)
+    page = Page(tuple(Column(BIGINT, big, on) for _ in range(4)), on)
+    if program == "join_match":
+        text = E._jit_join_match.lower(False, ((big, on),), ((small, som),), (None,), on, som).as_text()
+    elif program == "join_match_two_columns":
+        text = E._jit_join_match.lower(
+            False, ((big, on), (big, on)), ((small, som), (small, som)), (None, None), on, som
+        ).as_text()
+    elif program == "compact":
+        text = E._jit_compact.lower(n // 2, page).as_text()
+    elif program == "order_by":
+        from trino_tpu.planner.plan import Ordering
+
+        orderings = (Ordering("a", False, False), Ordering("b", True, False))
+        text = E._jit_sort.lower(orderings, ("a", "b", "c", "d"), 10, page).as_text()
+    else:
+        col = Column(BIGINT, big, on)
+        text = E._jit_semijoin.lower(col, Column(BIGINT, small, som), None, page, som, False).as_text()
+    most, operands = PROGRAM_SHAPES[program]
+    sorts = _sorts(text)
+    assert 1 <= len(sorts) <= most and sum(sorts) <= operands, sorts
+
+
+# --------------------------------------------------------- spans and counters
+
+
+def _counter(name, **labels):
+    return REGISTRY.counter(name, labels).value
+
+
+CASES = {
+    "q03": ({"segment": "BUILDING", "day": 15}, 2, "sort", 3),
+    "q05": ({"region": "ASIA", "year": 1994}, 5, "direct", 1),
+    "q10": ({"month": "1993-10"}, 3, "sort", 7),
+    "q18": ({"quantity": 250}, 2, "sort", 5),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_operator_spans_and_counters(runner, name):
+    params, joins, path, keys = CASES[name]
+    before = {
+        side: _counter(E.JOIN_ROWS_COUNTER, side=side) for side in ("probe", "build", "out")
+    }
+    grouped_before = _counter(E.GROUP_ROWS_COUNTER, path=path)
+    passes_before = _counter(E.SORT_PASSES_COUNTER)
+    res = run(runner, TEMPLATES[name], params)
+    spans = TRACER.spans(res.trace_id)
+    join_spans = [s.attributes for s in spans if s.name == "op:JoinNode"]
+    assert len(join_spans) == joins
+    for a in join_spans:
+        assert {"probe_rows", "build_rows", "rows_out", "capacity_out", "key_types",
+                "probe_types", "build_types", "sort_passes"} <= set(a)
+        assert 0 <= a["rows_out"] <= a["capacity_out"] and a["probe_rows"] <= a["probe_capacity"]
+        assert a["key_types"] and all(t == "bigint" for t in a["key_types"])
+        # inner joins: the dynamic filter's range packs the keys into one word
+        assert a["key_bits"] and sum(a["key_bits"]) <= 32
+    for side in before:
+        grown = _counter(E.JOIN_ROWS_COUNTER, side=side) - before[side]
+        rows = {"probe": "probe_rows", "build": "build_rows", "out": "rows_out"}[side]
+        semis = [s.attributes for s in spans if s.name == "op:SemiJoinNode"]
+        assert grown == sum(a[rows] for a in join_spans + semis)
+    aggregations = [s.attributes for s in spans if s.name == "op:AggregationNode"]
+    top = aggregations[0]  # the statement's own GROUP BY closes first among equals
+    assert top["path"] == path and top["keys"] == keys and len(top["key_types"]) == keys
+    assert top["rows_in"] <= top["capacity_in"] and top["agg_types"]
+    if path == "sort":
+        assert top["groups"] >= 1 and top["sort_passes"] >= 1
+    assert _counter(E.GROUP_ROWS_COUNTER, path=path) - grouped_before == sum(
+        a["rows_in"] for a in aggregations if a["path"] == path
+    )
+    ordering = [s.attributes for s in spans if s.name in ("op:TopNNode", "op:SortNode")]
+    assert len(ordering) == 1
+    assert ordering[0]["rows_out"] <= ordering[0]["rows_in"] and ordering[0]["keys"] in (1, 2)
+    everything = join_spans + aggregations + ordering + [
+        s.attributes for s in spans if s.name == "op:SemiJoinNode"
+    ]
+    assert _counter(E.SORT_PASSES_COUNTER) - passes_before == sum(
+        a.get("sort_passes", 0) for a in everything
+    )
+    if name == "q18":
+        (semi,) = [s.attributes for s in spans if s.name == "op:SemiJoinNode"]
+        assert semi["rows_out"] == semi["probe_rows"] and semi["key_types"] == ["bigint"]
+    # the attributes are counts the executor held already: no read was added for them
+    syncs = [s.name for s in spans if s.name.startswith("sync:")]
+    assert set(syncs) <= {"sync:compact", "sync:join_capacity", "sync:num_groups",
+                          "sync:dynamic_filter", "sync:scan_pack"}
+
+
+# ------------------------------------------- the names the benchmark's readers use
+
+
+@pytest.mark.parametrize("program", readers.JOIN_PROGRAMS + readers.GROUP_PROGRAMS)
+def test_the_readers_program_names_are_the_executors(program):
+    assert program.startswith("jit_")
+    function = getattr(E, program[len("jit_"):])
+    assert getattr(function, "__wrapped__", function).__name__ == program[len("jit_"):]
+
+
+def test_the_readers_span_names_are_the_executors():
+    from trino_tpu.planner import plan
+
+    for name in readers.JOIN_SPANS + readers.GROUP_SPANS:
+        assert name.startswith(E.OP_PREFIX) and hasattr(plan, name[len(E.OP_PREFIX):])
+
+
+# ------------------------------------- what the plans needed (PR 34, the chip's findings)
+
+
+def test_the_memory_catalog_bounds_distinct_values_by_the_columns_ranges(runner):
+    """Without them the join order of Q5 met customers and suppliers on the
+    nation code alone: 108 million rows at SF3, RESOURCE_EXHAUSTED on the chip."""
+    from trino_tpu.spi.connector import SchemaTableName
+
+    connector = runner.memory
+    customer = connector.table(SchemaTableName("default", "customer"))
+    assert customer.rows == 1500 and customer.spans["c_custkey"] == (1, 1500)
+    assert customer.spans["c_nationkey"] == (0, 24) and "c_name" not in customer.spans
+    from trino_tpu.spi.connector import TableHandle
+
+    stats = connector.metadata().get_table_statistics(
+        TableHandle("memory", SchemaTableName("default", "customer"))
+    )
+    assert stats.row_count == 1500 and stats.column("c_nationkey").ndv == 25
+    assert stats.column("c_custkey").ndv == 1500 and stats.column("c_name").ndv is None
+    lineitem = connector.table(SchemaTableName("default", "lineitem"))
+    low, high = lineitem.spans["l_orderkey"]
+    assert 1 <= low < high <= 15000 * 4 and lineitem.spans["l_suppkey"] == (1, 100)
+
+
+def test_q5_joins_the_fact_table_before_it_meets_customers_at_sf3_statistics():
+    """The order `join_graph_order` gives under the statistics the memory
+    catalog holds at SF3 (rows, and a key's range as the bound of its distinct
+    values): region, nation, supplier, then lineitem and orders, customer last."""
+    from trino_tpu.planner import stats as S
+    from trino_tpu.spi.connector import SchemaTableName
+
+    r = LocalQueryRunner.tpch(scale=0.001)
+    memory = MemoryConnector()
+    r.register_catalog("memory", memory)
+    facts = {
+        "lineitem": (17993932, {"l_orderkey": (1, 18000000), "l_suppkey": (1, 30000)}),
+        "orders": (4500000, {"o_orderkey": (1, 18000000), "o_custkey": (1, 449999), "o_orderdate": (8035, 10440)}),
+        "customer": (450000, {"c_custkey": (1, 450000), "c_nationkey": (0, 24)}),
+        "supplier": (30000, {"s_suppkey": (1, 30000), "s_nationkey": (0, 24)}),
+        "nation": (25, {"n_nationkey": (0, 24), "n_regionkey": (0, 4)}),
+        "region": (5, {"r_regionkey": (0, 4)}),
+    }
+    for table, (rows, spans) in facts.items():
+        r.execute(f"CREATE TABLE memory.default.{table} AS SELECT * FROM tpch.{r.session.schema}.{table} WHERE false")
+        stored = memory.table(SchemaTableName("default", table))
+        stored.rows, stored.spans = rows, dict(spans)
+    orders = []
+    real = S.join_graph_order
+
+    def spy(leaves, leaf_conjuncts, equi_edges, estimator):
+        order = real(leaves, leaf_conjuncts, equi_edges, estimator)
+        orders.append([str(leaves[i].table).split(".")[-1] for i in order])
+        return order
+
+    S.join_graph_order = spy
+    try:
+        r.execute("EXPLAIN " + q05.SQL.format(schema="memory.default", region="ASIA", date="1994-01-01"))
+    finally:
+        S.join_graph_order = real
+    assert orders == [["region", "nation", "supplier", "lineitem", "orders", "customer"]]
+    # the same tables without the ranges: the nation code looks like a key
+    for table in facts:
+        memory.table(SchemaTableName("default", table)).spans = {}
+    S.join_graph_order = spy
+    try:
+        r.execute("EXPLAIN " + q05.SQL.format(schema="memory.default", region="ASIA", date="1994-01-01"))
+    finally:
+        S.join_graph_order = real
+    assert orders[1].index("customer") < orders[1].index("lineitem")
+
+
+def test_q18s_semi_join_is_decided_on_orders(runner):
+    """`o_orderkey IN (...)` goes below the joins to the side that has the key."""
+    res = run(runner, q18, {"quantity": 250})
+    spans = TRACER.spans(res.trace_id)
+    (semi,) = [s.attributes for s in spans if s.name == "op:SemiJoinNode"]
+    assert semi["probe_capacity"] == 16384  # the page of `orders`, not lineitem x orders x customer
+    joins = [s.attributes for s in spans if s.name == "op:JoinNode"]
+    assert all(a["rows_out"] < 2000 for a in joins)  # every join works on the large orders only
+    text = "\n".join(row[0] for row in runner.execute(
+        "EXPLAIN " + q18.SQL.format(schema="memory.default", quantity=313)).rows)
+    assert text.index("- Join[INNER") < text.index("- SemiJoin") < text.index("memory.default.orders")
+
+
+@pytest.mark.parametrize("kind", ["LEFT", "RIGHT", "FULL"])
+def test_a_semi_join_stays_above_an_outer_join(runner, kind):
+    sql = (
+        "SELECT count(*) FROM memory.default.orders o {kind} JOIN memory.default.customer c "
+        "ON o_custkey = c_custkey WHERE o_orderkey IN (SELECT l_orderkey FROM memory.default.lineitem "
+        "WHERE l_quantity > 49)"
+    ).format(kind=kind)
+    text = "\n".join(row[0] for row in runner.execute("EXPLAIN " + sql).rows)
+    assert text.index("- SemiJoin") < text.index(f"- Join[{kind}")
+    inner = runner.execute(sql.replace(f"{kind} JOIN", "JOIN")).rows
+    assert runner.execute(sql).rows == inner  # every order has its customer: the count is the same

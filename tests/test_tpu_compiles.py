@@ -1,0 +1,95 @@
+"""Compiles for the chip that is not attached (the `on-chip-measurement` guide,
+section 2): the sort family's programs at the join deployment's shapes, held to
+the budget ISSUE 34 set: no program over 90 s. Three programs, all in this one
+file; the topology is described inside a fixture and nothing touches the TPU's
+library while a module is imported. A compile that passes is not a chip run."""
+
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from trino_tpu.runtime import executor as E
+from trino_tpu.spi.page import Column, Dictionary, Page
+from trino_tpu.spi.types import BIGINT, VARCHAR, decimal_type
+
+BUDGET_S = 90.0
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # such a compile is written to the persistent cache and cannot be read
+    # back without a chip: keep the cache out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _column(type_, rows: int, sharding, dtype, dictionary=None) -> Column:
+    return Column(
+        type_, jax.ShapeDtypeStruct((rows,), dtype, sharding=sharding),
+        jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=sharding), dictionary,
+    )
+
+
+def _compile_seconds(jitted, *args) -> float:
+    start = time.perf_counter()
+    jitted.lower(*args).compile()
+    return time.perf_counter() - start
+
+
+def test_group_sort_of_seven_keys(one_chip):
+    """Q10's GROUP BY (cl. 2.4.10): a bigint, a decimal and five dictionary-coded
+    strings, and the revenue they carry, at 131,072 rows."""
+    rows = 131072
+    names = Dictionary.from_strings([f"s{i:06d}" for i in range(2000)])
+    cols = [
+        _column(BIGINT, rows, one_chip, jnp.int64),
+        _column(VARCHAR, rows, one_chip, jnp.int32, names),
+        _column(decimal_type(12, 2), rows, one_chip, jnp.int64),
+        _column(VARCHAR, rows, one_chip, jnp.int32, names),
+        _column(VARCHAR, rows, one_chip, jnp.int32, names),
+        _column(VARCHAR, rows, one_chip, jnp.int32, names),
+        _column(VARCHAR, rows, one_chip, jnp.int32, names),
+        _column(decimal_type(18, 4), rows, one_chip, jnp.int64),
+    ]
+    symbols = tuple(f"c{i}" for i in range(len(cols)))
+    page = Page(tuple(cols), jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip))
+    assert _compile_seconds(E._jit_group_sort, symbols[:7], symbols, symbols, page) < BUDGET_S
+
+
+def test_dense_compaction_of_four_columns(one_chip):
+    """A quarter of 524,288 rows kept (the `sort` path of `_compact_path`)."""
+    rows = 524288
+    cols = tuple(_column(BIGINT, rows, one_chip, jnp.int64) for _ in range(4))
+    page = Page(cols, jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip))
+    assert E._compact_path(rows // 4, page) == "sort"
+    assert _compile_seconds(E._jit_compact, rows // 4, page) < BUDGET_S
+
+
+def test_join_match_of_one_bigint_key(one_chip):
+    """524,288 probe rows against 131,072 build rows."""
+    probe, build = 524288, 131072
+
+    def key(rows):
+        return (jax.ShapeDtypeStruct((rows,), jnp.int64, sharding=one_chip),
+                jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip))
+
+    seconds = _compile_seconds(
+        E._jit_join_match, False, (key(probe),), (key(build),), (None,),
+        key(probe)[1], key(build)[1],
+    )
+    assert seconds < BUDGET_S

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..spi.connector import (
+    ColumnStatistics,
     ColumnMetadata,
     Connector,
     ConnectorMetadata,
@@ -28,6 +29,7 @@ from ..spi.connector import (
     TableStatistics,
 )
 from ..spi.page import Column, Page
+from ..spi.types import DateType, IntegralType
 
 
 @dataclass
@@ -43,9 +45,59 @@ class _StoredTable:
     # replace_pages: under the connector's lock, counted on the device when
     # the rows change), so that statistics read no page
     rows: int = 0
+    # {column: (least, most)} over the live, non-null values of the integer
+    # columns (bigint, integer, date), kept as ``rows`` is and read with it
+    # in the same one read: a key's range bounds its distinct values, which
+    # is what join ordering needs to tell a key from a nation code
+    spans: Dict[str, Tuple[int, int]] = field(default_factory=dict)
 
     def row_count(self) -> int:
         return self.rows
+
+    def note(self, facts: "List[int]", reset: bool = False) -> int:
+        """Fold what ``_page_facts`` read of the pages written into ``rows``
+        and ``spans``; returns the rows they hold."""
+        if reset:
+            self.rows, self.spans = 0, {}
+        names = [c.name for c in self.columns if _ranged(c)]
+        width = 1 + 2 * len(names)
+        added = 0
+        for at in range(0, len(facts), width):
+            rows = int(facts[at])
+            added += rows
+            if not rows:
+                continue
+            for j, name in enumerate(names):
+                low, high = int(facts[at + 1 + 2 * j]), int(facts[at + 2 + 2 * j])
+                if low > high:
+                    continue  # every value null
+                had = self.spans.get(name)
+                self.spans[name] = (low, high) if had is None else (min(had[0], low), max(had[1], high))
+        self.rows += added
+        return added
+
+
+def _ranged(column: ColumnMetadata) -> bool:
+    return isinstance(column.type, (IntegralType, DateType))
+
+
+def _page_facts(columns: Sequence[ColumnMetadata], pages: Sequence[Page]) -> "List[int]":
+    """For each page: its live rows, then (least, most) of every ranged
+    column over its live, non-null values; all pages in ONE device read."""
+    scalars = []
+    for page in pages:
+        scalars.append(page.num_rows().astype(jnp.int64))
+        for meta, col in zip(columns, page.columns):
+            if not _ranged(meta):
+                continue
+            if col.data.ndim != 1 or col.dictionary is not None:
+                scalars += [jnp.int64(1), jnp.int64(0)]  # no range: least > most
+                continue
+            live = page.active & col.valid
+            info = jnp.iinfo(col.data.dtype)
+            scalars.append(jnp.min(jnp.where(live, col.data, info.max)).astype(jnp.int64))
+            scalars.append(jnp.max(jnp.where(live, col.data, info.min)).astype(jnp.int64))
+    return np.asarray(jnp.stack(scalars)).tolist() if scalars else []
 
 
 class MemoryConnector(Connector):
@@ -141,9 +193,9 @@ class MemoryConnector(Connector):
                 raise ValueError(
                     f"column count mismatch: {page.num_columns} vs {len(table.columns)}"
                 )
-            rows = int(page.num_rows())  # counted on the device: one integer read
             self._bump(name)
-            table.rows += rows
+            # rows and the integer columns' ranges, counted on the device: one read
+            rows = table.note(_page_facts(table.columns, [page]))
             if not table.bucketed_by:
                 table.pages.append(page)
                 return rows
@@ -197,12 +249,12 @@ class MemoryConnector(Connector):
             self._bump(name)
             if not table.bucketed_by:
                 table.pages = list(pages)
-                counts = [p.num_rows() for p in table.pages if p is not None]
-                # one integer read for all the pages
-                table.rows = int(jnp.stack(counts).sum()) if counts else 0
+                # one read for all the pages
+                live = [p for p in table.pages if p is not None]
+                table.note(_page_facts(table.columns, live), reset=True)
                 return
             table.pages = []
-            table.rows = 0  # each insert below adds what it writes
+            table.note([], reset=True)  # each insert below adds what it writes
             for p in pages:
                 if p is not None:
                     self.insert(name, p)
@@ -239,7 +291,15 @@ class _MemoryMetadata(ConnectorMetadata):
 
     def get_table_statistics(self, handle: TableHandle) -> TableStatistics:
         t = self.connector.table(handle.schema_table)
-        return TableStatistics(row_count=float(t.row_count()) if t else 0.0)
+        if t is None:
+            return TableStatistics(row_count=0.0)
+        rows = float(t.row_count())
+        # an integer column holds no more distinct values than its range has
+        columns = {
+            name: ColumnStatistics(ndv=min(rows, float(high - low + 1)))
+            for name, (low, high) in t.spans.items()
+        }
+        return TableStatistics(row_count=rows, columns=columns)
 
 
 class _MemorySplitManager(ConnectorSplitManager):

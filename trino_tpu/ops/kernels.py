@@ -152,43 +152,203 @@ def cumsum(x: jnp.ndarray) -> jnp.ndarray:
     return out[:n] if pad else out
 
 
-def lexsort_perm(keys: Sequence[jnp.ndarray], active: jnp.ndarray) -> jnp.ndarray:
-    """Permutation sorting by keys (first = most significant); inactive rows last.
+# --------------------------------------------------------------------------- #
+# the sort family: order fields -> 32-bit words -> one two-key sort in a loop,
+# payloads gathered by the permutation as one matrix
+# --------------------------------------------------------------------------- #
+#
+# What the TPU's compiler charges for a sort grows with its operands, not
+# with its rows: on a v5e's host a 2-operand 32-bit sort compiles in 7 to 16
+# s (262,144 to 18.9M rows), every further 32-bit operand adds 6 to 14 s, a
+# 64-bit key costs as much as three words, `is_stable` another operand (PERF.md
+# section 6, PR 34). A page's columns riding a sort made single programs of 200
+# to 500 s. So every sort here has two or three 32-bit operands whatever the
+# page holds: a key word, the row's position as the second key (which makes the
+# sort stable without `is_stable`), and the permutation so far. Run time, same
+# chip, 18.9M rows: such a sort 52 to 72 ms; a 1-D gather of 4 / 8 bytes 163 /
+# 326 ms (8.6 / 17 ns an element, ascending or random alike); eight 32-bit
+# words gathered as one [8, n] matrix 278 ms (1.8 ns a word), which is why
+# `gather_rows` packs what it moves.
 
-    Implemented as a chain of stable single-operand argsorts (least-significant
-    key first) instead of one variadic lexsort: XLA's variadic sort comparator
-    compiles catastrophically slowly on CPU as operand count x size grows, while
-    single-key argsort + gather compiles linearly and runs equally fast.
-    """
-    perm = None
-    cols = list(keys)[::-1] + [(~active).astype(jnp.int8)]
-    for k in cols:
-        if perm is None:
-            perm = jnp.argsort(k)
-        else:
-            perm = perm[jnp.argsort(k[perm])]  # stable: earlier order preserved
+_SIGN32 = np.uint32(1 << 31)
+
+
+def order_field(data: jnp.ndarray, bits: Optional[int] = None) -> Tuple[jnp.ndarray, int]:
+    """(unsigned value, its width in bits) whose unsigned order is the order of
+    ``data``: a boolean takes 1 bit, a signed integer of w bits w (its sign
+    bit flipped), a double 64 (``float_order_key``). ``bits``, where given,
+    says that ``data`` holds codes in [0, 2**bits) (dictionary codes)."""
+    if bits is not None:
+        return data.astype(jnp.uint64) & jnp.uint64((1 << bits) - 1), bits
+    if data.dtype == jnp.bool_:
+        return data.astype(jnp.uint64), 1
+    if jnp.issubdtype(data.dtype, jnp.floating):
+        data = float_order_key(data)
+    width = data.dtype.itemsize * 8
+    if jnp.issubdtype(data.dtype, jnp.unsignedinteger):
+        return data.astype(jnp.uint64), width
+    if width == 64:
+        return data.view(jnp.uint64) ^ jnp.uint64(1 << 63), 64
+    return (data.astype(jnp.int64) + (1 << (width - 1))).astype(jnp.uint64), width
+
+
+def sort_words(fields: Sequence[Tuple[jnp.ndarray, int]]) -> List[jnp.ndarray]:
+    """The bit string ``fields`` spell (most significant first, each an
+    ``order_field``) cut into int32 words, LEAST significant first: sorting by
+    the words in that order, each pass stable, sorts by the fields. Fields
+    are packed: seven dictionary-coded keys and their validity bits are two
+    words, not fourteen passes."""
+    words: List[jnp.ndarray] = []
+    cur, cur_bits = None, 0
+    for value, bits in reversed(list(fields)):
+        pieces = [(value, bits)]
+        if bits > 32:
+            pieces = [(value & jnp.uint64(0xFFFFFFFF), 32), (value >> jnp.uint64(32), bits - 32)]
+        for v, b in pieces:
+            cur = v if cur is None else cur | (v << jnp.uint64(cur_bits))
+            cur_bits += b
+            if cur_bits >= 32:
+                words.append((cur & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32))
+                cur, cur_bits = cur >> jnp.uint64(32), cur_bits - 32
+                if cur_bits == 0:
+                    cur = None
+    if cur_bits:
+        words.append(cur.astype(jnp.uint32))
+    # unsigned order as signed words: flip the top bit, keep the bits
+    return [jax.lax.bitcast_convert_type(w ^ _SIGN32, jnp.int32) for w in words]
+
+
+def sort_perm(fields: Sequence[Tuple[jnp.ndarray, int]]) -> jnp.ndarray:
+    """The stable ascending permutation (int32) of rows ordered by ``fields``
+    (most significant first). One sort instance whatever the number of keys:
+    the words of ``sort_words`` are stacked and a loop runs one pass a word,
+    least significant first; a pass sorts (word, position, permutation) on
+    its first two operands. A word that is the same in every row orders
+    nothing and its pass is skipped at run time (the upper half of a bigint
+    key that stays under 2**32, validity bits of columns without nulls)."""
+    words = sort_words(fields)
+    n = words[0].shape[0]
+    if len(words) == 1:
+        iota = jnp.arange(n, dtype=jnp.int32)
+        return jax.lax.sort((words[0], iota), num_keys=2, is_stable=False)[1]
+    stack = jnp.stack(words)
+    # zero, but typed as the words are: under shard_map the loop's state has
+    # to vary over the mesh as any of them does (XLA folds x ^ x)
+    zero = stack[0] ^ stack[0]
+    iota = jnp.arange(n, dtype=jnp.int32) + zero
+    orders = jnp.min(stack, axis=1) != jnp.max(stack, axis=1)
+
+    def one_pass(p, state):
+        def run(state):
+            perm, moved = state
+            # the first pass that runs reads its word in place: perm is iota
+            word = jax.lax.cond(moved, lambda: stack[p][perm], lambda: stack[p])
+            perm = jax.lax.sort((word, iota, perm), num_keys=2, is_stable=False)[2]
+            return perm, zero[0] == 0
+
+        return jax.lax.cond(orders[p], run, lambda state: state, state)
+
+    perm, _ = jax.lax.fori_loop(0, len(words), one_pass, (iota, zero[0] != 0))
     return perm
 
 
-def cosort(pass_keys: Sequence[jnp.ndarray], payloads: Sequence[jnp.ndarray]):
-    """Stable multi-pass sort carrying payloads inside lax.sort.
+# gather_rows packs when it moves at least this share of the rows; below it
+# (a TopN's ten rows, an aggregation's group starts) packing would stream the
+# whole page to move a few rows of it
+_PACK_SHARE = 8
 
-    ``pass_keys`` are applied least-significant first (the last is primary).
-    Returns (sorted_pass_keys, sorted_payloads). Co-sorting avoids separate
-    permutation gathers of every row: on a v5e a gather costs 12 / 20 / 40 ns
-    an element of 1 / 4 / 8 bytes, a 37.7M-row sort 102 ms for its key and 47
-    ms more for each 32-bit operand word (28 ms a mask), so payloads ride the
-    sort when most rows are kept and are gathered when a sixteenth or less is
-    (``live_indices``). Multi-pass single-key sorts are deliberate: the
-    variadic lexicographic comparator (num_keys > 1) compiles catastrophically
-    slowly in the TPU backend (>9 min for a 16-operand sort)."""
-    arrays = list(pass_keys) + list(payloads)
-    nkeys = len(pass_keys)
-    for idx in range(nkeys):
-        ops = (arrays[idx], *arrays[:idx], *arrays[idx + 1 :])
-        res = jax.lax.sort(ops, num_keys=1, is_stable=True)
-        arrays = list(res[1 : idx + 1]) + [res[0]] + list(res[idx + 1 :])
-    return arrays[:nkeys], arrays[nkeys:]
+
+def gather_rows(arrays: Sequence[jnp.ndarray], idx: jnp.ndarray) -> List[jnp.ndarray]:
+    """``[a[idx] for a in arrays]``. Arrays of one dimension travel together:
+    they are cut into 32-bit words (a 64-bit value is two, up to 32 booleans
+    share one), stacked as one [words, n] matrix and gathered in one gather,
+    which on a v5e costs 1.8 ns a word where a gather per array costs 8.6.
+    Arrays of more dimensions (Int128 limbs, vectors, array lanes) are rows
+    already and are gathered as they are."""
+    arrays = list(arrays)
+    n = arrays[0].shape[0] if arrays else 0
+    flat = [i for i, a in enumerate(arrays) if a.ndim == 1 and a.dtype.itemsize <= 8]
+    if len(flat) < 2 or idx.shape[0] * _PACK_SHARE < n:
+        return [a[idx] for a in arrays]
+    words: List[jnp.ndarray] = []
+    plan = {}
+    flags: List[int] = []
+    for i in flat:
+        a = arrays[i]
+        if a.dtype == jnp.bool_:
+            flags.append(i)
+        elif a.dtype.itemsize == 8:
+            v = a.view(jnp.uint64)
+            plan[i] = (len(words), 2)
+            words.append(jax.lax.bitcast_convert_type(v.astype(jnp.uint32), jnp.int32))
+            words.append(jax.lax.bitcast_convert_type((v >> jnp.uint64(32)).astype(jnp.uint32), jnp.int32))
+        elif a.dtype.itemsize == 4:
+            plan[i] = (len(words), 1)
+            words.append(a if a.dtype == jnp.int32 else jax.lax.bitcast_convert_type(a, jnp.int32))
+        else:
+            plan[i] = (len(words), 0)
+            words.append(a.astype(jnp.int32))
+    for at in range(0, len(flags), 32):
+        word = jnp.zeros((n,), dtype=jnp.uint32)
+        for bit, i in enumerate(flags[at:at + 32]):
+            word = word | (arrays[i].astype(jnp.uint32) << jnp.uint32(bit))
+            plan[i] = (len(words), -1 - bit)
+        words.append(jax.lax.bitcast_convert_type(word, jnp.int32))
+    moved = jnp.stack(words)[:, idx]
+    out = [None] * len(arrays)
+    for i, a in enumerate(arrays):
+        if i not in plan:
+            out[i] = a[idx]
+            continue
+        at, kind = plan[i]
+        if kind < 0:
+            out[i] = ((moved[at] >> jnp.int32(-1 - kind)) & 1).astype(jnp.bool_)
+        elif kind == 2:
+            lo = jax.lax.bitcast_convert_type(moved[at], jnp.uint32).astype(jnp.uint64)
+            hi = jax.lax.bitcast_convert_type(moved[at + 1], jnp.uint32).astype(jnp.uint64)
+            out[i] = ((hi << jnp.uint64(32)) | lo).view(a.dtype)
+        elif kind == 1:
+            out[i] = moved[at] if a.dtype == jnp.int32 else jax.lax.bitcast_convert_type(moved[at], a.dtype)
+        else:
+            out[i] = moved[at].astype(a.dtype)
+    return out
+
+
+def cummax(x: jnp.ndarray) -> jnp.ndarray:
+    """1-D inclusive running maximum, blocked as ``cumsum`` is: the TPU's
+    compiler takes 15 s over a flat ``lax.cummax`` of 524,288 elements and
+    under a second over rows of 2,048 and the rows' maxima (PR 34)."""
+    n = x.shape[0]
+    K = 2048
+    if n <= K * 4:
+        return jax.lax.cummax(x)
+    least = jnp.iinfo(x.dtype).min if jnp.issubdtype(x.dtype, jnp.integer) else -jnp.inf
+    pad = (-n) % K
+    xp = jnp.pad(x, (0, pad), constant_values=least) if pad else x
+    within = jax.lax.cummax(xp.reshape(-1, K), axis=1)
+    upto = cummax(within[:, -1])  # rows' maxima, inclusive
+    before = jnp.concatenate([jnp.full((1,), least, x.dtype), upto[:-1]])
+    out = jnp.maximum(within, before[:, None]).reshape(-1)
+    return out[:n] if pad else out
+
+
+def lexsort_perm(keys: Sequence[jnp.ndarray], active: jnp.ndarray) -> jnp.ndarray:
+    """Permutation sorting by keys (first = most significant); inactive rows
+    last; stable. ``sort_perm`` over the keys' order fields."""
+    fields = [order_field(~active)] + [order_field(k) for k in keys]
+    return sort_perm(fields)
+
+
+def cosort(pass_keys: Sequence[jnp.ndarray], payloads: Sequence[jnp.ndarray]):
+    """Stable sort by ``pass_keys`` (least significant first: the last is
+    primary) that brings ``payloads`` along. Returns (sorted_pass_keys,
+    sorted_payloads). The permutation comes from ``sort_perm``; keys and
+    payloads follow it in one ``gather_rows``, so the program holds one sort
+    of three operands whatever it carries."""
+    pass_keys, payloads = list(pass_keys), list(payloads)
+    perm = sort_perm([order_field(k) for k in reversed(pass_keys)])
+    moved = gather_rows(pass_keys + payloads, perm)
+    return moved[: len(pass_keys)], moved[len(pass_keys):]
 
 
 def last_active_prev(vals: jnp.ndarray, active: jnp.ndarray):
@@ -237,7 +397,9 @@ def live_indices(active: jnp.ndarray, new_cap: int) -> jnp.ndarray:
     n = active.shape[0]
     if new_cap * LIVE_INDEX_SHARE > n:
         pos = jnp.where(active, jnp.arange(n, dtype=jnp.int32), jnp.int32(n))
-        idx = jax.lax.sort(pos)[:new_cap]
+        # a sort of one operand takes the TPU's compiler 23 s at 18.9M rows, the
+        # same sort with a second operand that nothing reads 4 s (PR 34)
+        idx = jax.lax.sort((pos, jnp.zeros((n,), jnp.int8)), num_keys=1, is_stable=False)[0][:new_cap]
         if n < new_cap:
             idx = jnp.pad(idx, (0, new_cap - n), constant_values=n)
         return idx
@@ -253,20 +415,6 @@ def live_indices(active: jnp.ndarray, new_cap: int) -> jnp.ndarray:
     lane = jnp.sum(at_most, axis=1, dtype=jnp.int32) - 1
     idx = row_of.astype(jnp.int32) * _LIVE_ROW + lane
     return jnp.where(in_range, idx, jnp.int32(n))
-
-
-def boundary_positions(new_group: jnp.ndarray, out_cap: int) -> jnp.ndarray:
-    """Indices of the first out_cap True entries of ``new_group`` (ascending),
-    padded with n for absent slots — computed with a sort, not nonzero()."""
-    n = new_group.shape[0]
-    idx = jnp.arange(n)
-    keys, payload = cosort([(~new_group).astype(jnp.int8)], [idx])
-    starts = payload[0][:out_cap]
-    if starts.shape[0] < out_cap:  # out_cap may exceed tiny input capacities
-        starts = jnp.pad(starts, (0, out_cap - starts.shape[0]), constant_values=n)
-    rank = jnp.arange(out_cap)
-    count = jnp.sum(new_group.astype(jnp.int32))
-    return jnp.where(rank < count, starts, n)
 
 
 # --------------------------------------------------------------------------- #
@@ -372,6 +520,16 @@ def segment_reduce(
         )
         csum = cumsum(vals)
         n = values_sorted.shape[0]
+        if bounds is not None and jnp.issubdtype(vals.dtype, jnp.integer):
+            # exact arithmetic: the sum of a segment is the running sum before
+            # the next segment's start less the one before its own, ONE gather
+            # of capacity + 1 elements where the form below makes three (a
+            # gather of 8.4M int64 takes 143 ms on a v5e: Q18's 4.5M groups).
+            # bounds[0] is padded with n, where the running sum is the total
+            before = jnp.concatenate([jnp.zeros((1,), csum.dtype), csum])
+            starts = jnp.concatenate([bounds[0], jnp.full((1,), n, bounds[0].dtype)])
+            at_start = before[jnp.clip(starts, 0, n)]
+            return at_start[1:] - at_start[:-1]
         if bounds is not None:
             start, end = bounds
         else:
@@ -533,109 +691,98 @@ def scatter_first(
 # --------------------------------------------------------------------------- #
 
 
-def dense_ranks(values: jnp.ndarray) -> jnp.ndarray:
-    """Order-preserving map of int64 values to dense ranks in [0, ndv).
-
-    Sort-based renumbering: equal values get equal ranks, distinct values get
-    distinct ranks, rank order == value order. The building block that makes
-    multi-column key packing exact without range-product overflow."""
-    n = values.shape[0]
-    idx = jnp.arange(n)
-    (sk,), (si,) = cosort([values], [idx])
-    new = jnp.zeros(n, dtype=bool).at[0].set(True) | (sk != jnp.roll(sk, 1))
-    rank_sorted = cumsum(new.astype(jnp.int64)) - 1
-    # invert the permutation with another stable sort — scatter-free (TPU
-    # scatters serialize; sorting by the original index restores row order)
-    _, (ranks,) = cosort([si], [rank_sorted])
-    return ranks
-
-
-def pack_key_pair(
+def join_keys(
     probe_cols: Sequence[Tuple[jnp.ndarray, jnp.ndarray]],
     build_cols: Sequence[Tuple[jnp.ndarray, jnp.ndarray]],
 ):
-    """Pack multi-column join keys with renumbering shared across BOTH sides
-    (per-side renumbering would pack the same key to different codes).
-
-    Exact and overflow-free: columns are dense-ranked over the union of the two
-    sides and the partial pack re-densified between columns, bounding packed
-    values by (|probe|+|build|)^2 < 2^63 — no hash collisions, so no equality
-    confirmation pass is needed (ref: JoinCompiler hashes then CONFIRMS
-    equality, operator/join/PagesHash.java; here the pack is collision-free)."""
+    """Multi-column join keys as ``join_match`` takes them: (probe key
+    columns, probe_valid, build key columns, build_valid), a row valid when
+    every one of its key columns is. The columns stay apart: the match sorts
+    by all of their words at once, so a key of several columns needs no
+    renumbering pass and cannot collide (ref: JoinCompiler hashes then
+    CONFIRMS equality, operator/join/PagesHash.java; here the comparison is
+    on the values themselves)."""
     p_valid = probe_cols[0][1]
     for _, v in probe_cols[1:]:
         p_valid = p_valid & v
     b_valid = build_cols[0][1]
     for _, v in build_cols[1:]:
         b_valid = b_valid & v
-    if len(probe_cols) == 1:
-        return order_key(probe_cols[0][0]), p_valid, order_key(build_cols[0][0]), b_valid
-    cap_p = probe_cols[0][0].shape[0]
-    n = cap_p + build_cols[0][0].shape[0]
-    p_packed = b_packed = None
-    for (pd, _), (bd, _) in zip(probe_cols, build_cols):
-        u = dense_ranks(jnp.concatenate([order_key(pd), order_key(bd)]))
-        if p_packed is None:
-            p_packed, b_packed = u[:cap_p], u[cap_p:]
-        else:
-            both = jnp.concatenate([p_packed, b_packed]) * jnp.int64(n) + u
-            both = dense_ranks(both)
-            p_packed, b_packed = both[:cap_p], both[cap_p:]
-    return p_packed, p_valid, b_packed, b_valid
+    return [d for d, _ in probe_cols], p_valid, [d for d, _ in build_cols], b_valid
 
 
-def join_match(
-    build_key: jnp.ndarray,
-    build_active: jnp.ndarray,
-    probe_key: jnp.ndarray,
-    probe_active: jnp.ndarray,
-):
+def join_match(build_keys, build_active, probe_keys, probe_active, key_bits=None):
     """Sorted-build matching: returns (perm_b, lo, hi, count) where sorted build
     rows [lo, hi) match each probe row. (PagesHash/JoinProbe analogue.)
+    ``build_keys`` / ``probe_keys``: one int64 key column or a sequence of
+    them (a multi-column key, most significant first). ``key_bits``, where
+    given, holds for each column None or the bits its values fit (they are
+    then in [0, 2**bits) on every active row of both sides): the columns'
+    fields are packed, so narrow keys make a one-word sort.
 
-    Inactive build rows are keyed INT64_MAX but sort strictly AFTER active
-    rows of the same key (secondary sort on ~active), and ``hi`` is capped at
-    the active-row count — so a probe key that genuinely equals INT64_MAX can
-    never falsely match the inactive tail (PagesHash confirms equality after
-    the hash lookup for the same reason)."""
-    key_norm = jnp.where(build_active, build_key, jnp.int64(INT64_MAX))
-    perm_b = jnp.lexsort(((~build_active).astype(jnp.int8), key_norm))
-    n = probe_key.shape[0]
-    m = build_key.shape[0]
-    # probe ranks via ONE stable merge sort, not searchsorted: binary search
-    # is ~20 dependent gather rounds over the probe (measured 2.5s for 6M
-    # probes into 1M build on v5e) while a stable sort of the concatenated
-    # keys is HBM-streaming (23ms at 6M). Concat order IS the tie-break:
-    # [lo-queries, active builds, hi-queries] — a stable sort keeps equal
-    # keys in segment order, so a lo-query ranks before its equal builds
-    # (counting keys strictly below) and a hi-query after (counting <=).
-    # Inactive builds carry is_build=0 and INT64_MAX keys; a genuine
-    # INT64_MAX probe still matches genuine INT64_MAX ACTIVE builds, and
-    # its hi-query precedes the inactive tail by segment order.
-    merged_key = jnp.concatenate([probe_key, key_norm, probe_key])
+    Probe ranks come from ONE merge sort, not searchsorted: binary search is
+    ~20 dependent gather rounds over the probe (measured 2.5s for 6M probes
+    into 1M build on v5e) while a sort of the concatenated keys streams.
+    Concat order IS the tie-break: [lo-queries, builds, hi-queries], and the
+    row's position is the sort's last key, so a lo-query ranks before its
+    equal builds (counting keys strictly below) and a hi-query after
+    (counting <=). The sort's operands are the key's 32-bit words and one
+    tag, position * 2 + is_build: nothing else rides it (what a sort costs
+    the TPU's compiler grows with its operands). Only ACTIVE builds carry
+    is_build, so an inactive build row is never counted whatever its key
+    holds: no sentinel key, and a genuine INT64_MAX matches like any other.
+    ``perm_b`` lists the active build rows in key order, ties in row order;
+    the slots after them hold row 0 and are never matched."""
+    if not isinstance(build_keys, (list, tuple)):
+        build_keys, probe_keys = [build_keys], [probe_keys]
+    n = probe_active.shape[0]
+    m = build_active.shape[0]
+    total = 2 * n + m
+    if total >= 1 << 30:
+        raise ValueError(f"join of {n} probe and {m} build rows: the tag needs 2n + m < 2**30")
+    fields = []
+    for i, (bk, pk) in enumerate(zip(build_keys, probe_keys)):
+        bits = key_bits[i] if key_bits is not None else None
+        if bits is not None:
+            bk = jnp.where(build_active, bk, 0)
+            fields.append(order_field(jnp.concatenate([pk, bk, pk]), bits=bits))
+            continue
+        # integer keys of one width keep it (a date or an integer is one word,
+        # a bigint two); anything else meets as an int64 order key
+        if pk.dtype != bk.dtype or not jnp.issubdtype(pk.dtype, jnp.signedinteger):
+            pk, bk = order_key(pk), order_key(bk)
+        # an inactive build's key orders nothing: zero it
+        bk = jnp.where(build_active, bk, jnp.zeros((), bk.dtype))
+        fields.append(order_field(jnp.concatenate([pk, bk, pk])))
+    pos = jnp.arange(total, dtype=jnp.int32)
     is_build = jnp.concatenate(
-        [
-            jnp.zeros(n, dtype=jnp.int32),
-            build_active.astype(jnp.int32),
-            jnp.zeros(n, dtype=jnp.int32),
-        ]
+        [jnp.zeros(n, jnp.int32), build_active.astype(jnp.int32), jnp.zeros(n, jnp.int32)]
     )
-    # query id: lo-query i -> i, hi-query i -> n + i, builds -> 2n (dropped)
-    qid = jnp.concatenate(
-        [
-            jnp.arange(n, dtype=jnp.int32),
-            jnp.full(m, 2 * n, dtype=jnp.int32),
-            jnp.arange(n, 2 * n, dtype=jnp.int32),
-        ]
-    )
-    _, (s_is_build, s_qid) = cosort([merged_key], [is_build, qid])
+    words = sort_words(fields)[::-1]  # most significant first, as lax.sort compares
+    s_tag = jax.lax.sort(
+        (*words, pos * 2 + is_build), num_keys=len(words) + 1, is_stable=False
+    )[-1]
+    s_is_build = s_tag & 1
     builds_before = cumsum(s_is_build) - s_is_build  # exclusive
-    ranks = jnp.zeros(2 * n, dtype=jnp.int32).at[s_qid].set(
-        builds_before.astype(jnp.int32), mode="drop"
-    )
-    lo = ranks[:n]
-    hi = ranks[n:]
+    # back to the probe's order: query id i for lo-query i, n + i for hi-query
+    # i, 2n for the builds, which so sort behind the queries. A sort by the
+    # ids, which are distinct, and not a scatter: the TPU's scatter sorts its
+    # indices itself, with more operands
+    s_pos = s_tag >> 1
+    qid = jnp.where(s_pos < n, s_pos, jnp.where(s_pos >= n + m, s_pos - m, 2 * n))
+    rank = jax.lax.sort(
+        (qid, builds_before.astype(jnp.int32)), num_keys=1, is_stable=False
+    )[1][: 2 * n]
+    lo = rank[:n]
+    hi = rank[n:]
     count = jnp.where(probe_active, jnp.maximum(hi - lo, 0), 0)
+    # the builds in sorted order ARE perm_b: where the r-th active build stands
+    # in the merged order, read off the mask (``live_indices``: a walk over it
+    # where the builds are few among the queries, a sort of the positions
+    # where they are not). Slots past the active builds hold row 0: nothing
+    # matches there.
+    at = live_indices(s_is_build == 1, m)
+    perm_b = jnp.where(at < total, s_pos[jnp.minimum(at, total - 1)] - n, 0)
     return perm_b, lo, hi, count
 
 
@@ -658,12 +805,15 @@ def expand_probe_slots(emit: jnp.ndarray, out_capacity: int):
     # (searchsorted is ~20 dependent gather rounds; this is one scatter at
     # probe size + one scan at output size). Ties on start (zero-emit rows)
     # resolve to the max i — the searchsorted('right')-1 behavior.
+    # start never decreases, and the scatter is told so: left to find that
+    # out, the TPU's scatter sorts its indices first (13 s of compiling and a
+    # sort's time at 18.9M rows, PR 34)
     marks = (
         jnp.zeros(out_capacity, dtype=jnp.int32)
         .at[start]
-        .max(jnp.arange(start.shape[0], dtype=jnp.int32), mode="drop")
+        .max(jnp.arange(start.shape[0], dtype=jnp.int32), mode="drop", indices_are_sorted=True)
     )
-    probe_idx = jax.lax.cummax(marks)
+    probe_idx = cummax(marks)
     probe_idx = jnp.clip(probe_idx, 0, start.shape[0] - 1)
     d = p - start[probe_idx]
     out_active = p < total

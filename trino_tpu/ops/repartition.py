@@ -125,8 +125,8 @@ def _repartition_epilogue(n_parts: int, key_idx: Tuple[int, ...], page: Page):
     offsets[p] + counts[p]]`` in original relative order; inactive rows sort
     to the tail (destination ``n_parts``). Dictionaries ride the jit cache as
     static aux (page layout), so the value-key LUTs fold into the program as
-    constants. The stable cosort carries the payload rows inside lax.sort —
-    gathers cost ~60ns/element on TPU (ops/kernels.cosort rationale)."""
+    constants. The rows follow the stable sort of their destinations in one
+    gather (``K.cosort``: a three-operand sort and ``K.gather_rows``)."""
     dest = _partition_dest(n_parts, key_idx, page)
     counts = jnp.bincount(dest, length=n_parts + 1)[:n_parts].astype(jnp.int64)
     offsets = jnp.concatenate(

@@ -678,6 +678,33 @@ def push_filter_through_sort(root: PlanNode) -> PlanNode:
     return rewrite_plan(root, fn)
 
 
+def push_semijoin_through_join(root: PlanNode) -> PlanNode:
+    """``x IN (subquery)`` over an inner join is decided on the side that has
+    ``x``: the match depends on the source key alone, so the semi-join goes
+    below the join, as deep as inner joins reach, and the filter on its match
+    follows it there with the next predicate pushdown. TPC-H Q18 then keeps
+    its hundred-odd large orders before it meets ``lineitem`` and
+    ``customer``, and not after joining all 18 million lines to both (ref:
+    PredicatePushDown.visitSemiJoin pushes the source side's predicates; the
+    reference reaches the same plan through ReorderJoins' treatment of the
+    semi-join's output as a filter on its source)."""
+
+    def push(semi: SemiJoinNode) -> PlanNode:
+        join = semi.source
+        if not (isinstance(join, JoinNode) and join.kind in (JoinKind.INNER, JoinKind.CROSS)):
+            return semi
+        if semi.source_key in join.left.output_symbols:
+            return replace(join, left=push(replace(semi, source=join.left)))
+        if semi.source_key in join.right.output_symbols:
+            return replace(join, right=push(replace(semi, source=join.right)))
+        return semi
+
+    def fn(node: PlanNode) -> PlanNode:
+        return push(node) if isinstance(node, SemiJoinNode) else node
+
+    return rewrite_plan(root, fn)
+
+
 def push_filter_through_aggregation(root: PlanNode) -> PlanNode:
     """Conjuncts over group keys only filter identical rows before or after
     grouping — push them below (PushPredicateThroughProjectIntoRowNumber's

@@ -99,6 +99,24 @@ def _permute_column(c: Column, perm) -> Column:
     )
 
 
+def _permute_columns(cols: Sequence[Column], perm, extra=()):
+    """``[_permute_column(c, perm) for c in cols]`` and ``[a[perm] for a in
+    extra]``, with every flat array (data, validity, the extra masks) moved by
+    one ``K.gather_rows``; nested and multi-lane parts go as
+    ``_permute_column`` moves them. Returns (columns, moved extra)."""
+    flat = [not c.children and c.data.ndim == 1 and c.lengths is None for c in cols]
+    arrays = [a for c, f in zip(cols, flat) if f for a in (c.data, c.valid)] + list(extra)
+    moved = K.gather_rows(arrays, perm)
+    out, at = [], 0
+    for c, f in zip(cols, flat):
+        if f:
+            out.append(Column(c.type, moved[at], moved[at + 1], c.dictionary))
+            at += 2
+        else:
+            out.append(_permute_column(c, perm))
+    return tuple(out), moved[at:]
+
+
 def _slice_column(c: Column, n: int) -> Column:
     return Column(
         c.type, c.data[:n], c.valid[:n], c.dictionary,
@@ -148,6 +166,10 @@ class Relation:
     page: Page
     symbols: Tuple[str, ...]
     sorted_by: Tuple[str, ...] = ()
+    # live rows, where an operator has read the count to the host already
+    # (a compaction check, a join's output size, a group count); None where
+    # nobody has. For the operators' spans only: nothing executes by it.
+    rows: Optional[int] = None
 
     def env(self) -> Dict[str, CVal]:
         return {
@@ -302,6 +324,69 @@ def _live_rows(active, site: str) -> int:
     return _sync_int(jnp.sum(active.astype(jnp.int32)), site)
 
 
+JOIN_ROWS_COUNTER = "trino_tpu_join_rows_total"
+JOIN_ROWS_HELP = (
+    "rows a join read and wrote, by side: probe, build (live rows where the "
+    "executor had counted them, else the page's capacity), out (matches emitted)"
+)
+GROUP_ROWS_COUNTER = "trino_tpu_group_rows_total"
+GROUP_ROWS_HELP = (
+    "rows that entered an aggregation (live rows where counted, else the page's "
+    "capacity), by path: direct, presorted, sort, global"
+)
+SORT_PASSES_COUNTER = "trino_tpu_sort_passes_total"
+SORT_PASSES_HELP = (
+    "sort passes the sort-family programs launched hold: 32-bit words of packed "
+    "keys for a group sort or an ORDER BY (a pass whose word is the same in every "
+    "row is skipped on the device and still counted), one for a join's merge sort"
+)
+
+
+def _note(**attributes) -> None:
+    """Attributes on the operator's span (``op:<PlanNode>``, the current one)."""
+    span = TRACER.current()
+    if span is not None:
+        span.attributes.update(attributes)
+
+
+def _rows_or_capacity(rel: "Relation") -> int:
+    return rel.capacity if rel.rows is None else rel.rows
+
+
+def _count_join_rows(**sides: int) -> None:
+    """``trino_tpu_join_rows_total{side}`` += rows, for each side given."""
+    for side, rows in sides.items():
+        REGISTRY.counter(JOIN_ROWS_COUNTER, {"side": side}, help=JOIN_ROWS_HELP).inc(rows)
+
+
+def _type_counts(cols) -> Dict[str, int]:
+    """{SQL type: how many of ``cols`` have it}, as a span carries it."""
+    out: Dict[str, int] = {}
+    for c in cols:
+        out[c.type.display()] = out.get(c.type.display(), 0) + 1
+    return out
+
+
+def _sort_passes(bits: int) -> int:
+    """Passes ``K.sort_perm`` holds for keys of ``bits`` bits in all; ticks
+    the counter by them."""
+    passes = -(-bits // 32)
+    REGISTRY.counter(SORT_PASSES_COUNTER, help=SORT_PASSES_HELP).inc(passes)
+    return passes
+
+
+def _key_bits(c: Column) -> int:
+    """Width in bits of a column as a group key (``_group_key_parts`` packs a
+    dictionary's codes in as many; the span's ``sort_passes`` follow from it)."""
+    if c.data.ndim == 2:
+        return 128
+    if c.dictionary is not None:
+        return max(1, (len(c.dictionary) - 1).bit_length())
+    return 1 if c.data.dtype == jnp.bool_ else (
+        64 if jnp.issubdtype(c.data.dtype, jnp.floating) else c.data.dtype.itemsize * 8
+    )
+
+
 @dataclass
 class OperatorStats:
     """Per-plan-node execution stats (ref: operator/OperatorStats.java — the
@@ -384,6 +469,8 @@ class PlanExecutor:
         operator-at-a-time model; traced executors override with a static
         bound + overflow accounting)."""
         total = _sync_int(jnp.sum(emit), "join_capacity")
+        _note(rows_out=total)
+        _count_join_rows(out=total)
         return _round_capacity(max(total, 1))
 
     def __init__(
@@ -426,6 +513,9 @@ class PlanExecutor:
         self.ann_probe_stats: Dict[int, dict] = {}
         # join node -> (synthetic dynamic-filter node id, probe node id)
         self.dyn_filters: Dict[int, Tuple[int, int]] = {}
+        # {id(join node): {build key symbol: (least, most) of its live values}}
+        # as `_dynamic_filter_predicate` read them; `_join_key_widths` uses them
+        self._join_key_bounds: Dict[int, Dict[str, Tuple[int, int]]] = {}
         self._pinned: List[PlanNode] = []  # synthetic nodes the keys above reference
         from .memory import query_memory_context
 
@@ -1404,13 +1494,16 @@ class PlanExecutor:
             if rel is not None:
                 return rel
 
+        key_bits, key_bases = self._join_key_widths(node, probe, build)
         emit, count, lo, perm_b = _jit_join_match(
-            left_outer, pkeys, bkeys, luts, probe.page.active, build.page.active
+            left_outer, pkeys, bkeys, luts, probe.page.active, build.page.active,
+            key_bits, key_bases,
         )
         out_capacity = self._choose_join_capacity(emit, probe.capacity, build.capacity)
         page = _jit_join_expand(
             out_capacity, emit, count, lo, perm_b, probe.page, build.page
         )
+        rows_out = self._note_join(node, probe, build, out_capacity, key_bits)
 
         if kind == JoinKind.FULL:
             # append unmatched build rows with a null probe side (the join is
@@ -1423,7 +1516,7 @@ class PlanExecutor:
         # last probe row with start <= slot), so the probe side's sort order
         # survives INNER/LEFT joins; the FULL tail breaks it
         out_sorted = probe.sorted_by if kind != JoinKind.FULL else ()
-        out = Relation(page, probe.symbols + build.symbols, out_sorted)
+        out = Relation(page, probe.symbols + build.symbols, out_sorted, rows_out)
 
         if node.filter is not None:
             if kind == JoinKind.FULL:
@@ -1452,6 +1545,61 @@ class PlanExecutor:
                 out = Relation(page, out.symbols, out.sorted_by)
         self._tag_vector_broadcast(build, out)
         return out
+
+    def _join_key_widths(self, node: JoinNode, probe: Relation, build: Relation):
+        """(bits, least values) of the join's key columns where the dynamic
+        filter has read the build side's range to the host already: a key
+        that spans 2**bits values is matched as ``key - least`` in that many
+        bits, so a bigint key whose values span 18 million is one 32-bit word
+        of the match's sort and two keys of 25 and 30,000 values share one.
+        The width is static (rounded up to a multiple of four, so that
+        statements whose ranges differ a little share a program); the least
+        value is an argument. (None, None) where no range is known: the keys
+        take their types' widths."""
+        bounds = self._join_key_bounds.get(id(node))
+        if not bounds:
+            return None, None
+        bits, bases = [], []
+        for probe_sym, build_sym in node.criteria:
+            span = bounds.get(build_sym)
+            integral = all(
+                jnp.issubdtype(rel.column_for(sym).data.dtype, jnp.signedinteger)
+                and rel.column_for(sym).dictionary is None
+                for rel, sym in ((probe, probe_sym), (build, build_sym))
+            )
+            width = None
+            if span is not None and integral:
+                width = -(-max(1, (int(span[1]) - int(span[0])).bit_length()) // 4) * 4
+            if width is None or width > 60:
+                bits.append(None)
+                bases.append(np.int64(0))
+            else:
+                bits.append(width)
+                bases.append(np.int64(int(span[0])))  # an argument, not a program of its own
+        if all(b is None for b in bits):
+            return None, None
+        return tuple(bits), tuple(bases)
+
+    def _note_join(self, node, probe: Relation, build: Relation, out_capacity: int,
+                   key_bits=None):
+        """What the join moved, on its span and in the counters; every value
+        is on the host already. Returns the rows emitted where
+        ``_choose_join_capacity`` counted them (``sync:join_capacity``), else
+        None; a FULL join's tail and a residual filter come after it."""
+        keys = [probe.column_for(l) for l, _ in node.criteria]
+        _note(
+            probe_rows=_rows_or_capacity(probe), build_rows=_rows_or_capacity(build),
+            probe_capacity=probe.capacity, build_capacity=build.capacity,
+            capacity_out=out_capacity, key_types=[c.type.display() for c in keys],
+            key_bits=None if key_bits is None else list(key_bits),
+            probe_types=_type_counts(probe.page.columns),
+            build_types=_type_counts(build.page.columns), sort_passes=_sort_passes(1),
+        )
+        _count_join_rows(probe=_rows_or_capacity(probe), build=_rows_or_capacity(build))
+        if node.kind == JoinKind.FULL or node.filter is not None:
+            return None
+        span = TRACER.current()
+        return None if span is None else span.attributes.get("rows_out")
 
     def _tag_vector_broadcast(self, build: Relation, out: Relation) -> None:
         """Embedding-JOIN detection (vector serving plane): a build side
@@ -1599,6 +1747,7 @@ class PlanExecutor:
         from ..spi.types import BOOLEAN as B, is_string as _is_str
 
         conjuncts = []
+        bounds = self._join_key_bounds[id(node)] = {}
         for probe_sym, build_sym in node.criteria:
             bc = build.column_for(build_sym)
             if _is_str(bc.type):
@@ -1610,6 +1759,7 @@ class PlanExecutor:
             info_min = jnp.where(w, bc.data, bc.data.max()).min()
             info_max = jnp.where(w, bc.data, bc.data.min()).max()
             lo, hi = bc.type.storage_dtype.type(info_min).item(), bc.type.storage_dtype.type(info_max).item()
+            bounds[build_sym] = (lo, hi)  # the join's match packs its keys by them
             ptype = self.types[probe_sym]
             ref = Reference(probe_sym, ptype)
             conjuncts.append(
@@ -1638,7 +1788,18 @@ class PlanExecutor:
         page = _jit_semijoin(
             skey, fkey, lut, source.page, filtering.page.active, node.null_aware
         )
-        return Relation(page, source.symbols + (node.output,))
+        _note(
+            probe_rows=_rows_or_capacity(source), build_rows=_rows_or_capacity(filtering),
+            probe_capacity=source.capacity, build_capacity=filtering.capacity,
+            rows_out=_rows_or_capacity(source), capacity_out=source.capacity,
+            key_types=[skey.type.display()], probe_types=_type_counts(source.page.columns),
+            build_types=_type_counts([fkey]), sort_passes=_sort_passes(1),
+        )
+        _count_join_rows(
+            probe=_rows_or_capacity(source), build=_rows_or_capacity(filtering),
+            out=_rows_or_capacity(source),
+        )
+        return Relation(page, source.symbols + (node.output,), rows=source.rows)
 
     # ------------------------------------------------------------- sort/limit
 
@@ -1646,13 +1807,15 @@ class PlanExecutor:
         rel = self.eval(node.source)
         if self.allow_host_sync:
             rel = _maybe_compact(rel)
+        _note_sort(node.orderings, rel, None)
         page = _jit_sort(node.orderings, rel.symbols, None, rel.page)
-        return Relation(page, rel.symbols)
+        return Relation(page, rel.symbols, rows=rel.rows)
 
     def _exec_TopNNode(self, node: TopNNode) -> Relation:
         rel = self.eval(node.source)
         if self.allow_host_sync:
             rel = _maybe_compact(rel)
+        _note_sort(node.orderings, rel, node.count)
         page = _jit_sort(node.orderings, rel.symbols, node.count, rel.page)
         return Relation(page, rel.symbols)
 
@@ -1895,6 +2058,20 @@ def _load_splits(provider, splits, col_indexes, session) -> List[Page]:
         )
 
 
+def _note_sort(orderings, rel: Relation, count: Optional[int]) -> None:
+    """An ORDER BY's span attributes: rows in and out, keys, passes."""
+    rows_in = _rows_or_capacity(rel)
+    # `K.encode_sort_columns`: an int64 order key a column, two for Int128 limbs
+    bits = 1 + sum(128 if rel.column_for(o.symbol).data.ndim == 2 else 64 for o in orderings)
+    _note(
+        rows_in=rows_in, capacity_in=rel.capacity,
+        rows_out=rows_in if count is None else min(count, rows_in),
+        keys=len(orderings),
+        key_types=[rel.column_for(o.symbol).type.display() for o in orderings],
+        carried_types=_type_counts(rel.page.columns), sort_passes=_sort_passes(bits),
+    )
+
+
 def _maybe_compact(rel: Relation, density: int = 4, min_cap: int = 8192) -> Relation:
     """Drop inactive rows when fewer than 1/``density`` of capacity is live.
 
@@ -1907,15 +2084,16 @@ def _maybe_compact(rel: Relation, density: int = 4, min_cap: int = 8192) -> Rela
         return rel
     n = _live_rows(rel.page.active, "compact")
     if n * density > cap:
+        rel.rows = n
         return rel
     # compaction is a stable partition by activity — order preserved
-    return Relation(_compact(rel.page, n), rel.symbols, rel.sorted_by)
+    return Relation(_compact(rel.page, n), rel.symbols, rel.sorted_by, rows=n)
 
 
 COMPACTIONS_COUNTER = "trino_tpu_compactions_total"
 COMPACTIONS_HELP = (
-    "pages made dense, by path: index (live-row positions and a gather of the "
-    "rows kept) or sort (one stable sort that carries every column)"
+    "pages made dense, by how the rows kept are found: index (a walk over the "
+    "mask) or sort (one sort of the positions); the columns are gathered"
 )
 
 
@@ -1937,14 +2115,14 @@ def _compact(page: Page, live_rows: int) -> Page:
 
 
 def _compact_path(new_cap: int, page: Page) -> str:
-    """``index`` or ``sort``, from the static shapes alone. A gather costs per
-    row KEPT (v5e: 12 / 20 / 40 ns an element of 1 / 4 / 8 bytes, and 26 ns a
-    row for its position), a sort per row SCANNED (capacity 37.7M: 102 ms the
-    key, 47 ms each 32-bit operand word, 28 ms each mask). Measured at that
-    capacity with one and three bigint columns: an eighteenth kept, 164 and
-    421 ms by index against 252 and 505 by sort; a ninth kept, 327 and 857
-    (PERF.md section 6). Nested and multi-lane columns cannot ride
-    ``lax.sort`` and take the index path at any density."""
+    """How ``K.live_indices`` finds the rows kept, from the static shapes
+    alone, as the span and the counter name it: ``index`` (the walk over the
+    mask, work per row KEPT) under a sixteenth kept, ``sort`` (one sort of
+    the positions, the same at any share kept) above it. Either way the
+    columns follow by one ``K.gather_rows`` of the rows kept: no column rides
+    a sort (PERF.md section 6, PR 34: the eleven-operand sort that carried
+    them compiled for 206 s). Nested and multi-lane pages are named
+    ``index`` at any density, as before."""
     flat = not any(c.children or c.data.ndim > 1 for c in page.columns)
     if flat and new_cap * K.LIVE_INDEX_SHARE > page.capacity:
         return "sort"
@@ -1953,28 +2131,10 @@ def _compact_path(new_cap: int, page: Page) -> str:
 
 @partial(kernelcost.jit, static_argnums=(0,))
 def _jit_compact(new_cap: int, page: Page) -> Page:
-    if _compact_path(new_cap, page) == "index":
-        idx = K.live_indices(page.active, new_cap)
-        rows = jnp.minimum(idx, page.capacity - 1)  # padding slots: any row
-        cols = tuple(_permute_column(c, rows) for c in page.columns)
-        return Page(cols, idx < page.capacity)
-    key = (~page.active).astype(jnp.int8)
-    payloads: List[jnp.ndarray] = []
-    for c in page.columns:
-        payloads.append(c.data)
-        payloads.append(c.valid)
-    payloads.append(page.active)
-    _, sorted_payloads = K.cosort([key], payloads)
-    cols = tuple(
-        Column(
-            c.type,
-            sorted_payloads[2 * i][:new_cap],
-            sorted_payloads[2 * i + 1][:new_cap],
-            c.dictionary,
-        )
-        for i, c in enumerate(page.columns)
-    )
-    return Page(cols, sorted_payloads[-1][:new_cap])
+    idx = K.live_indices(page.active, new_cap)
+    rows = jnp.minimum(idx, page.capacity - 1)  # padding slots: any row
+    cols, _ = _permute_columns(page.columns, rows)
+    return Page(cols, idx < page.capacity)
 
 
 def _needed_agg_symbols(node: AggregationNode) -> Tuple[str, ...]:
@@ -2049,16 +2209,18 @@ def aggregate_relation(
 
     - direct-indexed (small static key domains): gid computed elementwise from
       dictionary codes, one fused bandwidth-bound pass — no sort, no host sync.
-    - sort-based: (1) co-sort the needed columns by the group keys inside
-      lax.sort (no permutation gathers of every row: K.cosort has the costs),
-      host-sync the group count, (2) reduction program with a bucketed static
-      output capacity, segment sums via cumsum-at-boundaries."""
+    - sort-based: (1) order the needed columns by the group keys (one sort of
+      the keys' packed words, the columns gathered by its permutation:
+      ``_group_sort_impl``), host-sync the group count, (2) reduction program
+      with a bucketed static output capacity, segment sums via
+      cumsum-at-boundaries."""
     domains = _direct_agg_domains(rel, node)
     if domains is not None:
         page = _jit_direct_aggregate(
             node.group_keys, node.aggregations, domains, rel.symbols, rel.page,
             pallas_mode,
         )
+        _note_aggregation(node, rel, "direct", None)
         return Relation(page, node.group_keys + tuple(s for s, _ in node.aggregations))
     # sparse inputs (a selective filter upstream) would drag dead rows through
     # every multi-pass sort — compact first (this path host-syncs anyway).
@@ -2103,18 +2265,22 @@ def aggregate_relation(
             )
             if not _sync_int(viol, "presorted_check"):
                 sorted_page, new_group, num_groups = p, ng, n_grp
+        path = "presorted"
         if sorted_page is None:
+            path = "sort"
             sorted_page, new_group, num_groups = _jit_group_sort(
                 node.group_keys, needed, rel.symbols, rel.page
             )
-        out_cap = min(
-            _round_capacity(max(_sync_int(num_groups, "num_groups"), 1), base=16), max(rel.capacity, 16)
-        )
+        groups = _sync_int(num_groups, "num_groups")
+        _note_aggregation(node, rel, path, groups)
+        out_cap = min(_round_capacity(max(groups, 1), base=16), max(rel.capacity, 16))
     else:
         # global aggregation: no sort at all — select the needed columns
         cols = tuple(rel.column_for(s) for s in needed)
         sorted_page = Page(cols, rel.page.active)
         new_group, num_groups, out_cap = None, 1, 1
+        groups = 1
+        _note_aggregation(node, rel, "global", 1)
     # lane-valued aggregates (array_agg, map_agg, histogram, multimap_agg,
     # listagg) need a static lane width = the largest group's row count
     # (host-synced like num_groups; ref operator/aggregation/ArrayAggregation)
@@ -2161,7 +2327,26 @@ def aggregate_relation(
                 cols[nk + i] = _finalize_multimap(cols[nk + i], agg.output_type)
         page = Page(tuple(cols), page.active)
     out_symbols = node.group_keys + tuple(s for s, _ in node.aggregations)
-    return Relation(page, out_symbols)
+    return Relation(page, out_symbols, rows=groups)
+
+
+def _note_aggregation(node: AggregationNode, rel: Relation, path: str, groups) -> None:
+    """An aggregation's span attributes and ``trino_tpu_group_rows_total``.
+    ``groups`` is the synced count (None on the direct path, which reads
+    none); a group sort's passes follow from its keys' widths."""
+    keys = [rel.column_for(k) for k in node.group_keys]
+    rows_in = _rows_or_capacity(rel)
+    attributes = dict(
+        path=path, rows_in=rows_in, capacity_in=rel.capacity, groups=groups,
+        keys=len(keys), key_types=[c.type.display() for c in keys],
+        agg_types=_type_counts(
+            rel.column_for(s) for s in _needed_agg_symbols(node) if s not in node.group_keys
+        ),
+    )
+    if path == "sort":
+        attributes["sort_passes"] = _sort_passes(1 + sum(_key_bits(c) + 1 for c in keys))
+    _note(**attributes)
+    REGISTRY.counter(GROUP_ROWS_COUNTER, {"path": path}, help=GROUP_ROWS_HELP).inc(rows_in)
 
 
 # aggregates whose per-group state is a padded lane grid [out_cap, agg_w]
@@ -2260,63 +2445,60 @@ _jit_presorted_group = partial(kernelcost.jit, static_argnums=(0, 1, 2))(
 
 
 def _group_sort_impl(group_keys, needed, symbols, page: Page):
-    """Phase 1: co-sort needed columns by group keys; detect group boundaries.
-    Returns (sorted Page over ``needed`` symbols, new_group mask, num_groups).
+    """Phase 1: order the needed columns by the group keys; detect group
+    boundaries. Returns (sorted Page over ``needed`` symbols, new_group mask,
+    num_groups). Active rows first; then, key by key, nulls before values and
+    values ascending. The order is one ``K.sort_perm`` over the keys' packed
+    order fields (a key's width is its type's or its dictionary's, so seven
+    keys are a handful of words and the program holds one three-operand sort
+    whatever the keys and the columns); the columns follow in one gather.
     Plain body — ops/megakernels.py re-traces it inside the fused join
     kernel's sort-path aggregation stage (bit-identity by construction)."""
     rel = Relation(page, symbols)
-    pass_keys: List[jnp.ndarray] = []
-    # least-significant first; each key contributes (norm, validity-bit) passes
-    for k in reversed(group_keys):
+    fields = []
+    for i, k in enumerate(group_keys):
         c = rel.column_for(k)
-        if c.data.ndim == 2:  # Int128 limbs: lo pass then hi pass
-            from ..ops import int128 as i128
-
-            h, l = i128.order_key_pair(c.data)
-            pass_keys.append(jnp.where(c.valid, l, jnp.int64(K.INT64_MAX)))
-            pass_keys.append(jnp.where(c.valid, h, jnp.int64(K.INT64_MAX)))
-        else:
-            norm = jnp.where(c.valid, K.order_key(c.data), jnp.int64(K.INT64_MAX))
-            pass_keys.append(norm)
-        pass_keys.append(c.valid.astype(jnp.int8))
-    pass_keys.append((~page.active).astype(jnp.int8))  # inactive rows last
-
-    payloads: List[jnp.ndarray] = []
-    lanes: List[int] = []  # payloads per column's data (Int128 limbs ride as 2)
-    for s in needed:
-        c = rel.column_for(s)
-        if c.data.ndim == 2:
-            for j in range(c.data.shape[1]):
-                payloads.append(c.data[:, j])
-            lanes.append(c.data.shape[1])
-        else:
-            payloads.append(c.data)
-            lanes.append(1)
-        payloads.append(c.valid)
-    payloads.append(page.active)
-
-    sorted_keys, sorted_payloads = K.cosort(pass_keys, payloads)
-    active_s = sorted_payloads[-1]
+        flag = c.valid.astype(jnp.uint64)  # nulls (0) before values (1)
+        if i == 0:  # and inactive rows (2) after both
+            flag = jnp.where(page.active, flag, jnp.uint64(2))
+        fields.append((flag, 2 if i == 0 else 1))
+        fields.extend(K.order_field(x, bits) for x, bits in _group_key_parts(c))
+    perm = K.sort_perm(fields)
+    cols, (active_s,) = _permute_columns(
+        [rel.column_for(s) for s in needed], perm, extra=[page.active]
+    )
     cap = page.capacity
+    by_symbol = dict(zip(needed, cols))
     diff = jnp.zeros(cap, dtype=bool)
-    for k in sorted_keys[:-1]:
-        diff = diff | (k != jnp.roll(k, 1))
+    for k in group_keys:
+        c = by_symbol[k]
+        diff = diff | (c.valid != jnp.roll(c.valid, 1))
+        for x, _ in _group_key_parts(c):
+            diff = diff | (x != jnp.roll(x, 1))
     first = jnp.zeros(cap, dtype=bool).at[0].set(True)
     prev_active = jnp.roll(active_s, 1).at[0].set(False)
     new_group = active_s & (first | diff | ~prev_active)
     num_groups = jnp.sum(new_group.astype(jnp.int32))
+    return Page(cols, active_s), new_group, num_groups
 
-    cols = []
-    pos = 0
-    for s, nl in zip(needed, lanes):
-        c = rel.column_for(s)
-        if nl == 1:
-            data = sorted_payloads[pos]
-        else:
-            data = jnp.stack(sorted_payloads[pos : pos + nl], axis=-1)
-        cols.append(Column(c.type, data, sorted_payloads[pos + nl], c.dictionary))
-        pos += nl + 1
-    return Page(tuple(cols), active_s), new_group, num_groups
+
+def _group_key_parts(c: Column):
+    """A group key as integers that are equal where the key is and ordered
+    as it is, with the width each needs where it is known: [(values, bits or
+    None)]. Nulls read zero (the validity bit is compared apart), a double
+    its order key (so -0.0 and 0.0 stay two groups and NaNs one, as the sort
+    sees them), Int128 limbs two parts, a dictionary's codes as many bits as
+    the dictionary needs."""
+    if c.data.ndim == 2:
+        from ..ops import int128 as i128
+
+        return [(jnp.where(c.valid, x, 0), None) for x in i128.order_key_pair(c.data)]
+    if c.dictionary is not None:
+        return [(jnp.where(c.valid, c.data, 0), _key_bits(c))]
+    data = c.data
+    if jnp.issubdtype(data.dtype, jnp.floating):
+        data = K.float_order_key(data)
+    return [(jnp.where(c.valid, data, jnp.zeros((), data.dtype)), None)]
 
 
 _jit_group_sort = partial(kernelcost.jit, static_argnums=(0, 1, 2))(_group_sort_impl)
@@ -2350,7 +2532,7 @@ def _aggregate_impl(
     bounds = None
     gid = None
     if not global_agg:
-        starts = K.boundary_positions(new_group, out_cap)  # n-padded
+        starts = K.live_indices(new_group, out_cap)  # n-padded
         ends = jnp.concatenate([starts[1:], jnp.array([n])]) - 1
         bounds = (starts, ends)
         safe_starts = jnp.clip(starts, 0, n - 1)
@@ -3153,14 +3335,22 @@ def _project_impl(compiled, env: Dict[str, CVal], page: Page) -> Page:
 _jit_project = partial(kernelcost.jit, static_argnums=(0,))(_project_impl)
 
 
-@partial(kernelcost.jit, static_argnums=(0,))
-def _jit_join_match(left_outer: bool, pkeys, bkeys, luts, probe_active, build_active):
-    """Join phase 1: key normalization + sorted-build matching + emit counts."""
+@partial(kernelcost.jit, static_argnums=(0, 6))
+def _jit_join_match(
+    left_outer: bool, pkeys, bkeys, luts, probe_active, build_active,
+    key_bits=None, key_bases=None,
+):
+    """Join phase 1: key normalization + sorted-build matching + emit counts.
+    ``key_bits`` / ``key_bases`` (``PlanExecutor._join_key_widths``): where a
+    key column's entry is a width, the column is matched as ``value - base``
+    in that many bits; a probe value outside that range matches nothing, as
+    no live build value lies there."""
     if not pkeys:  # cross join: all-equal keys
-        probe_key = jnp.zeros(probe_active.shape, dtype=jnp.int64)
-        build_key = jnp.zeros(build_active.shape, dtype=jnp.int64)
+        probe_key = [jnp.zeros(probe_active.shape, dtype=jnp.int32)]
+        build_key = [jnp.zeros(build_active.shape, dtype=jnp.int32)]
         probe_valid = jnp.ones(probe_active.shape, dtype=jnp.bool_)
         build_valid = jnp.ones(build_active.shape, dtype=jnp.bool_)
+        key_bits = None
     else:
         aligned = []
         for (pd, pv), lut in zip(pkeys, luts):
@@ -3168,12 +3358,21 @@ def _jit_join_match(left_outer: bool, pkeys, bkeys, luts, probe_active, build_ac
                 mapped = lut[jnp.clip(pd, 0, lut.shape[0] - 1)]
                 pd, pv = mapped, pv & (mapped >= 0)
             aligned.append((pd, pv))
-        probe_key, probe_valid, build_key, build_valid = K.pack_key_pair(
+        probe_key, probe_valid, build_key, build_valid = K.join_keys(
             aligned, list(bkeys)
         )
+    if key_bits is not None:
+        for i, (bits, base) in enumerate(zip(key_bits, key_bases)):
+            if bits is None:
+                continue
+            rebased = probe_key[i].astype(jnp.int64) - base
+            inside = (rebased >= 0) & (rebased < (1 << bits))
+            probe_valid = probe_valid & inside
+            probe_key[i] = jnp.where(inside, rebased, 0)
+            build_key[i] = build_key[i].astype(jnp.int64) - base
     pa = probe_active & probe_valid
     ba = build_active & build_valid
-    perm_b, lo, hi, count = K.join_match(build_key, ba, probe_key, pa)
+    perm_b, lo, hi, count = K.join_match(build_key, ba, probe_key, pa, key_bits)
     if left_outer:
         emit = jnp.where(probe_active, jnp.maximum(count, 1), 0)
     else:
@@ -3188,11 +3387,8 @@ def _jit_join_expand(
     probe_idx, build_pos, matched, out_active, _ = K.expand_matches(
         emit, count, lo, perm_b, out_capacity
     )
-    cols = []
-    for c in probe_page.columns:
-        cols.append(_permute_column(c, probe_idx))
-    for c in build_page.columns:
-        pc = _permute_column(c, build_pos)
+    cols = list(_permute_columns(probe_page.columns, probe_idx)[0])
+    for pc in _permute_columns(build_page.columns, build_pos)[0]:
         cols.append(replace(pc, valid=pc.valid & matched))
     return Page(tuple(cols), out_active)
 
@@ -3215,11 +3411,8 @@ def _jit_left_join_residual(
     probe_idx, build_pos, matched, out_active, _ = K.expand_matches(
         emit, count, lo, perm_b, out_capacity
     )
-    cols = []
-    for c in probe_page.columns:
-        cols.append(_permute_column(c, probe_idx))
-    for c in build_page.columns:
-        pc = _permute_column(c, build_pos)
+    cols = list(_permute_columns(probe_page.columns, probe_idx)[0])
+    for pc in _permute_columns(build_page.columns, build_pos)[0]:
         cols.append(replace(pc, valid=pc.valid & matched))
     env = {s: _cval_of(c) for s, c in zip(symbols, cols)}
     v = residual_fn(env)
@@ -3251,7 +3444,7 @@ def _jit_full_join_tail(pkeys, bkeys, luts, probe_page: Page, build_page: Page) 
             mapped = lut[jnp.clip(pd, 0, lut.shape[0] - 1)]
             pd, pv = mapped, pv & (mapped >= 0)
         aligned.append((pd, pv))
-    probe_key, probe_valid, build_key, build_valid = K.pack_key_pair(
+    probe_key, probe_valid, build_key, build_valid = K.join_keys(
         aligned, list(bkeys)
     )
     matched_b = K.semijoin_mask(
@@ -3311,7 +3504,7 @@ def _sort_impl(orderings, symbols, count, page: Page) -> Page:
         # per column, not full capacity (gathers cost ~60ns/element on TPU)
         n = min(count, page.capacity)
         perm, out_active = perm[:n], out_active[:n]
-    cols = tuple(_permute_column(c, perm) for c in page.columns)
+    cols, _ = _permute_columns(page.columns, perm)
     return Page(cols, out_active)
 
 
