@@ -201,6 +201,10 @@ def _compactions(path: str) -> float:
     return REGISTRY.counter(E.COMPACTIONS_COUNTER, {"path": path}).value
 
 
+def _compaction_gathers(form: str) -> float:
+    return REGISTRY.counter(E.COMPACTION_GATHERS_COUNTER, {"form": form}).value
+
+
 class TestCallers:
     def test_maybe_compact_keeps_row_order_and_sorted_by(self):
         order = np.arange(CAP, dtype=np.int64) * 3
@@ -215,10 +219,45 @@ class TestCallers:
         spans = {s.name: s.attributes for s in TRACER.spans(root.trace_id)}
         assert spans["compact"] == {
             "capacity_in": CAP, "live_rows": CAP // 100, "capacity_out": 1024,
-            "columns": 1, "path": "index",
+            "columns": 1, "path": "index", "gather": "packed", "words": 3,
         }
         assert spans["sync:compact"]["value"] == CAP // 100
         assert _compactions("index") == before + 1
+
+    @pytest.mark.parametrize(
+        "cap,live,path,form",
+        [
+            (1 << 20, 10, "index", "plain"),       # one row in 1,024 of a long page: a gather an array
+            (CAP, CAP // 100, "index", "packed"),  # one in 64: the columns' words as one matrix
+            (16384, 16384 // 4, "sort", "packed"),
+        ],
+    )
+    def test_the_span_and_the_counter_name_the_form_of_the_gather(self, cap, live, path, form):
+        mask = np.zeros(cap, dtype=bool)
+        mask[np.random.default_rng(cap).choice(cap, live, replace=False)] = True
+        page = _layout_page("flat", cap, mask)
+        before = {f: _compaction_gathers(f) for f in ("packed", "plain")}
+        with TRACER.span("test") as root:
+            out = E._compact(page, live)
+        (span,) = [s.attributes for s in TRACER.spans(root.trace_id) if s.name == "compact"]
+        arrays = [a for c in page.columns for a in (c.data, c.valid)]
+        gathers, words = K.gather_shape(arrays)
+        assert (span["path"], span["gather"], span["words"]) == (path, form, words)
+        assert form == K.gather_form(cap, span["capacity_out"], gathers, words)
+        after = {f: _compaction_gathers(f) for f in ("packed", "plain")}
+        assert after == {f: before[f] + (f == form) for f in before}
+        # the program is the form the span names: a gather an array, or one of them all
+        text = E._jit_compact.lower(span["capacity_out"], page).as_text()
+        moved = text.count('"stablehlo.gather"(') - (2 if path == "index" else 0)  # live_indices' own
+        assert moved == (1 if form == "packed" else len(arrays))
+        _assert_compacted(page, out, span["capacity_out"])
+
+    def test_a_nested_page_packs_its_flat_column_alone(self):
+        page = _layout_page("nested", 16384, _mask("one_pct", 16384))
+        with TRACER.span("test") as root:
+            E._compact(page, 163)
+        (span,) = [s.attributes for s in TRACER.spans(root.trace_id) if s.name == "compact"]
+        assert (span["gather"], span["words"]) == ("packed", 3)  # its one flat column: a bigint's two words and its mask
 
     @pytest.mark.parametrize("name", ["quarter", "full"])
     def test_maybe_compact_leaves_a_quarter_live_alone(self, name):

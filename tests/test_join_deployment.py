@@ -214,18 +214,99 @@ def test_join_match_counts_and_places_matches_as_numpy_does(columns):
             assert perm_b[lo[i]: lo[i] + count[i]].tolist() == want  # ties in row order
 
 
-def test_gather_rows_is_a_gather_of_each_array():
-    rng = np.random.default_rng(9)
-    n = 1000
-    arrays = [
+def _gather_zoo(rng, n: int) -> list:
+    """A 64-bit integer, a double, a boolean, an 8-bit and a 32-bit integer, a
+    float, a two-dimensional array that rides along, and more flags than one
+    word holds."""
+    return [
         rng.integers(-(2**62), 2**62, size=n), rng.normal(size=n), rng.random(n) < 0.5,
         rng.integers(-100, 100, size=n).astype(np.int8), rng.integers(0, 9, size=n).astype(np.int32),
         rng.normal(size=n).astype(np.float32), rng.integers(0, 9, size=(n, 2)),
-    ] + [rng.random(n) < 0.5 for _ in range(40)]  # more flags than one word holds
-    for idx in (rng.permutation(n), rng.integers(0, n, size=5), rng.integers(0, n, size=3000)):
-        moved = jax.jit(K.gather_rows)([jnp.asarray(a) for a in arrays], jnp.asarray(idx.astype(np.int32)))
+    ] + [rng.random(n) < 0.5 for _ in range(40)]
+
+
+def _gathers(lowered_text: str) -> list:
+    """The operand type of every gather instruction in a lowered program."""
+    return re.findall(r'"stablehlo\.gather"\([^)]*\)[^\n]*?:\s*\(tensor<([^>]*)>', lowered_text)
+
+
+GATHER_N = 65536
+ZOO_SHAPE = (48, 9)  # the zoo's one-dimensional arrays: 48 gathers of their own, or 9 words
+# the fewest rows moved at which the zoo travels packed: about one row in 2,500 of 65,536
+ZOO_CROSSOVER = next(m for m in range(1, GATHER_N) if K.gather_form(GATHER_N, m, *ZOO_SHAPE) == "packed")
+
+
+@pytest.mark.parametrize("order", ["ascending", "shuffled"])
+@pytest.mark.parametrize(
+    "m", [5, ZOO_CROSSOVER - 1, ZOO_CROSSOVER, ZOO_CROSSOVER + 1, GATHER_N // 72, GATHER_N // 8, GATHER_N, 3 * GATHER_N]
+)
+def test_gather_rows_is_a_gather_of_each_array(m, order):
+    """On both sides of the crossover and at it, whichever form is taken."""
+    rng = np.random.default_rng(9)
+    arrays = _gather_zoo(rng, GATHER_N)
+    assert K.gather_shape(arrays) == ZOO_SHAPE and 5 < ZOO_CROSSOVER < GATHER_N // 72
+    idx = rng.integers(0, GATHER_N, size=m).astype(np.int32)
+    idx = np.sort(idx) if order == "ascending" else idx
+    program = jax.jit(K.gather_rows)
+    args = ([jnp.asarray(a) for a in arrays], jnp.asarray(idx))
+    moved = program(*args)
+    for got, a in zip(moved, arrays):
+        assert got.dtype == a.dtype and np.array_equal(np.asarray(got), a[idx])
+    # the rule is the program: the matrix and the array that rides along, or a gather an array
+    form = K.gather_form(GATHER_N, m, *ZOO_SHAPE)
+    assert form == ("plain" if m < ZOO_CROSSOVER else "packed")
+    assert len(_gathers(program.lower(*args).as_text())) == (2 if form == "packed" else len(arrays))
+
+
+def test_each_form_alone_is_a_gather_of_each_array():
+    """The packed form where the rule would not take it (five rows of 4,096)
+    and the plain one where it would not either."""
+    rng = np.random.default_rng(10)
+    arrays = _gather_zoo(rng, 4096)
+    for idx in (rng.integers(0, 4096, size=5), rng.permutation(4096)):
+        moved = jax.jit(K._gather_packed)([jnp.asarray(a) for a in arrays], jnp.asarray(idx))
         for got, a in zip(moved, arrays):
             assert got.dtype == a.dtype and np.array_equal(np.asarray(got), a[idx])
+    assert K.gather_rows([], jnp.arange(3)) == []
+    (alone,) = K.gather_rows([jnp.asarray(arrays[4])], jnp.arange(4096)[::-1])
+    assert np.array_equal(np.asarray(alone), arrays[4][::-1])
+
+
+GATHER_FORMS = {
+    # (n, m, gathers, words): form. The probe's table (chiprun_out/pr35_probe, PR 35) on both sides:
+    "q14_compaction": ((18_874_368, 262_144, 11, 8), "packed"),         # one row in 72: 11.5 ms against 42.6
+    "q14_as_arrays": ((18_874_368, 262_144, 8, 8), "packed"),           # ISSUE 35's count: eight arrays
+    "one_in_288": ((18_874_368, 65_536, 11, 8), "packed"),              # 8.7 against 12.9
+    "one_in_256": ((16_777_216, 65_536, 11, 8), "packed"),              # 8.1 against 12.1
+    "one_in_1024": ((16_777_216, 16_384, 11, 8), "plain"),              # 7.3 against 5.8
+    "one_in_1152": ((18_874_368, 16_384, 11, 8), "plain"),              # 8.1 against 6.0
+    "one_in_18432": ((18_874_368, 1_024, 11, 8), "plain"),              # 8.0 against 4.1
+    "small_page_one_in_64": ((1_048_576, 16_384, 11, 8), "packed"),     # 1.5 against 2.8
+    "small_page_one_in_1024": ((1_048_576, 1_024, 11, 8), "plain"),     # 1.4 against 1.3
+    "q3_expansion": ((16_777_216, 131_072, 11, 8), "packed"),           # one row in 128
+    "topn": ((524_288, 16, 11, 8), "plain"),                            # ten rows of a long page
+    "group_starts": ((18_874_368, 64, 3, 3), "plain"),                  # a few groups of a long page
+    "a_sort": ((18_874_368, 18_874_368, 11, 8), "packed"),              # every row moves
+    "a_dense_compaction": ((4_194_304, 1_048_576, 22, 15), "packed"),
+    "one_word": ((1_048_576, 1_048_576, 1, 1), "plain"),                # nothing to travel with
+    "nothing": ((0, 3, 0, 0), "plain"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GATHER_FORMS))
+def test_gather_form_is_what_costs_less_by_the_static_shapes(case):
+    shape, form = GATHER_FORMS[case]
+    assert K.gather_form(*shape) == form
+
+
+def test_gather_shape_counts_gathers_and_words():
+    S = jax.ShapeDtypeStruct
+    q14 = [S((64,), t) for t in (jnp.int64, jnp.bool_, jnp.int64, jnp.bool_, jnp.int64, jnp.bool_, jnp.int32, jnp.bool_)]
+    assert K.gather_shape(q14) == (11, 8)
+    assert K.gather_shape(q14 + [S((64, 2), jnp.int64)]) == (11, 8)  # limbs are rows already
+    assert K.gather_shape([S((64,), jnp.bool_)] * 33) == (33, 2)
+    assert K.gather_shape([S((64,), jnp.int8), S((64,), jnp.float64)]) == (3, 3)
+    assert K.gather_shape([]) == (0, 0)
 
 
 # -------------------------------- what the TPU's compiler is handed (PERF.md, PR 34)
@@ -295,6 +376,27 @@ def test_sort_family_programs_hold_few_small_sorts(program):
     most, operands = PROGRAM_SHAPES[program]
     sorts = _sorts(text)
     assert 1 <= len(sorts) <= most and sum(sorts) <= operands, sorts
+
+
+def test_q14s_compaction_moves_its_columns_in_one_gather():
+    """`_jit_compact` lowered for a page of q14's shape at SF3 (shapes alone, no
+    data: 18,874,368 rows of a bigint, two decimal(12,2) and a date, 262,144
+    kept): the row gather of `live_indices`, its slots' rows, and ONE gather
+    of the columns' eight words where there were eight gathers (ISSUE 35)."""
+    n, m = 18_874_368, 262_144
+    S = jax.ShapeDtypeStruct
+    on = S((n,), jnp.bool_)
+    page = Page(
+        (
+            Column(BIGINT, S((n,), jnp.int64), on), Column(decimal_type(12, 2), S((n,), jnp.int64), on),
+            Column(decimal_type(12, 2), S((n,), jnp.int64), on), Column(DATE, S((n,), jnp.int32), on),
+        ),
+        on,
+    )
+    finds = _gathers(jax.jit(K.live_indices, static_argnums=1).lower(on, m).as_text())
+    gathers = _gathers(E._jit_compact.lower(m, page).as_text())
+    assert len(finds) == 2 and f"{n // 256}x256xui8" in finds
+    assert sorted(gathers) == sorted(finds + [f"8x{n}xi32"])
 
 
 # --------------------------------------------------------- spans and counters

@@ -200,6 +200,29 @@ class TestOneTree:
             # under a sixteenth of the page is kept: positions and a gather
             assert a["capacity_out"] * 16 <= a["capacity_in"]
             assert a["path"] == "index"
+            # one row in 32 or 64 of 65,536: the columns' words travel as one matrix
+            assert a["gather"] == "packed" and a["words"] >= 2
+
+    def test_a_dense_compaction_states_its_gather_too(self, client):
+        """About a row in eleven kept: the positions are sorted, the columns
+        follow in one packed gather, and both counters tick."""
+        from trino_tpu.runtime import executor as E
+        from trino_tpu.runtime.metrics import REGISTRY
+
+        def ticks():
+            return (
+                REGISTRY.counter(E.COMPACTIONS_COUNTER, {"path": "sort"}).value,
+                REGISTRY.counter(E.COMPACTION_GATHERS_COUNTER, {"form": "packed"}).value,
+            )
+
+        before = ticks()
+        res = client.execute(
+            "SELECT l_orderkey, sum(l_quantity) FROM lineitem WHERE l_discount = 0.05 GROUP BY l_orderkey"
+        )
+        compact = [s.attributes for s in finished_tree(res.query_id) if s.name == "compact"]
+        assert compact and all(a["capacity_out"] * 16 > a["capacity_in"] for a in compact)
+        assert all((a["path"], a["gather"]) == ("sort", "packed") and a["words"] >= 2 for a in compact)
+        assert ticks() == (before[0] + len(compact), before[1] + len(compact))
 
     def test_a_global_sum_under_a_selective_filter_does_not_compact(self, q06):
         _, tree, _ = q06

@@ -1,6 +1,6 @@
 """Compiles for the chip that is not attached (the `on-chip-measurement` guide,
 section 2): the sort family's programs at the join deployment's shapes, held to
-the budget ISSUE 34 set: no program over 90 s. Three programs, all in this one
+the budget ISSUE 34 set: no program over 90 s. Four programs, all in this one
 file; the topology is described inside a fixture and nothing touches the TPU's
 library while a module is imported. A compile that passes is not a chip run."""
 
@@ -13,7 +13,7 @@ import pytest
 
 from trino_tpu.runtime import executor as E
 from trino_tpu.spi.page import Column, Dictionary, Page
-from trino_tpu.spi.types import BIGINT, VARCHAR, decimal_type
+from trino_tpu.spi.types import BIGINT, DATE, VARCHAR, decimal_type
 
 BUDGET_S = 90.0
 
@@ -78,6 +78,23 @@ def test_dense_compaction_of_four_columns(one_chip):
     page = Page(cols, jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip))
     assert E._compact_path(rows // 4, page) == "sort"
     assert _compile_seconds(E._jit_compact, rows // 4, page) < BUDGET_S
+
+
+def test_q14s_sparse_compaction_at_sf3(one_chip):
+    """One row in 72 of `lineitem`'s stored page kept (PR 35): the `index` path,
+    the four columns' eight words as one matrix beside the page."""
+    rows, kept = 18_874_368, 262_144
+    cols = (
+        _column(BIGINT, rows, one_chip, jnp.int64), _column(decimal_type(12, 2), rows, one_chip, jnp.int64),
+        _column(decimal_type(12, 2), rows, one_chip, jnp.int64), _column(DATE, rows, one_chip, jnp.int32),
+    )
+    page = Page(cols, jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip))
+    assert E._compact_path(kept, page) == "index"
+    start = time.perf_counter()
+    compiled = E._jit_compact.lower(kept, page).compile()
+    assert time.perf_counter() - start < BUDGET_S
+    # the matrix (604 MB), the words cut from 64-bit columns and what `live_indices` holds
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 8 * 4 * rows
 
 
 def test_join_match_of_one_bigint_key(one_chip):

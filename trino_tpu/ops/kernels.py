@@ -252,29 +252,98 @@ def sort_perm(fields: Sequence[Tuple[jnp.ndarray, int]]) -> jnp.ndarray:
     return perm
 
 
-# gather_rows packs when it moves at least this share of the rows; below it
-# (a TopN's ten rows, an aggregation's group starts) packing would stream the
-# whole page to move a few rows of it
-_PACK_SHARE = 8
+# What the two forms of `gather_rows` cost on a v5e, read by
+# tools/gather_probe.py (chiprun_out/pr35_probe/probe.json, PR 35): q14's
+# eight arrays (three 64-bit, one 32-bit, four masks: 11 gathers of their own,
+# or 8 words of one matrix) moved by m ascending indices out of n rows, ms:
+#
+#   n = 18,874,368   m = 1,024  16,384  65,536  262,144  1,048,576
+#   plain                 4.08    6.03   12.87    42.62     202.64
+#   packed                7.99    8.13    8.75    11.50      22.39
+#   n = 1,048,576    plain 1.31   2.84    7.39    29.52     155.01
+#                    packed 1.42  1.49    1.59     2.37       5.26
+#
+# A gather of one 32-bit array costs 12 to 18 ns a slot wherever the slots
+# lie (a 64-bit array is two such gathers, a mask one): 11 gathers of 2.3 to
+# 4.1 ms at q14's shape, ascending or shuffled. Packed, a slot of up to eight
+# words is one (8, 128) tile fetched, 13.5 ns (the gather: 3.3 ms), after a
+# pass that writes the matrix (`concatenate` 2.5 ms: XLA does not fuse it into
+# the gather) and the upper words: 3.9 ms over what plain pays to read 64-bit
+# arrays at all, 0.026 ns a word of a row. The forms cross near one row in
+# 600 moved (n = 16,777,216: plain 5.82 and packed 7.32 ms at one in 1,024,
+# 12.08 and 8.10 at one in 256), not at one in 8. Held to one, two, three
+# and sixteen arrays (pr35_probe2/widths.json): right wherever the forms
+# differ by more than a factor of two, a millisecond lost at three shapes
+# nearer the crossover. A third form (each array as [n/128, 128], the row
+# gathered and the lane selected: no pass) reads 24.9 ms at q14's shape and
+# within a millisecond of the cheaper of these two everywhere below it: not
+# kept.
+_PLAIN_SLOT_NS = 12.7    # a slot of one 32-bit array, gathered by itself
+_PACKED_SLOT_NS = 13.5   # a slot of the [words, n] matrix, for each eight words
+_PACK_WORD_NS = 0.026    # a word of a row, written into the matrix
+
+
+def gather_form(n: int, m: int, gathers: int, words: int) -> str:
+    """How ``gather_rows`` moves ``m`` rows of ``n``: ``packed`` or ``plain``,
+    whichever costs less on a v5e by the constants above. ``gathers`` is the
+    32-bit gathers the plain form makes and ``words`` the 32-bit words the
+    packed form stacks (``gather_shape``). Static shapes only: the choice is
+    made while the program is traced, and ``executor._compact`` states it
+    on its span by the same call."""
+    plain = gathers * m * _PLAIN_SLOT_NS
+    packed = words * n * _PACK_WORD_NS + -(-words // 8) * m * _PACKED_SLOT_NS
+    return "packed" if packed < plain else "plain"
+
+
+def _packable(a) -> bool:
+    return a.ndim == 1 and a.dtype.itemsize <= 8
+
+
+def gather_shape(arrays: Sequence) -> Tuple[int, int]:
+    """(gathers, words) of what ``gather_rows`` may pack of ``arrays`` (arrays
+    or their shapes): a 64-bit array is two gathers and two words, up to 32
+    masks share a word. Arrays of more dimensions count for neither."""
+    flat = [a for a in arrays if _packable(a)]
+    flags = sum(a.dtype == jnp.bool_ for a in flat)
+    gathers = len(flat) + sum(a.dtype.itemsize == 8 for a in flat)
+    return gathers, gathers - flags + -(-flags // 32)
 
 
 def gather_rows(arrays: Sequence[jnp.ndarray], idx: jnp.ndarray) -> List[jnp.ndarray]:
-    """``[a[idx] for a in arrays]``. Arrays of one dimension travel together:
-    they are cut into 32-bit words (a 64-bit value is two, up to 32 booleans
-    share one), stacked as one [words, n] matrix and gathered in one gather,
-    which on a v5e costs 1.8 ns a word where a gather per array costs 8.6.
-    Arrays of more dimensions (Int128 limbs, vectors, array lanes) are rows
-    already and are gathered as they are."""
+    """``[a[idx] for a in arrays]``, in the form ``gather_form`` finds cheaper
+    for these shapes. Packed, arrays of one dimension travel together: they
+    are cut into 32-bit words (a 64-bit value is two, up to 32 booleans share
+    one), stacked as one [words, n] matrix and gathered in one gather. Arrays
+    of more dimensions (Int128 limbs, vectors, array lanes) are rows already
+    and are gathered as they are."""
     arrays = list(arrays)
     n = arrays[0].shape[0] if arrays else 0
-    flat = [i for i, a in enumerate(arrays) if a.ndim == 1 and a.dtype.itemsize <= 8]
-    if len(flat) < 2 or idx.shape[0] * _PACK_SHARE < n:
+    if gather_form(n, idx.shape[0], *gather_shape(arrays)) == "plain":
         return [a[idx] for a in arrays]
+    return _gather_packed(arrays, idx)
+
+
+def _gather_packed(arrays: List[jnp.ndarray], idx: jnp.ndarray) -> List[jnp.ndarray]:
+    """``gather_rows``' packed form: the arrays that can be packed as 32-bit
+    words of one [words, n] matrix, one gather, and the words put back
+    together; the others gathered as they are."""
+    words, plan = _pack_words(arrays)
+    moved = jnp.stack(words)[:, idx]
+    return [_unpack_words(moved, plan[i], a) if i in plan else a[idx] for i, a in enumerate(arrays)]
+
+
+def _pack_words(arrays: List[jnp.ndarray]):
+    """The arrays of one dimension cut into int32 words (a 64-bit value is
+    two, up to 32 booleans share one, an 8- or 16-bit value is widened), and
+    where each such array's words are: {index: (first word, kind)}, kind 2, 1
+    or 0 by the width and -1 - bit for a flag."""
+    n = arrays[0].shape[0]
     words: List[jnp.ndarray] = []
     plan = {}
     flags: List[int] = []
-    for i in flat:
-        a = arrays[i]
+    for i, a in enumerate(arrays):
+        if not _packable(a):
+            continue
         if a.dtype == jnp.bool_:
             flags.append(i)
         elif a.dtype.itemsize == 8:
@@ -294,24 +363,21 @@ def gather_rows(arrays: Sequence[jnp.ndarray], idx: jnp.ndarray) -> List[jnp.nda
             word = word | (arrays[i].astype(jnp.uint32) << jnp.uint32(bit))
             plan[i] = (len(words), -1 - bit)
         words.append(jax.lax.bitcast_convert_type(word, jnp.int32))
-    moved = jnp.stack(words)[:, idx]
-    out = [None] * len(arrays)
-    for i, a in enumerate(arrays):
-        if i not in plan:
-            out[i] = a[idx]
-            continue
-        at, kind = plan[i]
-        if kind < 0:
-            out[i] = ((moved[at] >> jnp.int32(-1 - kind)) & 1).astype(jnp.bool_)
-        elif kind == 2:
-            lo = jax.lax.bitcast_convert_type(moved[at], jnp.uint32).astype(jnp.uint64)
-            hi = jax.lax.bitcast_convert_type(moved[at + 1], jnp.uint32).astype(jnp.uint64)
-            out[i] = ((hi << jnp.uint64(32)) | lo).view(a.dtype)
-        elif kind == 1:
-            out[i] = moved[at] if a.dtype == jnp.int32 else jax.lax.bitcast_convert_type(moved[at], a.dtype)
-        else:
-            out[i] = moved[at].astype(a.dtype)
-    return out
+    return words, plan
+
+
+def _unpack_words(moved: jnp.ndarray, where: Tuple[int, int], like: jnp.ndarray) -> jnp.ndarray:
+    """The array ``like`` was, from the gathered [words, m] matrix."""
+    at, kind = where
+    if kind < 0:
+        return ((moved[at] >> jnp.int32(-1 - kind)) & 1).astype(jnp.bool_)
+    if kind == 2:
+        lo = jax.lax.bitcast_convert_type(moved[at], jnp.uint32).astype(jnp.uint64)
+        hi = jax.lax.bitcast_convert_type(moved[at + 1], jnp.uint32).astype(jnp.uint64)
+        return ((hi << jnp.uint64(32)) | lo).view(like.dtype)
+    if kind == 1:
+        return moved[at] if like.dtype == jnp.int32 else jax.lax.bitcast_convert_type(moved[at], like.dtype)
+    return moved[at].astype(like.dtype)
 
 
 def cummax(x: jnp.ndarray) -> jnp.ndarray:

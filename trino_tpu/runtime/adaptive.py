@@ -92,8 +92,8 @@ def _mask_top_valid(c: Column, keep: jnp.ndarray) -> Column:
 def trace_compact(new_cap: int, page: Page) -> Tuple[Page, jnp.ndarray, jnp.ndarray]:
     """Stable in-trace compaction: active rows move to the front of a
     ``new_cap``-row page. ``K.live_indices`` + one gather of ``new_cap`` rows
-    per column: the device program of ``executor._jit_compact``'s index path,
-    with the count left on the device.
+    per column, with the count left on the device. (``executor._jit_compact``
+    finds its rows the same way and moves its columns by ``K.gather_rows``.)
 
     Returns (page, overflow, true_count); rows past ``new_cap`` are dropped
     and counted in ``overflow`` (the caller retries with a larger capacity).

@@ -99,13 +99,20 @@ def _permute_column(c: Column, perm) -> Column:
     )
 
 
+def _flat_arrays(cols: Sequence[Column], extra=()):
+    """Which of ``cols`` are flat (no children, no lanes, no lengths) and the
+    arrays ``_permute_columns`` hands ``K.gather_rows`` for them: data and
+    validity of each flat column, then ``extra``."""
+    flat = [not c.children and c.data.ndim == 1 and c.lengths is None for c in cols]
+    return flat, [a for c, f in zip(cols, flat) if f for a in (c.data, c.valid)] + list(extra)
+
+
 def _permute_columns(cols: Sequence[Column], perm, extra=()):
     """``[_permute_column(c, perm) for c in cols]`` and ``[a[perm] for a in
     extra]``, with every flat array (data, validity, the extra masks) moved by
     one ``K.gather_rows``; nested and multi-lane parts go as
     ``_permute_column`` moves them. Returns (columns, moved extra)."""
-    flat = [not c.children and c.data.ndim == 1 and c.lengths is None for c in cols]
-    arrays = [a for c, f in zip(cols, flat) if f for a in (c.data, c.valid)] + list(extra)
+    flat, arrays = _flat_arrays(cols, extra)
     moved = K.gather_rows(arrays, perm)
     out, at = [], 0
     for c, f in zip(cols, flat):
@@ -2095,21 +2102,34 @@ COMPACTIONS_HELP = (
     "pages made dense, by how the rows kept are found: index (a walk over the "
     "mask) or sort (one sort of the positions); the columns are gathered"
 )
+COMPACTION_GATHERS_COUNTER = "trino_tpu_compaction_gathers_total"
+COMPACTION_GATHERS_HELP = (
+    "pages made dense, by how their flat columns follow the rows kept: packed "
+    "(32-bit words of one matrix, one gather) or plain (a gather an array)"
+)
 
 
 def _compact(page: Page, live_rows: int) -> Page:
     """The page's ``live_rows`` active rows, in row order, at the front of a
     page of the next capacity class. One `compact` span under the operator
-    that asked, and one tick of ``trino_tpu_compactions_total{path}``."""
+    that asked, one tick of ``trino_tpu_compactions_total{path}`` and one of
+    ``trino_tpu_compaction_gathers_total{form}``."""
     new_cap = min(_round_capacity(max(live_rows, 1)), page.capacity)
     path = _compact_path(new_cap, page)
+    # what `_jit_compact`'s one `K.gather_rows` is traced to, by the same call
+    gathers, words = K.gather_shape(_flat_arrays(page.columns)[1])
+    form = K.gather_form(page.capacity, new_cap, gathers, words)
     # what was scanned (capacity_in rows of `columns`) against what is kept
     with TRACER.span(
         "compact", capacity_in=page.capacity, live_rows=live_rows,
         capacity_out=new_cap, columns=len(page.columns), path=path,
+        gather=form, words=words,
     ):
         REGISTRY.counter(
             COMPACTIONS_COUNTER, {"path": path}, help=COMPACTIONS_HELP
+        ).inc()
+        REGISTRY.counter(
+            COMPACTION_GATHERS_COUNTER, {"form": form}, help=COMPACTION_GATHERS_HELP
         ).inc()
         return _jit_compact(new_cap, page)
 
