@@ -195,7 +195,7 @@ class TestMegakernelEdgeCases:
                 ba,
             )
             pkeys, bkeys, luts = ((pk, pv),), ((bk, bv),), (None,)
-            emit, count, lo, perm_b = E._jit_join_match(
+            emit, count, lo, perm_b, _ = E._jit_join_match(
                 False, pkeys, bkeys, luts, pa, ba
             )
             cap = E._round_capacity(max(int(jnp.sum(emit)), 1))

@@ -50,29 +50,51 @@ class _StoredTable:
     # in the same one read: a key's range bounds its distinct values, which
     # is what join ordering needs to tell a key from a nation code
     spans: Dict[str, Tuple[int, int]] = field(default_factory=dict)
+    # {column: strings in its dictionary} of the dictionary-coded columns, the
+    # largest over the pages written: such a column holds no more distinct
+    # values than its dictionary has strings (a brand is one of 25, a
+    # container one of 40), which is what tells a filter on them from a
+    # guess. On the host already: a page carries its dictionaries
+    codes: Dict[str, int] = field(default_factory=dict)
+    # the integer columns whose values do not fall from one stored row to the
+    # next (``lineitem`` by ``l_orderkey``, as its generator wrote it): seen
+    # in the same one read, page by page (a page's rows a prefix of it, none
+    # null, none under the one before; its least value not under the most of
+    # the pages before it), never declared, gone with the first page that
+    # breaks it. A scan states the first of them as ``sorted_by``, and a
+    # grouping on it takes ``_jit_presorted_group``, which checks again
+    ordered: Dict[str, bool] = field(default_factory=dict)
 
     def row_count(self) -> int:
         return self.rows
 
-    def note(self, facts: "List[int]", reset: bool = False) -> int:
-        """Fold what ``_page_facts`` read of the pages written into ``rows``
-        and ``spans``; returns the rows they hold."""
+    def note(self, pages: "Sequence[Page]", reset: bool = False) -> int:
+        """Fold the pages written into ``rows`` and ``spans`` (``_page_facts``:
+        one device read for all of them) and ``codes``; returns the rows they
+        hold."""
         if reset:
-            self.rows, self.spans = 0, {}
+            self.rows, self.spans, self.codes, self.ordered = 0, {}, {}, {}
+        for page in pages:
+            for meta, col in zip(self.columns, page.columns):
+                if col.dictionary is not None:
+                    self.codes[meta.name] = max(self.codes.get(meta.name, 0), len(col.dictionary))
+        facts = _page_facts(self.columns, pages)
         names = [c.name for c in self.columns if _ranged(c)]
-        width = 1 + 2 * len(names)
+        width = 1 + 3 * len(names)
         added = 0
         for at in range(0, len(facts), width):
             rows = int(facts[at])
-            added += rows
             if not rows:
                 continue
             for j, name in enumerate(names):
-                low, high = int(facts[at + 1 + 2 * j]), int(facts[at + 2 + 2 * j])
+                low, high, rising = (int(v) for v in facts[at + 1 + 3 * j:at + 4 + 3 * j])
+                had = self.spans.get(name)
+                first = self.rows + added == 0
+                self.ordered[name] = bool(rising) and (first or (self.ordered.get(name, False) and low >= had[1]))
                 if low > high:
                     continue  # every value null
-                had = self.spans.get(name)
                 self.spans[name] = (low, high) if had is None else (min(had[0], low), max(had[1], high))
+            added += rows
         self.rows += added
         return added
 
@@ -82,21 +104,28 @@ def _ranged(column: ColumnMetadata) -> bool:
 
 
 def _page_facts(columns: Sequence[ColumnMetadata], pages: Sequence[Page]) -> "List[int]":
-    """For each page: its live rows, then (least, most) of every ranged
-    column over its live, non-null values; all pages in ONE device read."""
+    """For each page: its live rows, then (least, most, rising) of every
+    ranged column over its live, non-null values, ``rising`` 1 where the live
+    rows are a prefix of the page, none of them null, and none under the row
+    before it; all pages in ONE device read."""
     scalars = []
     for page in pages:
-        scalars.append(page.num_rows().astype(jnp.int64))
+        rows = page.num_rows().astype(jnp.int64)
+        scalars.append(rows)
+        prefix = jnp.all(page.active == (jnp.arange(page.capacity) < rows))
         for meta, col in zip(columns, page.columns):
             if not _ranged(meta):
                 continue
             if col.data.ndim != 1 or col.dictionary is not None:
-                scalars += [jnp.int64(1), jnp.int64(0)]  # no range: least > most
+                scalars += [jnp.int64(1), jnp.int64(0), jnp.int64(0)]  # no range: least > most
                 continue
             live = page.active & col.valid
             info = jnp.iinfo(col.data.dtype)
             scalars.append(jnp.min(jnp.where(live, col.data, info.max)).astype(jnp.int64))
             scalars.append(jnp.max(jnp.where(live, col.data, info.min)).astype(jnp.int64))
+            falls = page.active[1:] & (col.data[1:] < col.data[:-1])
+            rising = prefix & ~jnp.any(page.active & ~col.valid) & ~jnp.any(falls)
+            scalars.append(rising.astype(jnp.int64))
     return np.asarray(jnp.stack(scalars)).tolist() if scalars else []
 
 
@@ -195,7 +224,7 @@ class MemoryConnector(Connector):
                 )
             self._bump(name)
             # rows and the integer columns' ranges, counted on the device: one read
-            rows = table.note(_page_facts(table.columns, [page]))
+            rows = table.note([page])
             if not table.bucketed_by:
                 table.pages.append(page)
                 return rows
@@ -251,7 +280,7 @@ class MemoryConnector(Connector):
                 table.pages = list(pages)
                 # one read for all the pages
                 live = [p for p in table.pages if p is not None]
-                table.note(_page_facts(table.columns, live), reset=True)
+                table.note(live, reset=True)
                 return
             table.pages = []
             table.note([], reset=True)  # each insert below adds what it writes
@@ -277,7 +306,10 @@ class _MemoryMetadata(ConnectorMetadata):
         t = self.connector.table(name)
         if t is None:
             return None
-        return TableMetadata(name, t.columns)
+        # the first column seen to rise from row to row, as the generator
+        # connectors state theirs
+        rising = tuple(c.name for c in t.columns if t.ordered.get(c.name))[:1]
+        return TableMetadata(name, t.columns, sorted_by=() if t.bucketed_by else rising)
 
     def table_partitioning(self, handle: TableHandle):
         from ..spi.connector import TablePartitioning
@@ -294,11 +326,14 @@ class _MemoryMetadata(ConnectorMetadata):
         if t is None:
             return TableStatistics(row_count=0.0)
         rows = float(t.row_count())
-        # an integer column holds no more distinct values than its range has
+        # an integer column holds no more distinct values than its range has,
+        # a dictionary-coded one no more than its dictionary has strings
         columns = {
             name: ColumnStatistics(ndv=min(rows, float(high - low + 1)))
             for name, (low, high) in t.spans.items()
         }
+        for name, strings in t.codes.items():
+            columns.setdefault(name, ColumnStatistics(ndv=min(rows, float(strings))))
         return TableStatistics(row_count=rows, columns=columns)
 
 

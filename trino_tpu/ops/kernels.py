@@ -398,6 +398,33 @@ def cummax(x: jnp.ndarray) -> jnp.ndarray:
     return out[:n] if pad else out
 
 
+def segment_running(values: jnp.ndarray, new_segment: jnp.ndarray, kind: str) -> jnp.ndarray:
+    """1-D inclusive running minimum or maximum (``kind``) that starts anew at
+    every row ``new_segment`` flags: row i holds the extreme of its segment's
+    rows up to i, so a segment's last row holds the segment's.
+
+    No scatter and no scan of fixed depth: every row knows how far it is from
+    its segment's first row (one ``cummax`` of the flagged positions), and a
+    loop doubles a shift s = 1, 2, 4, ...: a row at least s rows into its
+    segment takes in the row s before it, which by then covers the s rows up
+    to itself. The loop ends once s passes the longest segment, so it makes
+    log2 of the longest segment passes over the array (three where a group
+    is an order's one to seven lines) and each pass is one shifted read and
+    an elementwise op. A scatter-min of 18.9M rows into 4.5M groups took 2.07 s
+    on a v5e (PR 36: TPC-H Q21's min and max of l_suppkey by l_orderkey)."""
+    op = jnp.minimum if kind == "min" else jnp.maximum
+    n = values.shape[0]
+    at = jnp.arange(n, dtype=jnp.int32)
+    into = at - cummax(jnp.where(new_segment, at, 0))  # rows since the segment's first
+    longest = jnp.max(into)
+
+    def widen(state):
+        shift, running = state
+        return shift * 2, jnp.where(into >= shift, op(running, jnp.roll(running, shift)), running)
+
+    return jax.lax.while_loop(lambda state: state[0] <= longest, widen, (jnp.int32(1), values))[1]
+
+
 def lexsort_perm(keys: Sequence[jnp.ndarray], active: jnp.ndarray) -> jnp.ndarray:
     """Permutation sorting by keys (first = most significant); inactive rows
     last; stable. ``sort_perm`` over the keys' order fields."""
@@ -419,20 +446,36 @@ def cosort(pass_keys: Sequence[jnp.ndarray], payloads: Sequence[jnp.ndarray]):
 
 def last_active_prev(vals: jnp.ndarray, active: jnp.ndarray):
     """For each row i, the value at the most recent ACTIVE row strictly before
-    i (and whether one exists). One associative scan — lets presorted grouping
-    skip sorts even when inactive (filtered) rows are interleaved."""
+    i (and whether one exists): lets presorted grouping skip sorts even when
+    inactive (filtered) rows are interleaved. Read only at active rows.
 
-    def combine(a, b):
-        av, ah = a
-        bv, bh = b
-        return jnp.where(bh, bv, av), ah | bh
+    Every row knows how far back its last active row lies (one blocked
+    ``cummax`` of the active positions), and a loop brings the value over by
+    the bits of that distance, least first: at shift s a row whose distance
+    has bit s set takes the row s before it, which by then holds the value the
+    lower bits reach from there. The loop runs to the longest distance an
+    active row's predecessor needs, so a dense page makes no pass and a page
+    with runs of up to seven filtered rows three; a flat
+    ``lax.associative_scan`` over 18.9M rows did not compile for a v5e
+    inside 900 s (PR 36)."""
+    n = vals.shape[0]
+    at = jnp.arange(n, dtype=jnp.int32)
+    last = cummax(jnp.where(active, at, -1))  # the last active row at or before i, -1 if none
+    back = at - last
+    # the rows read are the predecessors of active rows
+    read = jnp.concatenate([active[1:], jnp.zeros((1,), active.dtype)]) & (last >= 0)
+    longest = jnp.max(jnp.where(read, back, 0))
 
-    inc = jax.lax.associative_scan(
-        combine, (jnp.where(active, vals, 0), active)
-    )
-    # exclusive: shift the inclusive scan right by one
-    prev_vals = jnp.roll(inc[0], 1).at[0].set(0)
-    prev_has = jnp.roll(inc[1], 1).at[0].set(False)
+    def reach(state):
+        shift, held = state
+        return shift * 2, jnp.where((back & shift) != 0, jnp.roll(held, shift), held)
+
+    held = jax.lax.while_loop(
+        lambda state: state[0] <= longest, reach, (jnp.int32(1), jnp.where(active, vals, 0))
+    )[1]
+    # exclusive: shift right by one
+    prev_vals = jnp.roll(held, 1).at[0].set(0)
+    prev_has = jnp.roll(last >= 0, 1).at[0].set(False)
     return prev_vals, prev_has
 
 
@@ -544,6 +587,64 @@ _BIT_OPS = {
 }
 
 
+def reads_at_ends(values: jnp.ndarray, kind: str) -> bool:
+    """Whether ``segment_reduce_at_ends`` computes this reduction: counts,
+    exact (integer) sums, and the extremes of a 1-D column. A floating sum
+    keeps ``segment_reduce``'s own form (it adds the segment's first value
+    back and reads two places)."""
+    if values.ndim != 1:
+        return False
+    return kind in ("count", "min", "max") or (
+        kind == "sum" and jnp.issubdtype(values.dtype, jnp.integer)
+    )
+
+
+def segment_scan(values: jnp.ndarray, weight: jnp.ndarray, kind: str, new_segment: jnp.ndarray):
+    """The per-row array whose reads at the segments' last rows give the
+    reduction ``kind`` of each segment of group-sorted rows: for ``min`` and
+    ``max`` the running extreme that starts anew at every segment
+    (``segment_running``), for ``sum`` and ``count`` the running sum over the
+    whole page (a segment's sum is the difference of two neighbouring reads).
+    Rows outside ``weight`` take no part."""
+    if kind == "count":
+        # a count never passes the page's rows: one 32-bit word to read, not two
+        n = weight.shape[0]
+        return cumsum(weight.astype(jnp.int32 if n < 2**31 else jnp.int64))
+    if kind == "sum":
+        return cumsum(jnp.where(weight, values, jnp.zeros_like(values)))
+    vals = jnp.where(weight, values, _reduce_identity(values.dtype, kind))
+    return segment_running(vals, new_segment, kind)
+
+
+def segment_reduce_at_ends(requests, new_segment: jnp.ndarray, ends: jnp.ndarray):
+    """The reductions ``[(values, weight, kind), ...]`` (``reads_at_ends``
+    holds for each) over group-sorted rows whose segments end at rows
+    ``ends``, one slot a segment: every request's ``segment_scan`` read at
+    ``ends`` in ONE ``gather_rows``. On a v5e a slot of one 32-bit array
+    gathered by itself costs what a slot of eight words gathered together does
+    (PR 35), and TPC-H Q21's min, max and count of l_suppkey over 4.5M orders
+    were seven such gathers of 5.2M slots, 0.5 of the aggregation's 0.57 s
+    (PR 36).
+
+    A segment without a participant reads the identity (0 for a sum or a
+    count), and a slot past the segments what the last segment's end holds
+    (``ends`` is padded with the page's last row): callers mask both by the
+    participant count."""
+    n = new_segment.shape[0]
+    at = jnp.clip(ends, 0, n - 1)
+    moved = gather_rows([segment_scan(v, w, kind, new_segment) for v, w, kind in requests], at)
+    out = []
+    for (values, _, kind), read in zip(requests, moved):
+        if kind in ("sum", "count"):
+            # rows of no weight lie before the first segment and between
+            # segments, so the running sum at the segment before's end is the
+            # one before this segment's first row
+            read = read - jnp.concatenate([jnp.zeros((1,), read.dtype), read[:-1]])
+            read = read.astype(jnp.int64 if kind == "count" else values.dtype)
+        out.append(read)
+    return out
+
+
 def segment_reduce(
     values_sorted: jnp.ndarray,
     weight_sorted: jnp.ndarray,  # bool: row participates
@@ -558,7 +659,9 @@ def segment_reduce(
     For sum/count with segment boundaries available (``new_group_sorted``), uses
     the cumsum-at-boundaries formulation instead of scatter-add: rows are sorted
     by group, so segment g's sum is csum[end_g] - csum[start_g] + v[start_g].
-    TPU scatters serialize; cumsum + two small gathers vectorize fully.
+    TPU scatters serialize; cumsum + two small gathers vectorize fully. With
+    ``bounds`` too, counts, exact sums and min/max are
+    ``segment_reduce_at_ends`` of the one request: one gather.
     """
     if capacity == 1:
         # global aggregation: plain masked reduction
@@ -578,6 +681,12 @@ def segment_reduce(
             )
             return jax.lax.reduce(vals, jnp.int64(ident), op, (0,))[None]
         raise ValueError(kind)
+    if new_group_sorted is not None and bounds is not None and reads_at_ends(values_sorted, kind):
+        # rows are group-sorted and the segments' bounds known: exact sums,
+        # counts and extremes are read at the segments' last rows
+        return segment_reduce_at_ends(
+            [(values_sorted, weight_sorted, kind)], new_group_sorted, bounds[1]
+        )[0]
     if kind in ("sum", "count") and new_group_sorted is not None:
         vals = (
             weight_sorted.astype(jnp.int64)
@@ -586,16 +695,6 @@ def segment_reduce(
         )
         csum = cumsum(vals)
         n = values_sorted.shape[0]
-        if bounds is not None and jnp.issubdtype(vals.dtype, jnp.integer):
-            # exact arithmetic: the sum of a segment is the running sum before
-            # the next segment's start less the one before its own, ONE gather
-            # of capacity + 1 elements where the form below makes three (a
-            # gather of 8.4M int64 takes 143 ms on a v5e: Q18's 4.5M groups).
-            # bounds[0] is padded with n, where the running sum is the total
-            before = jnp.concatenate([jnp.zeros((1,), csum.dtype), csum])
-            starts = jnp.concatenate([bounds[0], jnp.full((1,), n, bounds[0].dtype)])
-            at_start = before[jnp.clip(starts, 0, n)]
-            return at_start[1:] - at_start[:-1]
         if bounds is not None:
             start, end = bounds
         else:

@@ -1226,6 +1226,22 @@ class LogicalPlanner:
         self.session = session
         self.symbols = SymbolAllocator()
         self._cte: Dict[str, t.Query] = {}
+        # sub-queries this planner rewrote to joins (the `planner` span's ``decorrelated``)
+        self.decorrelated = 0
+
+    def _note_decorrelated(self, kind: str) -> None:
+        """One sub-query planned as a join: a correlated scalar aggregate as a
+        join with its grouping ("scalar"), [NOT] EXISTS as a semi-join or a
+        LEFT join with per-key aggregates ("exists"), [NOT] IN as a semi-join
+        ("in")."""
+        from ..runtime.metrics import REGISTRY  # the runtime package imports the planner
+
+        self.decorrelated += 1
+        REGISTRY.counter(
+            "trino_tpu_decorrelated_subqueries_total", {"kind": kind},
+            help="sub-queries the logical planner rewrote to joins, by kind: "
+                 "scalar (a correlated aggregate), exists, in",
+        ).inc()
 
     # ------------------------------------------------------------- entry
 
@@ -2509,6 +2525,7 @@ class LogicalPlanner:
             source_key = self.symbols.new_symbol("in_key", source_expr.type)
             node = append_projection(node, ((source_key, source_expr),), self.symbols.types)
         match_sym = self.symbols.new_symbol("in_match", BOOLEAN)
+        self._note_decorrelated("in")
         semi = SemiJoinNode(
             source=node,
             filtering_source=sub.node,
@@ -2516,6 +2533,7 @@ class LogicalPlanner:
             filtering_key=filtering.symbol,
             output=match_sym,
             null_aware=True,
+            negated=negated,
         )
         pred: IrExpr = Reference(match_sym, BOOLEAN)
         if negated:
@@ -2623,6 +2641,7 @@ class LogicalPlanner:
         """Decorrelate expr <op> (correlated scalar agg): join against the
         subquery grouped by its correlation keys (ref: Q17/Q2/Q20 shapes)."""
         spec, pairs, residual, item, count_family = pattern
+        self._note_decorrelated("scalar")
         inner_keys = tuple(p[1] for p in pairs)
         grouped_spec = t.QuerySpecification(
             select_items=tuple(
@@ -2760,12 +2779,14 @@ class LogicalPlanner:
             outer_sym = self.symbols.new_symbol("exists_key", ir.type)
             node = append_projection(node, ((outer_sym, ir),), self.symbols.types)
         match_sym = self.symbols.new_symbol("exists_match", BOOLEAN)
+        self._note_decorrelated("exists")
         semi = SemiJoinNode(
             source=node,
             filtering_source=sub.node,
             source_key=outer_sym,
             filtering_key=sub.fields[0].symbol,
             output=match_sym,
+            negated=negated,
         )
         pred: IrExpr = Reference(match_sym, BOOLEAN)
         if negated:
@@ -2796,6 +2817,7 @@ class LogicalPlanner:
         family rules; the min/max split replaces the mark-join.)
         """
         qn = lambda n: t.QualifiedName((n,))  # noqa: E731
+        self._note_decorrelated("exists")
         inner_keys = [p[1] for p in pairs]
         select_items = [
             t.SelectItem(expression=k, alias=f"corr_key_{i}")

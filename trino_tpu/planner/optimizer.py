@@ -89,6 +89,8 @@ def optimizer_passes(metadata: Metadata, types: Dict[str, Type], session: Sessio
          rules.push_filter_through_aggregation),
         ("push_filter_through_union", rules.push_filter_through_union),
         ("push_filter_through_unnest", rules.push_filter_through_unnest),
+        ("reduce_aggregation_by_join_keys",
+         lambda r: rules.reduce_aggregation_by_join_keys(r, types, estimator())),
         ("push_semijoin_through_join", rules.push_semijoin_through_join),
         ("pushdown_predicates#3", lambda r: pushdown_predicates(r, types)),
         ("merge_adjacent_windows", rules.merge_adjacent_windows),

@@ -244,7 +244,11 @@ class SemiJoinNode(PlanNode):
     ``null_aware``: SQL IN three-valued semantics — the match column is NULL
     (not FALSE) when the probe key is NULL, or when it is unmatched and the
     filtering side contains a NULL (SemiJoinNode's output is nullable in the
-    reference for exactly this). EXISTS-derived semi joins are two-valued."""
+    reference for exactly this). EXISTS-derived semi joins are two-valued.
+
+    ``negated``: the planner made the node for NOT IN / NOT EXISTS, so the
+    mark is only read under NOT (an anti-join); the node computes the same
+    mark either way, the operator's span says which it was."""
 
     source: PlanNode = None
     filtering_source: PlanNode = None
@@ -252,6 +256,7 @@ class SemiJoinNode(PlanNode):
     filtering_key: str = ""
     output: str = ""  # boolean symbol appended to source outputs
     null_aware: bool = False
+    negated: bool = False
 
     @property
     def sources(self):

@@ -82,12 +82,19 @@ class StatsEstimator:
         self.metadata = metadata
         self.types = types
         self._memo: Dict[int, PlanStats] = {}
+        # the memo is keyed by id(): every node it knows stays referenced, or a
+        # node a later pass drops could hand its id, and its estimate, to a new
+        # one (an estimator lives through several passes that rebuild nodes)
+        self._held: List[PlanNode] = []
+
+    def _remember(self, node: PlanNode, stats: PlanStats) -> PlanStats:
+        self._held.append(node)
+        self._memo[id(node)] = stats
+        return stats
 
     def stats(self, node: PlanNode) -> PlanStats:
-        key = id(node)
-        if key not in self._memo:
-            self._memo[key] = self._estimate(node)
-        return self._memo[key]
+        known = self._memo.get(id(node))
+        return known if known is not None else self._remember(node, self._estimate(node))
 
     def rows(self, node: PlanNode) -> Optional[float]:
         return self.stats(node).rows
@@ -95,7 +102,7 @@ class StatsEstimator:
     def assume(self, node: PlanNode, stats: PlanStats) -> None:
         """Take ``stats`` as ``node``'s estimate: a node this plan cannot see
         behind (a remote source stands for its producer fragment's root)."""
-        self._memo[id(node)] = stats
+        self._remember(node, stats)
 
     # ------------------------------------------------------------------ nodes
 
@@ -312,10 +319,10 @@ class HistoryBasedStatsEstimator(StatsEstimator):
         self.history = history
 
     def stats(self, node: PlanNode) -> PlanStats:
-        key = id(node)
-        if key not in self._memo:
-            self._memo[key] = self._overlay(node, self._estimate(node))
-        return self._memo[key]
+        known = self._memo.get(id(node))
+        if known is not None:
+            return known
+        return self._remember(node, self._overlay(node, self._estimate(node)))
 
     def _lookup(self, *keys: Optional[str]) -> Optional[dict]:
         for k in keys:

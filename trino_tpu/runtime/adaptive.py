@@ -269,7 +269,7 @@ class _AdaptiveTracedExecutor(_TracedExecutor):
         finally:
             self._join_key = prev
 
-    def _choose_join_capacity(self, emit, probe_cap: int, build_cap: int) -> int:
+    def _choose_join_capacity(self, emit, probe_cap: int, build_cap: int, totals=None) -> int:
         key = self._join_key
         hint = self.capacities.get(key) if key is not None else None
         unhinted = _round_capacity(max(int(probe_cap * self.join_capacity_factor), 1))

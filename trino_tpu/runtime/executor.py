@@ -326,6 +326,15 @@ def _sync_int(x, site: str) -> int:
     return value
 
 
+def _sync_ints(x, site: str) -> List[int]:
+    """``_sync_int`` for a short vector of integers: still ONE device-to-host
+    read and one `sync:<site>` span, whose ``value`` is the first of them."""
+    with TRACER.span(SYNC_PREFIX + site) as span:
+        values = [int(v) for v in np.asarray(x)]
+        span.attributes["value"] = values[0]
+    return values
+
+
 def _live_rows(active, site: str) -> int:
     """Rows a page holds, read back to the host (a sync: `_sync_int`)."""
     return _sync_int(jnp.sum(active.astype(jnp.int32)), site)
@@ -335,6 +344,11 @@ JOIN_ROWS_COUNTER = "trino_tpu_join_rows_total"
 JOIN_ROWS_HELP = (
     "rows a join read and wrote, by side: probe, build (live rows where the "
     "executor had counted them, else the page's capacity), out (matches emitted)"
+)
+JOINS_COUNTER = "trino_tpu_joins_total"
+JOINS_HELP = (
+    "joins executed, by kind as executed: INNER, LEFT (a RIGHT join runs as a LEFT "
+    "one with its sides swapped), FULL, CROSS"
 )
 GROUP_ROWS_COUNTER = "trino_tpu_group_rows_total"
 GROUP_ROWS_HELP = (
@@ -364,6 +378,21 @@ def _count_join_rows(**sides: int) -> None:
     """``trino_tpu_join_rows_total{side}`` += rows, for each side given."""
     for side, rows in sides.items():
         REGISTRY.counter(JOIN_ROWS_COUNTER, {"side": side}, help=JOIN_ROWS_HELP).inc(rows)
+
+
+def _join_key_words(node, probe: "Relation", build: "Relation", key_bits) -> int:
+    """32-bit words a join's key is matched in (the operands of the match's
+    merge sort beside its tag), as ``K.join_match`` packs them: a narrowed
+    column takes its bits, an integer column its type's width where both sides
+    agree, anything else 64 (an order key); a cross join matches one word."""
+    bits = 0
+    for i, (probe_sym, build_sym) in enumerate(node.criteria):
+        if key_bits is not None and key_bits[i] is not None:
+            bits += key_bits[i]
+            continue
+        pk, bk = probe.column_for(probe_sym).data.dtype, build.column_for(build_sym).data.dtype
+        bits += pk.itemsize * 8 if pk == bk and jnp.issubdtype(pk, jnp.signedinteger) else 64
+    return max(1, -(-bits // 32))
 
 
 def _type_counts(cols) -> Dict[str, int]:
@@ -471,11 +500,18 @@ class PlanExecutor:
     # may happen mid-plan — everything stays inside one XLA program.
     allow_host_sync = True
 
-    def _choose_join_capacity(self, emit, probe_cap: int, build_cap: int) -> int:
+    def _choose_join_capacity(self, emit, probe_cap: int, build_cap: int, totals=None) -> int:
         """Join output capacity: host-sync the exact emitted row count (the
         operator-at-a-time model; traced executors override with a static
-        bound + overflow accounting)."""
-        total = _sync_int(jnp.sum(emit), "join_capacity")
+        bound + overflow accounting). ``totals`` is an outer join's (rows
+        emitted, matches among them), which ``_jit_join_match`` summed: the
+        same one read carries both, and the rows emitted beyond the matches
+        are the probe rows no build row matched (``unmatched_rows``)."""
+        if totals is None:
+            total = _sync_int(jnp.sum(emit), "join_capacity")
+        else:
+            total, matched = _sync_ints(totals, "join_capacity")
+            _note(unmatched_rows=total - matched)
         _note(rows_out=total)
         _count_join_rows(out=total)
         return _round_capacity(max(total, 1))
@@ -1502,11 +1538,11 @@ class PlanExecutor:
                 return rel
 
         key_bits, key_bases = self._join_key_widths(node, probe, build)
-        emit, count, lo, perm_b = _jit_join_match(
+        emit, count, lo, perm_b, totals = _jit_join_match(
             left_outer, pkeys, bkeys, luts, probe.page.active, build.page.active,
             key_bits, key_bases,
         )
-        out_capacity = self._choose_join_capacity(emit, probe.capacity, build.capacity)
+        out_capacity = self._choose_join_capacity(emit, probe.capacity, build.capacity, totals)
         page = _jit_join_expand(
             out_capacity, emit, count, lo, perm_b, probe.page, build.page
         )
@@ -1595,6 +1631,7 @@ class PlanExecutor:
         None; a FULL join's tail and a residual filter come after it."""
         keys = [probe.column_for(l) for l, _ in node.criteria]
         _note(
+            kind=node.kind.name, key_words=_join_key_words(node, probe, build, key_bits),
             probe_rows=_rows_or_capacity(probe), build_rows=_rows_or_capacity(build),
             probe_capacity=probe.capacity, build_capacity=build.capacity,
             capacity_out=out_capacity, key_types=[c.type.display() for c in keys],
@@ -1603,6 +1640,7 @@ class PlanExecutor:
             build_types=_type_counts(build.page.columns), sort_passes=_sort_passes(1),
         )
         _count_join_rows(probe=_rows_or_capacity(probe), build=_rows_or_capacity(build))
+        REGISTRY.counter(JOINS_COUNTER, {"kind": node.kind.name}, help=JOINS_HELP).inc()
         if node.kind == JoinKind.FULL or node.filter is not None:
             return None
         span = TRACER.current()
@@ -1800,7 +1838,7 @@ class PlanExecutor:
             probe_capacity=source.capacity, build_capacity=filtering.capacity,
             rows_out=_rows_or_capacity(source), capacity_out=source.capacity,
             key_types=[skey.type.display()], probe_types=_type_counts(source.page.columns),
-            build_types=_type_counts([fkey]), sort_passes=_sort_passes(1),
+            build_types=_type_counts([fkey]), sort_passes=_sort_passes(1), negated=node.negated,
         )
         _count_join_rows(
             probe=_rows_or_capacity(source), build=_rows_or_capacity(filtering),
@@ -2293,7 +2331,13 @@ def aggregate_relation(
             )
         groups = _sync_int(num_groups, "num_groups")
         _note_aggregation(node, rel, path, groups)
-        out_cap = min(_round_capacity(max(groups, 1), base=16), max(rel.capacity, 16))
+        # a power of two up to 2**20 groups, a stored page's class above it
+        # (4.5M groups take 5,242,880 slots, not 8,388,608: every aggregate is
+        # a gather of the slots, and the join above sorts them)
+        out_cap = min(
+            _round_capacity(groups, base=16) if groups <= 1 << 20 else capacity_class(groups),
+            max(rel.capacity, 16),
+        )
     else:
         # global aggregation: no sort at all — select the needed columns
         cols = tuple(rel.column_for(s) for s in needed)
@@ -2358,6 +2402,7 @@ def _note_aggregation(node: AggregationNode, rel: Relation, path: str, groups) -
     rows_in = _rows_or_capacity(rel)
     attributes = dict(
         path=path, rows_in=rows_in, capacity_in=rel.capacity, groups=groups,
+        functions=[a.function for _, a in node.aggregations],
         keys=len(keys), key_types=[c.type.display() for c in keys],
         agg_types=_type_counts(
             rel.column_for(s) for s in _needed_agg_symbols(node) if s not in node.group_keys
@@ -2556,16 +2601,19 @@ def _aggregate_impl(
         ends = jnp.concatenate([starts[1:], jnp.array([n])]) - 1
         bounds = (starts, ends)
         safe_starts = jnp.clip(starts, 0, n - 1)
-        # min/max/arbitrary/approx_* need dense gids (scatter/sort paths)
+        # arbitrary/approx_*/... need dense gids (scatter/sort paths); min and
+        # max read the segments' bounds, and only an Int128's two passes
+        # broadcast the first pass's extreme back by gid
         if any(
             a.function
             in (
-                "min", "max", "arbitrary", "any_value", "approx_distinct",
+                "arbitrary", "any_value", "approx_distinct",
                 "approx_percentile", "tdigest_agg", "qdigest_agg", "array_agg",
                 "map_agg", "histogram", "multimap_agg", "listagg", "min_by",
                 "max_by", "bitwise_and_agg", "bitwise_or_agg",
                 "bitwise_xor_agg",
             )
+            or (a.function in ("min", "max") and rel.column_for(a.args[0]).data.ndim == 2)
             for _, a in aggregations
         ):
             # max(…, 0): presorted (unsorted-layout) inputs may have inactive
@@ -2575,31 +2623,38 @@ def _aggregate_impl(
                 K.cumsum(new_group.astype(jnp.int32)) - 1, 0
             ).astype(jnp.int32)
 
-    out_cols: List[Column] = []
-    # group key outputs: gather the first row of each group (out_cap gathers)
-    for k in group_keys:
-        c = rel.column_for(k)
-        in_range = jnp.arange(out_cap) < num_groups
-        out_cols.append(
-            Column(
-                c.type,
-                c.data[safe_starts],
-                c.valid[safe_starts] & in_range,
-                c.dictionary,
-            )
-        )
-
     if global_agg:
         # exactly one output row even over empty input
         group_exists = jnp.ones((1,), dtype=jnp.bool_)
     else:
         group_exists = jnp.arange(out_cap) < num_groups
 
+    # group key outputs: the first row of each group, every key's values and
+    # validity in ONE gather of out_cap rows
+    keys = [rel.column_for(k) for k in group_keys]
+    first = K.gather_rows([a for c in keys for a in (c.data, c.valid)], safe_starts) if keys else []
+    out_cols: List[Column] = [
+        Column(c.type, first[2 * i], first[2 * i + 1] & group_exists, c.dictionary)
+        for i, c in enumerate(keys)
+    ]
+
     def reduce_fn(vals, w, kind):
-        if kind in ("sum", "count"):
+        if kind in ("sum", "count", "min", "max"):  # read off the sorted segments' bounds
             return K.segment_reduce(vals, w, gid, out_cap, kind, new_group, bounds)
         g = gid if gid is not None else jnp.zeros(active_s.shape, dtype=jnp.int32)
         return K.segment_reduce(vals, w, g, out_cap, kind)
+
+    def reduce_many(asked):
+        """One round of the aggregates' reductions: those that are read at the
+        sorted segments' ends (counts, exact sums, extremes) travel in one
+        gather, whichever aggregates asked for them."""
+        results = [None] * len(asked)
+        at_ends = [] if global_agg else [i for i, a in enumerate(asked) if K.reads_at_ends(a[0], a[2])]
+        if at_ends:
+            read = K.segment_reduce_at_ends([asked[i] for i in at_ends], new_group, bounds[1])
+            for i, r in zip(at_ends, read):
+                results[i] = r
+        return [reduce_fn(*a) if r is None else r for a, r in zip(asked, results)]
 
     def first_fn(vals, w):
         g = gid if gid is not None else jnp.zeros(active_s.shape, dtype=jnp.int32)
@@ -2782,10 +2837,9 @@ def _aggregate_impl(
         )
         return kdata, kev, vdata, vev, lengths
 
-    for sym, agg in aggregations:
-        out_type = agg.output_type
-        col = _eval_aggregate(
-            rel, agg, out_type, active_s, out_cap, reduce_fn, first_fn,
+    steps = [
+        _aggregate_steps(
+            rel, agg, agg.output_type, active_s, out_cap, first_fn,
             distinct_count_fn, hll_fn, percentile_fn, tdigest_fn,
             array_agg_fn if agg_w else None,
             map_lanes_fn if agg_w else None,
@@ -2794,7 +2848,9 @@ def _aggregate_impl(
                 else jnp.zeros(active_s.shape, dtype=jnp.int32)
             ],
         )
-        out_cols.append(col)
+        for _, agg in aggregations
+    ]
+    out_cols.extend(_run_aggregates(steps, reduce_many))
 
     return Page(tuple(out_cols), group_exists)
 
@@ -2891,6 +2947,31 @@ _jit_direct_aggregate = partial(kernelcost.jit, static_argnums=(0, 1, 2, 3, 5))(
 )
 
 
+def _run_aggregates(steps, reduce_many) -> List[Column]:
+    """Run ``_aggregate_steps`` generators round by round: the reductions the
+    aggregates are waiting for go to ``reduce_many`` together, as a list of
+    ``(vals, weight, kind)``, and each aggregate is sent its own result, until
+    every one has returned its Column. What one round asks for depends on no
+    result of the same round, so a strategy may compute it in one pass."""
+    columns: List[Optional[Column]] = [None] * len(steps)
+    asked = {}
+
+    def resume(i, result=None):  # send(None) starts a generator
+        try:
+            asked[i] = steps[i].send(result)
+        except StopIteration as done:
+            asked.pop(i, None)
+            columns[i] = done.value
+
+    for i in range(len(steps)):
+        resume(i)
+    while asked:
+        order = sorted(asked)
+        for i, result in zip(order, reduce_many([asked[i] for i in order])):
+            resume(i, result)
+    return columns
+
+
 def _eval_aggregate(
     rel: Relation,
     agg: Aggregation,
@@ -2899,6 +2980,22 @@ def _eval_aggregate(
     out_cap: int,
     reduce_fn,
     first_fn,
+    *strategies,
+    **more,
+) -> Column:
+    """One aggregate by itself: ``_aggregate_steps`` with each reduction it
+    asks for answered at once by ``reduce_fn(vals, weight, kind)``."""
+    steps = _aggregate_steps(rel, agg, out_type, active_s, out_cap, first_fn, *strategies, **more)
+    return _run_aggregates([steps], lambda asked: [reduce_fn(*a) for a in asked])[0]
+
+
+def _aggregate_steps(
+    rel: Relation,
+    agg: Aggregation,
+    out_type: Type,
+    active_s: jnp.ndarray,
+    out_cap: int,
+    first_fn,
     distinct_count_fn=None,
     hll_fn=None,
     percentile_fn=None,
@@ -2906,11 +3003,13 @@ def _eval_aggregate(
     array_agg_fn=None,
     map_lanes_fn=None,
     broadcast_fn=None,
-) -> Column:
-    """One aggregate, strategy-agnostic: ``reduce_fn(vals, weight, kind)``
-    produces the per-group reduction (sort path: cumsum-at-boundaries /
-    gid scatter; direct path: [G, n] masked reduce), ``first_fn`` an arbitrary
-    participating row (ref: operator/aggregation/*, the Accumulator bodies)."""
+):
+    """One aggregate, strategy-agnostic, as a generator: it yields each
+    per-group reduction it needs as ``(vals, weight, kind)``, is sent the
+    result (sort path: read off the sorted segments' ends / gid scatter;
+    direct path: [G, n] masked reduce), and returns its Column. ``first_fn``
+    gives an arbitrary participating row (ref: operator/aggregation/*, the
+    Accumulator bodies)."""
     name = agg.function
     fmask = active_s
     if agg.filter is not None:
@@ -2918,20 +3017,20 @@ def _eval_aggregate(
         fmask = fmask & (fcol.data.astype(jnp.bool_) & fcol.valid)
 
     if name == "count" and not agg.args:
-        data = reduce_fn(fmask.astype(jnp.int64), fmask, "count")
+        data = (yield (fmask.astype(jnp.int64), fmask, "count"))
         return Column(BIGINT, data, jnp.ones((out_cap,), dtype=jnp.bool_))
 
     arg = rel.column_for(agg.args[0])
     vals_s = arg.data
     valid_s = arg.valid
     w = fmask & valid_s
-    nonempty = reduce_fn(w.astype(jnp.int64), w, "count")
+    nonempty = (yield (w.astype(jnp.int64), w, "count"))
 
     if name == "count":
         return Column(BIGINT, nonempty, jnp.ones((out_cap,), dtype=jnp.bool_))
     if name == "count_if":
         ws = w & vals_s.astype(jnp.bool_)
-        data = reduce_fn(ws.astype(jnp.int64), ws, "count")
+        data = (yield (ws.astype(jnp.int64), ws, "count"))
         return Column(BIGINT, data, jnp.ones((out_cap,), dtype=jnp.bool_))
     if name in ("$fsum", "$fsumsq"):
         # float64 partial states for distributed stddev/variance (fragmenter)
@@ -2940,11 +3039,11 @@ def _eval_aggregate(
             x = x / float(10**arg.type.scale)
         if name == "$fsumsq":
             x = x * x
-        data = reduce_fn(x, w, "sum")
+        data = (yield (x, w, "sum"))
         return Column(DOUBLE, data, jnp.ones((out_cap,), dtype=jnp.bool_))
     if name in ("sum", "avg"):
         acc_dtype = jnp.float64 if is_floating(arg.type) else jnp.int64
-        data = reduce_fn(vals_s.astype(acc_dtype), w, "sum")
+        data = (yield (vals_s.astype(acc_dtype), w, "sum"))
         if name == "avg":
             if isinstance(out_type, DecimalType):
                 # decimal avg keeps scale: round-half-up division
@@ -2972,9 +3071,9 @@ def _eval_aggregate(
         if name == "max":  # order-reversing complement: one code path
             h, ulo = ~h, ~ulo
         sent = jnp.iinfo(jnp.int64).max
-        h_ext = reduce_fn(jnp.where(w, h, sent), jnp.ones_like(w), "min")
+        h_ext = (yield (jnp.where(w, h, sent), jnp.ones_like(w), "min"))
         tied = w & (h == broadcast_fn(h_ext))
-        l_ext = reduce_fn(jnp.where(tied, ulo, sent), jnp.ones_like(w), "min")
+        l_ext = (yield (jnp.where(tied, ulo, sent), jnp.ones_like(w), "min"))
         if name == "max":
             h_ext, l_ext = ~h_ext, ~l_ext
         data = i128.make(h_ext, l_ext ^ jnp.int64(jnp.iinfo(jnp.int64).min))
@@ -2990,17 +3089,17 @@ def _eval_aggregate(
             masked = jnp.where(w, vals_s, name == "min")
         else:
             masked = jnp.where(w, vals_s.astype(jnp.int64), sent)
-        data = reduce_fn(masked, jnp.ones_like(w), name)
+        data = (yield (masked, jnp.ones_like(w), name))
         return Column(
             out_type, data.astype(out_type.storage_dtype), nonempty > 0, arg.dictionary
         )
     if name in ("bool_and", "every"):
         ws = w & ~vals_s.astype(jnp.bool_)
-        anyfalse = reduce_fn(ws.astype(jnp.int64), ws, "count")
+        anyfalse = (yield (ws.astype(jnp.int64), ws, "count"))
         return Column(BOOLEAN, anyfalse == 0, nonempty > 0)
     if name == "bool_or":
         ws = w & vals_s.astype(jnp.bool_)
-        anytrue = reduce_fn(ws.astype(jnp.int64), ws, "count")
+        anytrue = (yield (ws.astype(jnp.int64), ws, "count"))
         return Column(BOOLEAN, anytrue > 0, nonempty > 0)
     if name in ("arbitrary", "any_value"):
         # any participating row of each group
@@ -3010,8 +3109,8 @@ def _eval_aggregate(
         x = vals_s.astype(jnp.float64)
         if isinstance(arg.type, DecimalType):
             x = x / float(10**arg.type.scale)
-        s1 = reduce_fn(x, w, "sum")
-        s2 = reduce_fn(x * x, w, "sum")
+        s1 = (yield (x, w, "sum"))
+        s2 = (yield (x * x, w, "sum"))
         n = jnp.maximum(nonempty, 1).astype(jnp.float64)
         mean = s1 / n
         var_pop = jnp.maximum(s2 / n - mean * mean, 0.0)
@@ -3048,7 +3147,7 @@ def _eval_aggregate(
         # a row participates only if BOTH value and percentile are non-null —
         # the rank count must match the sort's participant mask exactly
         wq = w & qcol.valid
-        nq = reduce_fn(wq.astype(jnp.int64), wq, "count")
+        nq = (yield (wq.astype(jnp.int64), wq, "count"))
         q_g = first_fn(q, wq)
         data = percentile_fn(vals_s, wq, q_g, nq)
         return Column(
@@ -3137,10 +3236,10 @@ def _eval_aggregate(
         wk = fmask & kcol.valid
         key = K.encode_sort_column(kcol.data, kcol.valid, True, False)
         key = jnp.where(wk, key, K.INT64_MAX if name == "min_by" else K.INT64_MIN)
-        extreme = reduce_fn(key, wk, "min" if name == "min_by" else "max")
+        extreme = (yield (key, wk, "min" if name == "min_by" else "max"))
         at = wk & (key == broadcast_fn(extreme))
         data = first_fn(vals_s, at)
-        valid_out = (reduce_fn(wk.astype(jnp.int64), wk, "count") > 0) & first_fn(
+        valid_out = ((yield (wk.astype(jnp.int64), wk, "count")) > 0) & first_fn(
             valid_s, at
         )
         return Column(out_type, data, valid_out, arg.dictionary)
@@ -3156,13 +3255,13 @@ def _eval_aggregate(
         w2 = fmask & valid_s & xcol.valid
         y = _f64(arg, w2)
         x = _f64(xcol, w2)
-        n2 = reduce_fn(w2.astype(jnp.int64), w2, "count")
+        n2 = (yield (w2.astype(jnp.int64), w2, "count"))
         n = jnp.maximum(n2, 1).astype(jnp.float64)
-        sx = reduce_fn(x, w2, "sum")
-        sy = reduce_fn(y, w2, "sum")
-        sxy = reduce_fn(x * y, w2, "sum")
-        sxx = reduce_fn(x * x, w2, "sum")
-        syy = reduce_fn(y * y, w2, "sum")
+        sx = (yield (x, w2, "sum"))
+        sy = (yield (y, w2, "sum"))
+        sxy = (yield (x * y, w2, "sum"))
+        sxx = (yield (x * x, w2, "sum"))
+        syy = (yield (y * y, w2, "sum"))
         cov_pop = sxy / n - (sx / n) * (sy / n)
         varx = jnp.maximum(sxx / n - (sx / n) ** 2, 0.0)
         vary = jnp.maximum(syy / n - (sy / n) ** 2, 0.0)
@@ -3209,9 +3308,9 @@ def _eval_aggregate(
         # log2 entropy of per-row counts (ref: operator/aggregation/
         # EntropyAggregation): E = log2(S) - sum(c*log2(c)) / S
         c = jnp.maximum(_f64(arg, w), 0.0)
-        s = reduce_fn(c, w, "sum")
+        s = (yield (c, w, "sum"))
         clogc = jnp.where(c > 0, c * jnp.log2(jnp.where(c > 0, c, 1.0)), 0.0)
-        sl = reduce_fn(clogc, w, "sum")
+        sl = (yield (clogc, w, "sum"))
         pos = s > 0
         data = jnp.where(
             pos, jnp.log2(jnp.where(pos, s, 1.0)) - sl / jnp.where(pos, s, 1.0), 0.0
@@ -3220,16 +3319,16 @@ def _eval_aggregate(
     if name in ("bitwise_and_agg", "bitwise_or_agg", "bitwise_xor_agg"):
         kind = {"bitwise_and_agg": "band", "bitwise_or_agg": "bor",
                 "bitwise_xor_agg": "bxor"}[name]
-        data = reduce_fn(vals_s.astype(jnp.int64), w, kind)
+        data = (yield (vals_s.astype(jnp.int64), w, kind))
         return Column(BIGINT, data, nonempty > 0)
     if name in ("skewness", "kurtosis"):
         # central moments from raw power sums (CentralMomentsAggregation)
         x = _f64(arg, w)
         n2 = nonempty
         n = jnp.maximum(n2, 1).astype(jnp.float64)
-        s1 = reduce_fn(x, w, "sum")
-        s2 = reduce_fn(x * x, w, "sum")
-        s3 = reduce_fn(x * x * x, w, "sum")
+        s1 = (yield (x, w, "sum"))
+        s2 = (yield (x * x, w, "sum"))
+        s3 = (yield (x * x * x, w, "sum"))
         m = s1 / n
         M2 = s2 - s1 * m
         M3 = s3 - 3 * s2 * m + 2 * s1 * m * m
@@ -3238,7 +3337,7 @@ def _eval_aggregate(
             data = jnp.sqrt(n) * M3 / denom
             valid_out = (n2 > 2) & (M2 > 0)
         else:
-            s4 = reduce_fn(x * x * x * x, w, "sum")
+            s4 = (yield (x * x * x * x, w, "sum"))
             M4 = s4 - 4 * s3 * m + 6 * s2 * m * m - 3 * s1 * m * m * m
             m2sq = jnp.maximum(M2 * M2, 1e-300)
             data = (n * (n + 1) / jnp.maximum((n - 1) * (n - 2) * (n - 3), 1.0)) * (
@@ -3249,7 +3348,7 @@ def _eval_aggregate(
     if name == "geometric_mean":
         x = _f64(arg, w)
         logs = jnp.where(w, jnp.log(jnp.where(w, x, 1.0)), 0.0)
-        s = reduce_fn(logs, w, "sum")
+        s = (yield (logs, w, "sum"))
         n = jnp.maximum(nonempty, 1).astype(jnp.float64)
         return Column(DOUBLE, jnp.exp(s / n), nonempty > 0)
     if name == "checksum":
@@ -3261,11 +3360,11 @@ def _eval_aggregate(
             v = lut[jnp.clip(v, 0, lut.shape[0] - 1)]
         hashed = K.splitmix64(K.order_key(v))
         hashed = jnp.where(w, hashed, jnp.int64(0x9E3779B9))
-        data = reduce_fn(jnp.where(fmask, hashed, 0), fmask, "sum")
+        data = (yield (jnp.where(fmask, hashed, 0), fmask, "sum"))
         # zero-ROW groups return NULL (ref ChecksumAggregationFunction) —
         # but NULL input rows still update the state (the 0x9E3779B9 term
         # above), so the mask counts fmask rows, not non-null ones
-        any_rows = reduce_fn(fmask.astype(jnp.int64), fmask, "count")
+        any_rows = (yield (fmask.astype(jnp.int64), fmask, "count"))
         return Column(BIGINT, data, any_rows > 0)
     raise ExecutionError(f"aggregate {name} not implemented")
 
@@ -3393,11 +3492,13 @@ def _jit_join_match(
     pa = probe_active & probe_valid
     ba = build_active & build_valid
     perm_b, lo, hi, count = K.join_match(build_key, ba, probe_key, pa, key_bits)
-    if left_outer:
-        emit = jnp.where(probe_active, jnp.maximum(count, 1), 0)
-    else:
-        emit = count
-    return emit, count, lo, perm_b
+    if not left_outer:
+        return count, count, lo, perm_b, None
+    emit = jnp.where(probe_active, jnp.maximum(count, 1), 0)
+    # (rows emitted, matches among them): the rest are the probe rows no build
+    # row matched, and the one read that sizes the output carries both
+    totals = jnp.stack([jnp.sum(emit.astype(jnp.int64)), jnp.sum(count.astype(jnp.int64))])
+    return emit, count, lo, perm_b, totals
 
 
 @partial(kernelcost.jit, static_argnums=(0,))

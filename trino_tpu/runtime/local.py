@@ -718,11 +718,12 @@ class LocalQueryRunner:
                         profile = None
 
                         def _plan_once():
-                            with TRACER.span("planner"):
+                            with TRACER.span("planner") as planning:
                                 planner = LogicalPlanner(
                                     self.metadata, self.session
                                 )
                                 p = planner.plan(stmt)
+                                planning.attributes["decorrelated"] = planner.decorrelated
                             with TRACER.span("optimizer"):
                                 return optimize(
                                     p, self.metadata, self.session

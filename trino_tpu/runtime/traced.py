@@ -107,7 +107,7 @@ class _TracedExecutor(PlanExecutor):
         self.join_capacity_factor = join_capacity_factor
         self.overflows: List[jnp.ndarray] = []
 
-    def _choose_join_capacity(self, emit, probe_cap: int, build_cap: int) -> int:
+    def _choose_join_capacity(self, emit, probe_cap: int, build_cap: int, totals=None) -> int:
         cap = _round_capacity(max(int(probe_cap * self.join_capacity_factor), 1))
         self.overflows.append(
             jnp.maximum(jnp.sum(emit).astype(jnp.int64) - cap, 0)
