@@ -194,24 +194,97 @@ def test_group_sort_orders_and_bounds_groups_as_numpy_does(keys):
     assert np.flatnonzero(np.asarray(new_group)).tolist() == starts
 
 
-@pytest.mark.parametrize("columns", [1, 2])
-def test_join_match_counts_and_places_matches_as_numpy_does(columns):
+def _random_match(columns: int, n: int = 700, m: int = 300):
     rng = np.random.default_rng(columns)
-    n, m = 700, 300
     probe = [rng.integers(0, 40, size=n).astype(np.int64) for _ in range(columns)]
     build = [rng.integers(0, 40, size=m).astype(np.int64) for _ in range(columns)]
     probe[0][:3], build[0][:3] = K.INT64_MAX, K.INT64_MAX  # the old sentinel is a key like any other
-    pa, ba = rng.random(n) > 0.2, rng.random(m) > 0.3
-    perm_b, lo, hi, count = jax.jit(K.join_match)(
-        [jnp.asarray(b) for b in build], jnp.asarray(ba), [jnp.asarray(p) for p in probe], jnp.asarray(pa)
+    return build, rng.random(m) > 0.3, probe, rng.random(n) > 0.2, None
+
+
+def _narrowed_match():
+    """A column the dynamic filter narrowed to six bits beside one left at its type's width."""
+    build, ba, probe, pa, _ = _random_match(2)
+    probe[0][:3], build[0][:3] = 7, 7  # inside [0, 2**6), as every active row has to be
+    return build, ba, probe, pa, (6, None)
+
+
+def _no_active_build():
+    build, ba, probe, pa, _ = _random_match(1)
+    return build, np.zeros_like(ba), probe, pa, None
+
+
+def _one_key_twenty_builds():
+    build, ba, probe, pa, _ = _random_match(1)
+    build[0][:], probe[0][:] = np.arange(len(ba)) + 100, 17
+    shared = np.arange(5, len(ba), 14)[:20]
+    build[0][shared], ba[shared] = 17, True
+    return build, ba, probe, pa, None
+
+
+def _probes_outside(offset: int):
+    build, ba, probe, pa, _ = _random_match(1)
+    return build, ba, [probe[0] % 40 + offset], pa, None
+
+
+def _key_zero_beside_inactive_builds():
+    """An inactive build's key is zeroed in the merge: it stands in key 0's run and must not count."""
+    build, ba, probe, pa, _ = _random_match(1)
+    probe[0][::2], pa[:20] = 0, True
+    ba[:150] = False
+    build[0][150:160] = [0, 5, 0, 0, 9, 0, 1, 0, 0, 2]
+    return build, ba, probe, pa, None
+
+
+def _extremes():
+    build, ba, probe, pa, _ = _random_match(1)
+    ends = np.array([K.INT64_MIN, K.INT64_MAX, 0, -1], dtype=np.int64)
+    rng = np.random.default_rng(5)
+    return [ends[rng.integers(0, 4, size=len(ba))]], ba, [ends[rng.integers(0, 4, size=len(pa))]], pa, None
+
+
+MATCHES = {
+    "one_column": lambda: _random_match(1),
+    "two_columns": lambda: _random_match(2),
+    "narrowed_column": _narrowed_match,
+    "no_active_build": _no_active_build,
+    "one_key_twenty_builds": _one_key_twenty_builds,
+    "probes_below_every_build": lambda: _probes_outside(-1000),
+    "probes_above_every_build": lambda: _probes_outside(1000),
+    "key_zero_beside_inactive_builds": _key_zero_beside_inactive_builds,
+    "int64_min_beside_max": _extremes,
+    # the widest build side whose lo and count share a word: 16 bits each, the word's top bit in use
+    "one_full_rank_word": lambda: _random_match(1, n=500, m=65_535),
+    # 2 * bits(m) > 32: lo and count travel back as two words (K.rank_words)
+    "two_rank_words": lambda: _random_match(1, n=500, m=70_000),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MATCHES))
+def test_join_match_counts_and_places_matches_as_numpy_does(case):
+    build, ba, probe, pa, key_bits = MATCHES[case]()
+    n, m = len(pa), len(ba)
+    assert K.rank_words(m) == (2 if case == "two_rank_words" else 1)
+    perm_b, lo, hi, count = jax.jit(K.join_match, static_argnums=4)(
+        [jnp.asarray(b) for b in build], jnp.asarray(ba), [jnp.asarray(p) for p in probe], jnp.asarray(pa), key_bits
     )
-    perm_b, lo, count = np.asarray(perm_b), np.asarray(lo), np.asarray(count)
-    assert perm_b.min() >= 0 and perm_b.max() < m
+    perm_b, lo, hi, count = np.asarray(perm_b), np.asarray(lo), np.asarray(hi), np.asarray(count)
+    assert perm_b.min() >= 0 and perm_b.max() < m and np.array_equal(hi, lo + count)
     for i in range(n):
-        want = [j for j in range(m) if ba[j] and all(b[j] == p[i] for b, p in zip(build, probe))]
+        same = ba.copy()
+        for b, p in zip(build, probe):
+            same &= b == p[i]
+        want = np.flatnonzero(same).tolist()
         assert count[i] == (len(want) if pa[i] else 0)
         if pa[i]:
             assert perm_b[lo[i]: lo[i] + count[i]].tolist() == want  # ties in row order
+
+
+@pytest.mark.parametrize("case", sorted(c for c in MATCHES if c not in ("two_columns", "narrowed_column")))
+def test_semijoin_mask_is_membership_among_the_active_builds(case):
+    (build,), ba, (probe,), pa, _ = MATCHES[case]()
+    mask = jax.jit(K.semijoin_mask)(jnp.asarray(build), jnp.asarray(ba), jnp.asarray(probe), jnp.asarray(pa))
+    assert np.array_equal(np.asarray(mask), pa & np.isin(probe, build[ba]))
 
 
 def _gather_zoo(rng, n: int) -> list:
@@ -341,13 +414,16 @@ def test_q10s_group_sort_is_one_sort_of_three_operands(runner):
 
 PROGRAM_SHAPES = {
     # program: (sort instructions at most, operands of all of them together at most)
-    # a join's match: the merge (key words and the tag), the ranks back in probe order (two
-    # operands), and the builds' places where they are dense among the queries (two)
-    "join_match": (3, 7),
-    "join_match_two_columns": (3, 9),  # two bigint keys of unknown range are four words
-    "compact": (1, 2),                 # the positions, and an operand nothing reads (K.live_indices)
+    # a join's match: the merge of n + m rows (the key's words and the tag), the ranks' way back in
+    # probe order (the probe's row number and lo and count: two payload words, or one where the
+    # build side is small enough for both to share it), and the builds' places where they are
+    # dense among the probes (the positions and an operand nothing reads)
+    "join_match": (3, 8),
+    "join_match_two_columns": (3, 10),  # two bigint keys of unknown range are four words
+    "compact": (1, 2),                  # the positions, and an operand nothing reads (K.live_indices)
     "order_by": (1, 3),
-    "semijoin": (3, 7),
+    # a semi-join: the merge, and one word back (the count); no perm_b, so no third sort
+    "semijoin": (2, 5),
 }
 
 
@@ -376,6 +452,26 @@ def test_sort_family_programs_hold_few_small_sorts(program):
     most, operands = PROGRAM_SHAPES[program]
     sorts = _sorts(text)
     assert 1 <= len(sorts) <= most and sum(sorts) <= operands, sorts
+
+
+def _sort_rows(lowered_text: str) -> list:
+    """Rows of every sort instruction in a lowered program (its first operand's length)."""
+    return [int(r) for r in re.findall(r'"stablehlo\.sort"\(.*?\}\) : \(tensor<(\d+)x', lowered_text, flags=re.S)]
+
+
+def test_a_match_sorts_each_probe_row_once():
+    """At (n, m) = (4096, 1024) no sort of the match or of the semi-join holds
+    more than n + m rows: 5,120 where the merge of [lo-queries, builds,
+    hi-queries] held 9,216 (ISSUE 37). The key is a bigint of unknown range,
+    and the build side wide enough (m = 70,000) for two rank words as well."""
+    for n, m in ((4096, 1024), (4096, 70_000)):
+        big, small = jnp.zeros(n, jnp.int64), jnp.zeros(m, jnp.int64)
+        on, som = jnp.ones(n, bool), jnp.ones(m, bool)
+        page = Page(tuple(Column(BIGINT, big, on) for _ in range(2)), on)
+        match = E._jit_join_match.lower(True, ((big, on),), ((small, som),), (None,), on, som).as_text()
+        semi = E._jit_semijoin.lower(Column(BIGINT, big, on), Column(BIGINT, small, som), None, page, som, False).as_text()
+        assert sorted(_sort_rows(match)) == [n + m] * 3 and _sorts(match) == [3, 1 + K.rank_words(m), 2]
+        assert _sort_rows(semi) == [n + m] * 2 and _sorts(semi) == [3, 2]
 
 
 def test_q14s_compaction_moves_its_columns_in_one_gather():

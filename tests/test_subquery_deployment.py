@@ -412,6 +412,10 @@ def test_operator_spans_and_counters(runner, name):
     join_spans = [s.attributes for s in spans if s.name == "op:JoinNode"]
     assert {k: sum(a["kind"] == k for a in join_spans) for k in kinds if k in joins} == joins
     assert len(join_spans) == sum(joins.values())
+    for a in join_spans + [s.attributes for s in spans if s.name == "op:SemiJoinNode"]:
+        # the match's merge holds each row once; the ranks come back in one word where two fit it
+        assert a["merged_rows"] == a["probe_capacity"] + a["build_capacity"]
+        assert a["rank_words"] == (1 if "negated" in a or a["build_capacity"] < 65536 else 2)
     for a in join_spans:
         narrowed = sum(b for b in (a["key_bits"] or []) if b)
         if a["kind"] == "CROSS":

@@ -1,6 +1,6 @@
 """Compiles for the chip that is not attached (the `on-chip-measurement` guide,
 section 2): the sort family's programs at the join deployment's shapes, held to
-the budget ISSUE 34 set: no program over 90 s. Four programs, all in this one
+the budget ISSUE 34 set: no program over 90 s. Six programs, all in this one
 file; the topology is described inside a fixture and nothing touches the TPU's
 library while a module is imported. A compile that passes is not a chip run."""
 
@@ -97,9 +97,17 @@ def test_q14s_sparse_compaction_at_sf3(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 8 * 4 * rows
 
 
-def test_join_match_of_one_bigint_key(one_chip):
-    """524,288 probe rows against 131,072 build rows."""
-    probe, build = 524288, 131072
+@pytest.mark.parametrize(
+    "probe,build",
+    [
+        pytest.param(524288, 131072, id="half_a_million_probes"),
+        # the cells' largest shape (PR 37): `lineitem`'s stored page against `orders`', a merge of
+        # 24,117,248 rows in three operands, three more on the way back, `perm_b` by a sort
+        pytest.param(18_874_368, 5_242_880, id="lineitem_against_orders_at_sf3"),
+    ],
+)
+def test_join_match_of_one_bigint_key(one_chip, probe, build):
+    """One bigint key of unknown range: two words and the tag."""
 
     def key(rows):
         return (jax.ShapeDtypeStruct((rows,), jnp.int64, sharding=one_chip),

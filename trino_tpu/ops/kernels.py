@@ -876,6 +876,71 @@ def join_keys(
     return [d for d, _ in probe_cols], p_valid, [d for d, _ in build_cols], b_valid
 
 
+def _merge_match(build_keys, build_active, probe_keys, probe_active, key_bits):
+    """The one merge of a match: every build row and every probe row once,
+    n + m rows. Returns, in merged order, (position, is_build, lo, count):
+    the row's place in [builds, probes], whether it is an active build, and,
+    for a probe's row, how many active builds hold a smaller key and how many
+    hold its own."""
+    if not isinstance(build_keys, (list, tuple)):
+        build_keys, probe_keys = [build_keys], [probe_keys]
+    n = probe_active.shape[0]
+    m = build_active.shape[0]
+    total = n + m
+    if total >= 1 << 30:
+        raise ValueError(f"join of {n} probe and {m} build rows: the tag needs n + m < 2**30")
+    fields = []
+    for i, (bk, pk) in enumerate(zip(build_keys, probe_keys)):
+        bits = key_bits[i] if key_bits is not None else None
+        if bits is not None:
+            bk = jnp.where(build_active, bk, 0)
+            fields.append(order_field(jnp.concatenate([bk, pk]), bits=bits))
+            continue
+        # integer keys of one width keep it (a date or an integer is one word,
+        # a bigint two); anything else meets as an int64 order key
+        if pk.dtype != bk.dtype or not jnp.issubdtype(pk.dtype, jnp.signedinteger):
+            pk, bk = order_key(pk), order_key(bk)
+        # an inactive build's key orders nothing: zero it
+        bk = jnp.where(build_active, bk, jnp.zeros((), bk.dtype))
+        fields.append(order_field(jnp.concatenate([bk, pk])))
+    pos = jnp.arange(total, dtype=jnp.int32)
+    is_build = jnp.concatenate([build_active.astype(jnp.int32), jnp.zeros(n, jnp.int32)])
+    words = sort_words(fields)[::-1]  # most significant first, as lax.sort compares
+    *s_words, s_tag = jax.lax.sort(
+        (*words, pos * 2 + is_build), num_keys=len(words) + 1, is_stable=False
+    )
+    s_is_build = s_tag & 1
+    # the builds stand before the probes, so a probe stands behind every build
+    # of its key: the builds before it are those at or below its key (hi)
+    builds_before = cumsum(s_is_build) - s_is_build  # exclusive
+    # the sort hands the key's words back in order: a run of equal keys begins
+    # where a word differs from the row before, and the count at a run's first
+    # row (the builds strictly below the key) reaches the run's other rows by
+    # a running maximum, the counts never falling
+    differs = s_words[0][1:] != s_words[0][:-1]
+    for w in s_words[1:]:
+        differs = differs | (w[1:] != w[:-1])
+    run_starts = jnp.concatenate([jnp.ones((1,), jnp.bool_), differs])
+    lo = cummax(jnp.where(run_starts, builds_before, 0))
+    return s_tag >> 1, s_is_build, lo, builds_before - lo
+
+
+def _ranks_back(s_pos, m: int, n: int, payloads):
+    """``payloads`` of the merged order's probe rows, in the probe's order: one
+    sort by the probe's row number, which is distinct; the builds sort behind
+    the probes. A sort and not a scatter: the TPU's scatter sorts its indices
+    itself, with more operands."""
+    qid = jnp.where(s_pos >= m, s_pos - m, n)
+    return [p[:n] for p in jax.lax.sort((qid, *payloads), num_keys=1, is_stable=False)[1:]]
+
+
+def rank_words(m: int) -> int:
+    """Payload words a join's ranks travel back in (``join_match``): ``lo``
+    and ``count`` lie in [0, m], and where two such fit 32 bits they share a
+    word."""
+    return 1 if 2 * m.bit_length() <= 32 else 2
+
+
 def join_match(build_keys, build_active, probe_keys, probe_active, key_bits=None):
     """Sorted-build matching: returns (perm_b, lo, hi, count) where sorted build
     rows [lo, hi) match each probe row. (PagesHash/JoinProbe analogue.)
@@ -888,67 +953,45 @@ def join_match(build_keys, build_active, probe_keys, probe_active, key_bits=None
     Probe ranks come from ONE merge sort, not searchsorted: binary search is
     ~20 dependent gather rounds over the probe (measured 2.5s for 6M probes
     into 1M build on v5e) while a sort of the concatenated keys streams.
-    Concat order IS the tie-break: [lo-queries, builds, hi-queries], and the
-    row's position is the sort's last key, so a lo-query ranks before its
-    equal builds (counting keys strictly below) and a hi-query after
-    (counting <=). The sort's operands are the key's 32-bit words and one
-    tag, position * 2 + is_build: nothing else rides it (what a sort costs
-    the TPU's compiler grows with its operands). Only ACTIVE builds carry
+    The merge holds every row once, n + m rows. Concat order IS the
+    tie-break: [builds, probes], and the row's position is the sort's last
+    key, so a probe stands behind its equal builds and the active builds
+    before it count the keys <= its own (hi); the count where its run of
+    equal keys begins, carried along the run, counts the keys strictly below
+    (lo). The sort's operands are the key's 32-bit words and one tag,
+    position * 2 + is_build: nothing else rides it (what a sort costs the
+    TPU's compiler grows with its operands). Only ACTIVE builds carry
     is_build, so an inactive build row is never counted whatever its key
-    holds: no sentinel key, and a genuine INT64_MAX matches like any other.
+    holds (zeroed, it joins key 0's run and counts for nothing): no sentinel
+    key, and a genuine INT64_MAX or INT64_MIN matches like any other. The
+    way back to the probe's order is a second sort of n + m rows by the
+    probe's row number that carries ``lo`` and ``count``: two payload words,
+    or one where the build side is small enough for both (``rank_words``).
     ``perm_b`` lists the active build rows in key order, ties in row order;
     the slots after them hold row 0 and are never matched."""
-    if not isinstance(build_keys, (list, tuple)):
-        build_keys, probe_keys = [build_keys], [probe_keys]
     n = probe_active.shape[0]
     m = build_active.shape[0]
-    total = 2 * n + m
-    if total >= 1 << 30:
-        raise ValueError(f"join of {n} probe and {m} build rows: the tag needs 2n + m < 2**30")
-    fields = []
-    for i, (bk, pk) in enumerate(zip(build_keys, probe_keys)):
-        bits = key_bits[i] if key_bits is not None else None
-        if bits is not None:
-            bk = jnp.where(build_active, bk, 0)
-            fields.append(order_field(jnp.concatenate([pk, bk, pk]), bits=bits))
-            continue
-        # integer keys of one width keep it (a date or an integer is one word,
-        # a bigint two); anything else meets as an int64 order key
-        if pk.dtype != bk.dtype or not jnp.issubdtype(pk.dtype, jnp.signedinteger):
-            pk, bk = order_key(pk), order_key(bk)
-        # an inactive build's key orders nothing: zero it
-        bk = jnp.where(build_active, bk, jnp.zeros((), bk.dtype))
-        fields.append(order_field(jnp.concatenate([pk, bk, pk])))
-    pos = jnp.arange(total, dtype=jnp.int32)
-    is_build = jnp.concatenate(
-        [jnp.zeros(n, jnp.int32), build_active.astype(jnp.int32), jnp.zeros(n, jnp.int32)]
+    s_pos, s_is_build, s_lo, s_count = _merge_match(
+        build_keys, build_active, probe_keys, probe_active, key_bits
     )
-    words = sort_words(fields)[::-1]  # most significant first, as lax.sort compares
-    s_tag = jax.lax.sort(
-        (*words, pos * 2 + is_build), num_keys=len(words) + 1, is_stable=False
-    )[-1]
-    s_is_build = s_tag & 1
-    builds_before = cumsum(s_is_build) - s_is_build  # exclusive
-    # back to the probe's order: query id i for lo-query i, n + i for hi-query
-    # i, 2n for the builds, which so sort behind the queries. A sort by the
-    # ids, which are distinct, and not a scatter: the TPU's scatter sorts its
-    # indices itself, with more operands
-    s_pos = s_tag >> 1
-    qid = jnp.where(s_pos < n, s_pos, jnp.where(s_pos >= n + m, s_pos - m, 2 * n))
-    rank = jax.lax.sort(
-        (qid, builds_before.astype(jnp.int32)), num_keys=1, is_stable=False
-    )[1][: 2 * n]
-    lo = rank[:n]
-    hi = rank[n:]
-    count = jnp.where(probe_active, jnp.maximum(hi - lo, 0), 0)
+    if rank_words(m) == 1:
+        shift = m.bit_length()  # unsigned: at m = 65,535 the word is full
+        packed = (s_lo.astype(jnp.uint32) << shift) | s_count.astype(jnp.uint32)
+        (packed,) = _ranks_back(s_pos, m, n, [packed])
+        lo = (packed >> shift).astype(jnp.int32)
+        count = (packed & jnp.uint32((1 << shift) - 1)).astype(jnp.int32)
+    else:
+        lo, count = _ranks_back(s_pos, m, n, [s_lo, s_count])
+    count = jnp.where(probe_active, count, 0)
     # the builds in sorted order ARE perm_b: where the r-th active build stands
     # in the merged order, read off the mask (``live_indices``: a walk over it
-    # where the builds are few among the queries, a sort of the positions
+    # where the builds are few among the probes, a sort of the positions
     # where they are not). Slots past the active builds hold row 0: nothing
     # matches there.
+    total = n + m
     at = live_indices(s_is_build == 1, m)
-    perm_b = jnp.where(at < total, s_pos[jnp.minimum(at, total - 1)] - n, 0)
-    return perm_b, lo, hi, count
+    perm_b = jnp.where(at < total, s_pos[jnp.minimum(at, total - 1)], 0)
+    return perm_b, lo, lo + count, count
 
 
 def expand_probe_slots(emit: jnp.ndarray, out_capacity: int):
@@ -1022,9 +1065,11 @@ def semijoin_mask(
     probe_key: jnp.ndarray,
     probe_active: jnp.ndarray,
 ) -> jnp.ndarray:
-    """matched[i] for each probe row (HashSemiJoinOperator/SetBuilderOperator)."""
-    _, lo, hi, count = join_match(build_key, build_active, probe_key, probe_active)
-    return count > 0
+    """matched[i] for each probe row (HashSemiJoinOperator/SetBuilderOperator):
+    ``join_match``'s merge, one word back (the count), and no ``perm_b``."""
+    s_pos, _, _, s_count = _merge_match(build_key, build_active, probe_key, probe_active, None)
+    (count,) = _ranks_back(s_pos, build_active.shape[0], probe_active.shape[0], [s_count])
+    return probe_active & (count > 0)
 
 
 # --------------------------------------------------------------------------- #

@@ -382,7 +382,8 @@ def _count_join_rows(**sides: int) -> None:
 
 def _join_key_words(node, probe: "Relation", build: "Relation", key_bits) -> int:
     """32-bit words a join's key is matched in (the operands of the match's
-    merge sort beside its tag), as ``K.join_match`` packs them: a narrowed
+    merge sort of n + m rows beside its one tag, position * 2 + is_build;
+    the way back is a sort of its own), as ``K.join_match`` packs them: a narrowed
     column takes its bits, an integer column its type's width where both sides
     agree, anything else 64 (an order key); a cross join matches one word."""
     bits = 0
@@ -1638,6 +1639,7 @@ class PlanExecutor:
             key_bits=None if key_bits is None else list(key_bits),
             probe_types=_type_counts(probe.page.columns),
             build_types=_type_counts(build.page.columns), sort_passes=_sort_passes(1),
+            merged_rows=probe.capacity + build.capacity, rank_words=K.rank_words(build.capacity),
         )
         _count_join_rows(probe=_rows_or_capacity(probe), build=_rows_or_capacity(build))
         REGISTRY.counter(JOINS_COUNTER, {"kind": node.kind.name}, help=JOINS_HELP).inc()
@@ -1839,6 +1841,7 @@ class PlanExecutor:
             rows_out=_rows_or_capacity(source), capacity_out=source.capacity,
             key_types=[skey.type.display()], probe_types=_type_counts(source.page.columns),
             build_types=_type_counts([fkey]), sort_passes=_sort_passes(1), negated=node.negated,
+            merged_rows=source.capacity + filtering.capacity, rank_words=1,
         )
         _count_join_rows(
             probe=_rows_or_capacity(source), build=_rows_or_capacity(filtering),
