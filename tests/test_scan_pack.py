@@ -6,6 +6,7 @@ and the class is smaller than the capacities together; else the plain
 concatenation. TPC-H at SF0.01 cut into 18 (`lineitem`) and 5 (`orders`)
 splits of 4,096 rows of capacity, as SF3 has 18 of 2,097,152."""
 
+import contextlib
 import math
 
 import jax
@@ -19,13 +20,11 @@ from trino_tpu.parallel import mesh_runner as mr
 from trino_tpu.parallel.runner import DistributedQueryRunner
 from trino_tpu.runtime import LocalQueryRunner
 from trino_tpu.runtime.executor import (
-    SCAN_CONCATS_COUNTER,
     _concat_pages,
     _concat_scan_pages,
     _load_splits,
     _round_capacity,
 )
-from trino_tpu.runtime.metrics import REGISTRY
 from trino_tpu.runtime.tracing import TRACER, children
 from trino_tpu.spi.connector import SchemaTableName, TableHandle
 from trino_tpu.spi.page import Page, capacity_class
@@ -37,15 +36,19 @@ SPLITS = {"lineitem": 18, "orders": 5}
 ROWS = {"lineitem": 59957, "orders": 15000}
 
 
-def concats() -> dict:
-    return {
-        path: REGISTRY.counter(SCAN_CONCATS_COUNTER, {"path": path}).value
-        for path in ("packed", "plain")
-    }
-
-
-def ticks(before: dict) -> dict:
-    return {path: n - before[path] for path, n in concats().items()}
+@contextlib.contextmanager
+def scans():
+    """{"packed": n, "plain": m} of the scans of several pages made under it,
+    from their spans: `scan_pack` where the pages were packed; the read of the
+    pages' counts (`sync:scan_pack`) with no `scan_pack` after it where they
+    were concatenated as they were (pages with a nested column are, before
+    any count is read: they leave no span)."""
+    seen = {}
+    with TRACER.span("test") as root:
+        yield seen
+    names = [s.name for s in TRACER.spans(root.trace_id)]
+    packed = names.count("scan_pack")
+    seen.update(packed=packed, plain=names.count("sync:scan_pack") - packed)
 
 
 def newest_tree():
@@ -86,9 +89,9 @@ def live(page: Page):
 def test_packed_scan_holds_the_plain_concatenations_rows_in_order(tpch, table):
     pages = split_pages(tpch, table)
     assert len(pages) == SPLITS[table]
-    before = concats()
-    packed, plain = _concat_scan_pages(pages), _concat_pages(pages)
-    assert ticks(before) == {"packed": 1, "plain": 0}
+    with scans() as seen:
+        packed, plain = _concat_scan_pages(pages), _concat_pages(pages)
+    assert seen == {"packed": 1, "plain": 0}
     # a prefix, in the connectors' class, and smaller than the padding kept
     active = np.asarray(packed.active)
     assert active[: ROWS[table]].all() and not active[ROWS[table]:].any()
@@ -131,9 +134,9 @@ def two_pages(rows_a, rows_b, capacity, active_b=None):
 ])
 def test_path_follows_what_the_pages_hold(case, pages, path):
     pages = pages()
-    before = concats()
-    got, plain = _concat_scan_pages(pages), _concat_pages(pages)
-    assert ticks(before) == {"packed": int(path == "packed"), "plain": int(path == "plain")}
+    with scans() as seen:
+        got, plain = _concat_scan_pages(pages), _concat_pages(pages)
+    assert seen == {"packed": int(path == "packed"), "plain": int(path == "plain")}
     (rows, _), = live(got)
     (want, _), = live(plain)
     assert rows.tolist() == want.tolist()
@@ -146,15 +149,15 @@ def test_path_follows_what_the_pages_hold(case, pages, path):
 
 def test_single_page_is_handed_back_untouched():
     (page, _) = two_pages(3, 2, 64)
-    before = concats()
-    assert _concat_scan_pages([page]) is page
-    assert ticks(before) == {"packed": 0, "plain": 0}
+    with scans() as seen:
+        assert _concat_scan_pages([page]) is page
+    assert seen == {"packed": 0, "plain": 0}
 
 
 def test_fully_pruned_scan_concatenates_nothing(runner):
-    before = concats()
-    assert runner.execute("SELECT count(*) FROM tpch.sf0_01.lineitem WHERE l_orderkey < 0").rows == [(0,)]
-    assert ticks(before) == {"packed": 0, "plain": 0}
+    with scans() as seen:
+        assert runner.execute("SELECT count(*) FROM tpch.sf0_01.lineitem WHERE l_orderkey < 0").rows == [(0,)]
+    assert seen == {"packed": 0, "plain": 0}
 
 
 FEW = "FROM tpch.sf0_01.orders WHERE o_orderkey < {}"  # a prefix of the scan's page
@@ -176,9 +179,11 @@ def test_two_stored_pages_scan_as_the_rows_inserted(runner, case, first, second,
     runner.execute("DROP TABLE IF EXISTS memory.default.two")
     runner.execute(f"CREATE TABLE memory.default.two AS {first}")
     runner.execute(f"INSERT INTO memory.default.two {second}")
-    before = concats()
-    got = runner.execute("SELECT id, v FROM memory.default.two").rows
-    assert ticks(before) == {"packed": int(path == "packed"), "plain": int(path == "plain")}
+    with scans() as seen:
+        got = runner.execute("SELECT id, v FROM memory.default.two").rows
+    # a nested column is concatenated as it is before any count is read: no span
+    read = case != "nested column"
+    assert seen == {"packed": int(path == "packed"), "plain": int(path == "plain" and read)}
     # in insertion order, with no ORDER BY: as a UNION ALL (`_concat_union_pages`) gives them
     assert got == runner.execute(f"{first} UNION ALL {second}").rows
     assert len(got) > 1 and len({str(v) for _, v in got}) > 1
@@ -189,18 +194,18 @@ def test_two_stored_pages_scan_as_the_rows_inserted(runner, case, first, second,
 
 @pytest.mark.parametrize("table,total", [("lineitem", "l_extendedprice"), ("orders", "o_totalprice")])
 def test_ctas_stores_the_packed_page_and_answers_as_the_direct_scan(runner, tpch, table, total):
-    before = concats()
-    created = runner.execute(f"CREATE TABLE memory.default.{table} AS SELECT * FROM tpch.sf0_01.{table}")
-    assert created.rows == [(ROWS[table],)]
-    assert ticks(before) == {"packed": 1, "plain": 0}
+    with scans() as seen:
+        created = runner.execute(f"CREATE TABLE memory.default.{table} AS SELECT * FROM tpch.sf0_01.{table}")
+        assert created.rows == [(ROWS[table],)]
+    assert seen == {"packed": 1, "plain": 0}
     stored = runner.catalogs.get("memory").table(SchemaTableName("default", table))
     assert [p.capacity for p in stored.pages] == [capacity_class(ROWS[table])]
     assert stored.row_count() == ROWS[table]
     sql = f"SELECT count(*), sum({total}), min({total}), max({total}) FROM {{}}.{table}"
-    before = concats()
-    assert runner.execute(sql.format("memory.default")).rows == runner.execute(sql.format("tpch.sf0_01")).rows
+    with scans() as seen:
+        assert runner.execute(sql.format("memory.default")).rows == runner.execute(sql.format("tpch.sf0_01")).rows
     # the stored page is one page (nothing to concatenate), the direct scan packs again
-    assert ticks(before) == {"packed": 1, "plain": 0}
+    assert seen == {"packed": 1, "plain": 0}
     # and in order: the generator's, by key
     key = {"lineitem": "l_orderkey, l_linenumber", "orders": "o_orderkey"}[table]
     stored_rows = runner.execute(f"SELECT {key} FROM memory.default.{table}").rows
@@ -208,9 +213,7 @@ def test_ctas_stores_the_packed_page_and_answers_as_the_direct_scan(runner, tpch
 
 
 def test_scan_pack_span_and_sync_sit_under_the_scan_that_asked(runner):
-    before = concats()
     runner.execute("SELECT count(*) FROM tpch.sf0_01.orders")
-    assert ticks(before) == {"packed": 1, "plain": 0}
     tree = newest_tree()
     (scan,) = [s for s in tree if s.name == "op:TableScanNode"]
     under = {s.name: s for s in children(tree, scan)}
@@ -232,16 +235,16 @@ def test_insert_delete_update_and_rollback_hold_on_a_packed_table(runner):
     n, total = count()
     assert n == 15000
     assert runner.execute("INSERT INTO memory.default.o2 SELECT * FROM tpch.sf0_01.orders").rows == [(15000,)]
-    before = concats()
-    assert count() == (2 * n, 2 * total)
+    with scans() as seen:
+        assert count() == (2 * n, 2 * total)
     # two pages of 16,384 hold 30,000 rows: their class is what they have together
-    assert ticks(before) == {"packed": 0, "plain": 1}
+    assert seen == {"packed": 0, "plain": 1}
     (deleted,), = runner.execute("DELETE FROM memory.default.o2 WHERE o_orderkey % 2 = 0").rows
     kept = runner.execute("SELECT count(*) FROM tpch.sf0_01.orders WHERE o_orderkey % 2 <> 0").rows[0][0]
     assert deleted == 2 * (n - kept)
-    before = concats()
-    assert count()[0] == 2 * kept
-    assert ticks(before) == {"packed": 0, "plain": 1}  # holes: not prefix-live
+    with scans() as seen:
+        assert count()[0] == 2 * kept
+    assert seen == {"packed": 0, "plain": 1}  # holes: not prefix-live
     runner.execute("START TRANSACTION")
     runner.execute("UPDATE memory.default.o2 SET o_totalprice = 0")
     assert count() == (2 * kept, 0)
@@ -268,9 +271,9 @@ def test_mesh_shards_a_quarter_of_the_packed_capacity(tpch):
         session=dist.session, n_devices=n, catalogs=dist.catalogs, metadata=dist.metadata
     )
     sql = "SELECT sum(l_quantity), count(*) FROM lineitem"
-    before = concats()
-    specs, _ = mesh._shard_scans(dist.plan_distributed(sql))
-    assert ticks(before) == {"packed": 1, "plain": 0}
+    with scans() as seen:
+        specs, _ = mesh._shard_scans(dist.plan_distributed(sql))
+    assert seen == {"packed": 1, "plain": 0}
     (spec,) = specs
     packed = capacity_class(ROWS["lineitem"])
     assert packed == 65536 < SPLITS["lineitem"] * 4096

@@ -41,14 +41,12 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..metadata import CatalogManager, Metadata, Session
-from ..planner import LogicalPlanner, optimize
 from ..planner.fragmenter import (
     ExchangeType,
     Partitioning,
     RemoteSourceNode,
     SubPlan,
-    add_exchanges,
-    create_fragments,
+    plan_fragments,
 )
 from ..planner.plan import LogicalPlan, OutputNode, PlanNode, TableScanNode, visit_plan
 from ..planner.stats import StatsEstimator
@@ -70,9 +68,8 @@ from ..runtime.local import QueryResult
 from ..runtime.memory import page_bytes
 from ..runtime.metrics import REGISTRY
 from ..runtime.traced import is_traceable
-from ..runtime.tracing import TRACER
+from ..runtime.tracing import SYNC_PREFIX, TRACER
 from ..spi.page import Column, Page
-from ..sql import parse_statement
 from . import exchange
 from .mesh import make_mesh
 
@@ -288,12 +285,7 @@ class MeshQueryRunner:
     # ----------------------------------------------------------------- planning
 
     def plan_distributed(self, sql: str) -> SubPlan:
-        stmt = parse_statement(sql)
-        planner = LogicalPlanner(self.metadata, self.session)
-        plan = planner.plan(stmt)
-        plan = optimize(plan, self.metadata, self.session)
-        plan = add_exchanges(plan, self.metadata, self.session)
-        return create_fragments(plan)
+        return plan_fragments(sql, self.metadata, self.session)
 
     # ---------------------------------------------------------------- execution
 
@@ -303,19 +295,32 @@ class MeshQueryRunner:
             names, page = self.execute_subplan(subplan)
             return QueryResult(names, self.gather(page))
 
-    @staticmethod
-    def gather(page: Page) -> list:
-        """The answer's rows on the host (span `mesh:gather`): the wait for
-        the mesh program, the copy from device 0 and the row encoding."""
+    def gather(self, out_page: Page) -> list:
+        """The answer's rows on the host (span `mesh:gather`), from the root
+        page as `execute_subplan` hands it back: shard 0's block of it, the
+        wait for the mesh program, the copy from device 0 and the row
+        encoding."""
         with TRACER.span("mesh:gather") as span:
-            rows = page.to_pylist()
+            # out_specs P(axis) stacks each shard's (replicated) root block;
+            # the root fragment is SINGLE so shard 0's block is the complete
+            # answer
+            cap = out_page.capacity // self.n
+            cols = tuple(
+                Column(c.type, c.data[:cap], c.valid[:cap], c.dictionary)
+                for c in out_page.columns
+            )
+            rows = Page(cols, out_page.active[:cap]).to_pylist()
             span.attributes["rows"] = len(rows)
         return rows
 
     def execute_subplan(self, subplan: SubPlan) -> Tuple[List[str], Page]:
-        """Spans `mesh:load_scan`, `mesh:shard` (per scan) and `mesh:program`
-        (per attempt) under the caller's statement root; `gather` adds
-        `mesh:gather`.
+        """Spans `mesh:lower` (the plan checked, keyed and fingerprinted,
+        the capacities read or seeded, the program in hand; the scans'
+        `mesh:load_scan` and `mesh:shard` inside it; once more for a rebuilt
+        program) and `mesh:program` (per attempt, its read of what it
+        measured a `sync:mesh_measured` inside it) under the caller's
+        statement root; `gather` adds `mesh:gather`. Returns the column names
+        and the root page as the program left it, every shard's block of it.
 
         A statement's first execution settles its capacities: seeded from the
         estimator (a shard's share, with its margin), grown where a point
@@ -323,43 +328,61 @@ class MeshQueryRunner:
         not run at the capacity its count asks for (`settled_capacity`). What
         it settles at is kept by the plan's fingerprint (runtime/capstore),
         so the next execution is one attempt of a cached program."""
-        self._check_lowerable(subplan)
-        scan_specs, scan_counts = self._shard_scans(subplan)
-        root = subplan.root_fragment.root
-        assert isinstance(root, OutputNode)
-
-        join_factor = float(self.session.get("mesh_join_capacity_factor") or 1.0)
-        flat_pages = [s.page for s in scan_specs]
-
         from ..runtime import observability as obs
 
         collector = obs.current_collector()
-        plan_key = repr(
-            [(f.fragment_id, f.partitioning, f.root) for f in subplan.fragments]
-        )
-        shapes = (self.n, tuple(p.capacity for p in flat_pages), join_factor)
-        points = self._points(subplan)
-        fingerprint = hashlib.sha256(repr((plan_key, shapes)).encode()).hexdigest()
-        kept = capstore.load(fingerprint)
-        settled = kept is not None and len(kept) == len(points)
-        caps = list(kept) if settled else self._seed_capacities(subplan, points)
+        join_factor = float(self.session.get("mesh_join_capacity_factor") or 1.0)
+
+        # from the plan to the program in hand; the scans' resharding
+        # (`mesh:load_scan`, `mesh:shard`) lies inside, the shapes it gives
+        # are part of the program's key
+        with TRACER.span("mesh:lower") as lowering:
+            self._check_lowerable(subplan)
+            scan_specs, scan_counts = self._shard_scans(subplan)
+            root = subplan.root_fragment.root
+            assert isinstance(root, OutputNode)
+            flat_pages = [s.page for s in scan_specs]
+            plan_key = repr(
+                [(f.fragment_id, f.partitioning, f.root) for f in subplan.fragments]
+            )
+            shapes = (self.n, tuple(p.capacity for p in flat_pages), join_factor)
+            points = self._points(subplan)
+            fingerprint = hashlib.sha256(repr((plan_key, shapes)).encode()).hexdigest()
+            kept = capstore.load(fingerprint)
+            settled = kept is not None and len(kept) == len(points)
+            caps = list(kept) if settled else self._seed_capacities(subplan, points)
+
+            def program_for(caps, lowering):
+                """(the program at these capacities, whether it was kept); the
+                span `mesh:lower` it ends says which, and of how many points."""
+                cache_key = (plan_key, shapes, tuple(caps))
+                program = self._program_cache.get(cache_key)
+                cached = program is not None
+                if program is None:
+                    program = self._build_program(subplan, scan_counts, caps, join_factor)
+                    self._program_cache[cache_key] = program
+                elif collector is not None:
+                    collector.add_count("compile_cache_hits")
+                lowering.attributes.update(
+                    cached=cached, points=len(points), settled=settled
+                )
+                return program, cached
+
+            program, cached = program_for(caps, lowering)
         resized = False
         for attempt in range(_MAX_ATTEMPTS):
-            cache_key = (plan_key, shapes, tuple(caps))
-            program = self._program_cache.get(cache_key)
-            cached = program is not None
-            if program is None:
-                program = self._build_program(subplan, scan_counts, caps, join_factor)
-                self._program_cache[cache_key] = program
-            elif collector is not None:
-                collector.add_count("compile_cache_hits")
+            if attempt:  # rebuilt at other capacities: a lowering of its own
+                with TRACER.span("mesh:lower", attempt=attempt) as lowering:
+                    program, cached = program_for(caps, lowering)
             # the one shard_map program and the one read of what it measured
-            # (the flight recorder keeps the span under the category `mesh`)
+            # (the flight recorder keeps the span under the category `mesh`);
+            # the read is the span's child, so its own time is the dispatch
             with TRACER.span(
                 "mesh:program", cat="mesh", attempt=attempt, cached=cached,
             ) as ran, obs.compile_window() as cw:
                 out_page, measured = program.fn(*flat_pages)
-                measured = np.asarray(measured)
+                with TRACER.span(SYNC_PREFIX + "mesh_measured"):
+                    measured = np.asarray(measured)
                 k = len(program.ordinals)
                 overflow, actual = measured[1 : 1 + k], measured[1 + k :]
                 held = int(measured[0]) == 0
@@ -414,15 +437,7 @@ class MeshQueryRunner:
         if caps != kept:
             capstore.save(fingerprint, caps)
 
-        # out_specs P(axis) stacks each shard's (replicated) root block; the
-        # root fragment is SINGLE so shard 0's block is the complete answer
-        cap = out_page.capacity // self.n
-        cols = tuple(
-            Column(c.type, c.data[:cap], c.valid[:cap], c.dictionary)
-            for c in out_page.columns
-        )
-        page = Page(cols, out_page.active[:cap])
-        return list(root.column_names), page
+        return list(root.column_names), out_page
 
     # ----------------------------------------------------------------- internals
 

@@ -25,15 +25,13 @@ import numpy as np
 
 from ..metadata import CatalogManager, Metadata, Session
 from .. import knobs
-from ..planner import LogicalPlanner, optimize
 from ..planner.fragmenter import (
     ExchangeType,
     Partitioning,
     PlanFragment,
     RemoteSourceNode,
     SubPlan,
-    add_exchanges,
-    create_fragments,
+    plan_fragments,
 )
 from ..planner.plan import LogicalPlan, OutputNode, PlanNode, TableScanNode, visit_plan
 from ..runtime.device_scheduler import current_priority as _current_priority
@@ -50,7 +48,6 @@ from ..spi.host_pages import (
 )
 from ..spi.page import Column, Dictionary, Page
 from ..spi.types import is_string
-from ..sql import parse_statement
 from ..sql import tree as t
 
 
@@ -278,17 +275,7 @@ class DistributedQueryRunner:
         return runner
 
     def plan_distributed(self, sql: str) -> SubPlan:
-        from ..planner.fragmenter import determine_partition_counts
-
-        stmt = parse_statement(sql)
-        planner = LogicalPlanner(self.metadata, self.session)
-        plan = planner.plan(stmt)
-        plan = optimize(plan, self.metadata, self.session)
-        plan = add_exchanges(plan, self.metadata, self.session)
-        subplan = create_fragments(plan)
-        return determine_partition_counts(
-            subplan, self.metadata, self.session, self.n_workers
-        )
+        return plan_fragments(sql, self.metadata, self.session, self.n_workers)
 
     def execute(self, sql: str) -> QueryResult:
         from ..runtime.failure import execute_with_retry

@@ -643,3 +643,31 @@ def determine_partition_counts(
                 1, min(max_parts, math.ceil(max(known) / target))
             )
     return subplan
+
+
+def plan_fragments(sql: str, metadata, session, max_parts: Optional[int] = None) -> SubPlan:
+    """SQL text to its fragments, for the runners that execute a SubPlan
+    (parallel/runner.py, parallel/mesh_runner.py), under the spans `parse`,
+    `planner`, `optimizer` as runtime/local.py opens them, and `fragment`
+    around the exchanges, the fragments and (with ``max_parts``) their
+    partition counts. They hang under the caller's current span; with none
+    current they are timed and kept in no tree."""
+    from ..runtime.tracing import TRACER
+    from ..sql import parse_statement
+    from .logical_planner import LogicalPlanner
+    from .optimizer import optimize
+
+    with TRACER.span("parse", root=False):
+        stmt = parse_statement(sql)
+    with TRACER.span("planner", root=False) as planning:
+        planner = LogicalPlanner(metadata, session)
+        plan = planner.plan(stmt)
+        planning.attributes["decorrelated"] = planner.decorrelated
+    with TRACER.span("optimizer", root=False):
+        plan = optimize(plan, metadata, session)
+    with TRACER.span("fragment", root=False) as fragmenting:
+        subplan = create_fragments(add_exchanges(plan, metadata, session))
+        if max_parts is not None:
+            subplan = determine_partition_counts(subplan, metadata, session, max_parts)
+        fragmenting.attributes["fragments"] = len(subplan.fragments)
+    return subplan

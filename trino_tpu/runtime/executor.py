@@ -211,27 +211,14 @@ def _concat_pages(pages: List[Page]) -> Page:
     return Page(tuple(cols), active)
 
 
-SCAN_CONCATS_COUNTER = "trino_tpu_scan_concats_total"
-SCAN_CONCATS_HELP = (
-    "scans that made one page of several split pages, by path: packed (the "
-    "live rows of every split at the front of a page of their capacity "
-    "class) or plain (a concatenation that keeps each split's padding)"
-)
-
-
 def _concat_scan_pages(pages: List[Page]) -> Page:
     """A scan's split pages as one page, packed where that is copies and
     nothing else (`_pack_pages`), else the plain concatenation. Rows keep
-    their order within and across splits either way. One tick of
-    ``trino_tpu_scan_concats_total{path}`` per scan of more than one page."""
+    their order within and across splits either way: the span `scan_pack`
+    is there where the pages were packed."""
     if len(pages) == 1:
         return pages[0]
     packed = _pack_pages(pages)
-    REGISTRY.counter(
-        SCAN_CONCATS_COUNTER,
-        {"path": "plain" if packed is None else "packed"},
-        help=SCAN_CONCATS_HELP,
-    ).inc()
     return _concat_pages(pages) if packed is None else packed
 
 
@@ -355,12 +342,6 @@ GROUP_ROWS_HELP = (
     "rows that entered an aggregation (live rows where counted, else the page's "
     "capacity), by path: direct, presorted, sort, global"
 )
-SORT_PASSES_COUNTER = "trino_tpu_sort_passes_total"
-SORT_PASSES_HELP = (
-    "sort passes the sort-family programs launched hold: 32-bit words of packed "
-    "keys for a group sort or an ORDER BY (a pass whose word is the same in every "
-    "row is skipped on the device and still counted), one for a join's merge sort"
-)
 
 
 def _note(**attributes) -> None:
@@ -405,11 +386,9 @@ def _type_counts(cols) -> Dict[str, int]:
 
 
 def _sort_passes(bits: int) -> int:
-    """Passes ``K.sort_perm`` holds for keys of ``bits`` bits in all; ticks
-    the counter by them."""
-    passes = -(-bits // 32)
-    REGISTRY.counter(SORT_PASSES_COUNTER, help=SORT_PASSES_HELP).inc(passes)
-    return passes
+    """Passes ``K.sort_perm`` holds for keys of ``bits`` bits in all: 32-bit
+    words of packed keys (the operator's span carries them, ``sort_passes``)."""
+    return -(-bits // 32)
 
 
 def _key_bits(c: Column) -> int:
@@ -2143,18 +2122,13 @@ COMPACTIONS_HELP = (
     "pages made dense, by how the rows kept are found: index (a walk over the "
     "mask) or sort (one sort of the positions); the columns are gathered"
 )
-COMPACTION_GATHERS_COUNTER = "trino_tpu_compaction_gathers_total"
-COMPACTION_GATHERS_HELP = (
-    "pages made dense, by how their flat columns follow the rows kept: packed "
-    "(32-bit words of one matrix, one gather) or plain (a gather an array)"
-)
 
 
 def _compact(page: Page, live_rows: int) -> Page:
     """The page's ``live_rows`` active rows, in row order, at the front of a
     page of the next capacity class. One `compact` span under the operator
-    that asked, one tick of ``trino_tpu_compactions_total{path}`` and one of
-    ``trino_tpu_compaction_gathers_total{form}``."""
+    that asked (``gather``: the form its columns follow in) and one tick of
+    ``trino_tpu_compactions_total{path}``."""
     new_cap = min(_round_capacity(max(live_rows, 1)), page.capacity)
     path = _compact_path(new_cap, page)
     # what `_jit_compact`'s one `K.gather_rows` is traced to, by the same call
@@ -2168,9 +2142,6 @@ def _compact(page: Page, live_rows: int) -> Page:
     ):
         REGISTRY.counter(
             COMPACTIONS_COUNTER, {"path": path}, help=COMPACTIONS_HELP
-        ).inc()
-        REGISTRY.counter(
-            COMPACTION_GATHERS_COUNTER, {"form": form}, help=COMPACTION_GATHERS_HELP
         ).inc()
         return _jit_compact(new_cap, page)
 

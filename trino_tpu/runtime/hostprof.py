@@ -68,9 +68,11 @@ _WAIT_LEAVES = frozenset({
 # the request phases phase_span names; kept ordered for docs/tests.
 # "route"/"proxy" are the coordinator-fleet additions (runtime/fleet.py):
 # ownership hashing + non-owner forwarding cost is attributed, not hidden
+# "client_turn" (server/coordinator.py) is the one the work waits in for the
+# client: from a page with a nextUri going out to the next request coming in
 PROTOCOL_PHASES = (
     "accept", "auth", "verify", "parse", "route", "proxy", "queue",
-    "admit", "result_stream", "dispatch",
+    "admit", "result_stream", "client_turn", "dispatch",
 )
 
 

@@ -143,6 +143,9 @@ class QueryExecution:
     # (QueryManager.close_statement), or by a reader that joins it
     feedback: List[Any] = field(default_factory=list, repr=False)
     _feedback_sent: int = field(default=0, repr=False)  # how many went to the pool
+    # the open span `client_turn`: a page with a nextUri is out, the next
+    # request is not in (server/coordinator.py)
+    _client_turn: Optional[Any] = field(default=None, repr=False)
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
     _done: threading.Event = field(default_factory=threading.Event, repr=False)
     _state_listeners: List[Callable] = field(default_factory=list, repr=False)
@@ -485,9 +488,21 @@ class QueryManager:
         from the HTTP thread that is about to send the answer."""
         from .tracing import TRACER
 
+        self.end_client_turn(q)
         if q.stats.root is not None:
             TRACER.close_span(q.stats.root, **attributes)
         self._schedule_feedback(q)
+
+    @staticmethod
+    def end_client_turn(q: QueryExecution) -> None:
+        """Ends the span `client_turn` the protocol front opened when it sent
+        a page with a nextUri: the client's next request has arrived, or the
+        statement is closed without one."""
+        from .tracing import TRACER
+
+        turn, q._client_turn = q._client_turn, None
+        if turn is not None:
+            TRACER.close_span(turn)
 
     def _schedule_feedback(self, q: QueryExecution) -> None:
         with q._lock:

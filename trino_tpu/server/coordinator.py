@@ -185,7 +185,8 @@ class CoordinatorServer:
                 """One page of the statement's protocol to the client: the
                 `result_stream` span under the statement's root, on this
                 HTTP thread. The page without a nextUri is the last: the
-                statement's timeline ends with it."""
+                statement's timeline ends with it. Any other opens the span
+                `client_turn` as it goes out, until the client asks again."""
                 from ..runtime.hostprof import phase_span
                 from ..runtime.observability import RECORDER
                 from ..runtime.tracing import TRACER
@@ -200,6 +201,16 @@ class CoordinatorServer:
                     sent["rows"] = len(payload.get("data", ())) + sum(
                         seg["rowCount"] for seg in payload.get("segments", ())
                     )
+                    if "nextUri" in payload and q.stats.root is not None:
+                        # from here the work waits for the client: until its
+                        # request for the next page arrives (do_GET), or the
+                        # statement is closed without one (cancel, expiry).
+                        # Opened before the page goes out: the next request
+                        # cannot arrive before its span is there to end
+                        q._client_turn = TRACER.open_span(
+                            "client_turn", q.stats.root, cat="protocol",
+                            token=token,
+                        )
                     sent["bytes"] = self._send(
                         200, payload,
                         extra_headers=coordinator._session_headers(q),
@@ -763,6 +774,7 @@ class CoordinatorServer:
                     if q is None:
                         self._send(404, {"error": "unknown query"})
                         return
+                    coordinator.manager.end_client_turn(q)
                     # long-poll-ish: wait briefly for progress (the reference's
                     # ExecutingStatementResource does the same with maxWait)
                     if not q.state.is_done:
