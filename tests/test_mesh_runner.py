@@ -273,3 +273,305 @@ class TestTierObservability:
         # cross joins are a documented mesh rejection — staged, with a reason
         assert lowered["cross"][0] == "staged"
         assert "cross" in (lowered["cross"][1] or "")
+
+
+# --------------------------------------------------------------------------- #
+# a table's shards stay on the mesh between statements (`_ShardStore`)
+# --------------------------------------------------------------------------- #
+
+STORE_DEV = 4
+T = "memory.default.t"
+SUMS = f"SELECT count(*), sum(k), sum(q) FROM {T}"
+
+
+def hits(result):
+    from trino_tpu.parallel import mesh_runner as mr
+    from trino_tpu.runtime.metrics import REGISTRY
+
+    return REGISTRY.counter(mr.COLUMNS_COUNTER, {"result": result}).value
+
+
+def mesh_over(connector):
+    """A mesh runner over ``connector`` as catalog `memory`, four devices."""
+    from trino_tpu.metadata import Session
+    from trino_tpu.parallel.mesh_runner import MeshQueryRunner
+
+    if len(jax.devices()) < STORE_DEV:
+        pytest.skip(f"need {STORE_DEV} devices")
+    mesh = MeshQueryRunner(Session(catalog="memory", schema="default"), n_devices=STORE_DEV)
+    mesh.catalogs.register("memory", connector)
+    return mesh
+
+
+@pytest.fixture()
+def stored():
+    """(mesh runner, loader, connector): the loader (one chip) writes the
+    tables of the memory connector that the mesh runner scans, as the
+    benchmark's runner `mesh_memory` has it."""
+    from trino_tpu.connectors.memory import MemoryConnector
+
+    memory = MemoryConnector()
+    loader = LocalQueryRunner.tpch(scale=SCALE)
+    loader.register_catalog("memory", memory)
+    loader.execute(
+        f"CREATE TABLE {T} AS SELECT l_orderkey AS k, l_quantity AS q, "
+        "l_extendedprice AS p, l_returnflag AS f FROM lineitem"
+    )
+    loader.execute("CREATE TABLE memory.default.u AS SELECT o_orderkey AS k, o_totalprice AS p FROM orders")
+    return mesh_over(memory), loader, memory
+
+
+def traced(mesh, sql):
+    """(rows, the attributes of the statement's `mesh:load_scan` spans, those
+    of its `mesh:shard` spans)."""
+    from trino_tpu.runtime.tracing import STATEMENT, TRACER
+
+    with TRACER.span(STATEMENT) as root:
+        rows = mesh.execute(sql).rows
+    tree = TRACER.spans(root.trace_id)
+    return (
+        rows,
+        [s.attributes for s in tree if s.name == "mesh:load_scan"],
+        [s.attributes for s in tree if s.name == "mesh:shard"],
+    )
+
+
+class TestShardsStayOnTheMesh:
+    def test_a_second_statement_moves_no_byte(self, stored):
+        mesh, loader, _ = stored
+        before = hits("hit"), hits("miss")
+        first, (load1,), (shard1,) = traced(mesh, SUMS)
+        second, (load2,), (shard2,) = traced(mesh, SUMS)
+        assert first == second == loader.execute(SUMS).rows
+        assert (shard1["cached"], shard1["put"]) == (0, 2) and shard1["h2d_bytes"] >= load1["bytes"] > 0
+        assert (shard2["cached"], shard2["put"], shard2["h2d_bytes"]) == (2, 0, 0)
+        assert load2["cached"] == 2 and (load2["rows"], load2["bytes"]) == (load1["rows"], load1["bytes"])
+        assert (hits("hit") - before[0], hits("miss") - before[1]) == (2, 2)
+
+    def test_statements_share_the_columns_they_have_in_common(self, stored):
+        mesh, loader, _ = stored
+        one = f"SELECT sum(k), sum(q) FROM {T} WHERE q < 30"
+        other = f"SELECT sum(p), max(k) FROM {T} WHERE q > 10"
+        (_, _, (shard1,)), (rows, _, (shard2,)) = traced(mesh, one), traced(mesh, other)
+        assert (shard1["cached"], shard1["put"]) == (0, 2)
+        assert (shard2["cached"], shard2["put"]) == (2, 1)   # k and q found, p put
+        assert 0 < shard2["h2d_bytes"] < shard1["h2d_bytes"]
+        assert rows == loader.execute(other).rows
+        (table,) = mesh._shards.tables.values()
+        assert len(table.columns) == 3    # the union of both scans, once each
+
+    @pytest.mark.parametrize("write", ["insert", "delete", "drop_create", "ctas"])
+    def test_a_write_is_read_by_the_next_statement(self, stored, write):
+        mesh, loader, _ = stored
+        assert traced(mesh, SUMS)[0] == loader.execute(SUMS).rows
+        held = mesh._shards.device_bytes()
+        old = [c.data for t in mesh._shards.tables.values() for c in t.columns.values()]
+        for sql in {
+            "insert": [f"INSERT INTO {T} SELECT k + 1000000, q, p, f FROM {T} WHERE k < 100"],
+            "delete": [f"DELETE FROM {T} WHERE k >= 100"],
+            "drop_create": [
+                f"DROP TABLE {T}",
+                f"CREATE TABLE {T} (k bigint, q decimal(12,2), p decimal(12,2), f varchar)",
+                f"INSERT INTO {T} VALUES (7, 1.50, 2.25, 'A'), (8, 2.50, 3.25, 'N')",
+            ],
+            "ctas": [
+                f"DROP TABLE {T}",
+                f"CREATE TABLE {T} AS SELECT o_orderkey AS k, o_totalprice AS q FROM orders WHERE o_orderkey < 50",
+            ],
+        }[write]:
+            loader.execute(sql)
+        want = loader.execute(SUMS).rows
+        stale, miss = hits("stale"), hits("miss")
+        rows, _, (shard,) = traced(mesh, SUMS)
+        assert rows == want
+        assert (shard["cached"], shard["put"]) == (0, 2)
+        assert (hits("stale") - stale, hits("miss") - miss) == (2, 2)
+        # the old version's arrays are gone from the store: one table, the new one
+        (table,) = mesh._shards.tables.values()
+        assert not any(c.data is o for c in table.columns.values() for o in old)
+        assert mesh._shards.device_bytes() == table.device_bytes > 0
+        if write in ("drop_create", "ctas"):   # a smaller table: the store's bytes fall back
+            assert mesh._shards.device_bytes() < held
+        assert traced(mesh, SUMS)[2][0]["h2d_bytes"] == 0
+
+    def test_a_write_after_the_sweep_is_caught_at_the_lookup(self, stored, monkeypatch):
+        mesh, loader, _ = stored
+        traced(mesh, SUMS)
+        sweep = mesh._shards.drop_stale
+
+        def sweep_then_write(metadata):
+            sweep(metadata)
+            loader.execute(f"DELETE FROM {T} WHERE k >= 100")
+
+        monkeypatch.setattr(mesh._shards, "drop_stale", sweep_then_write)
+        stale = hits("stale")
+        rows, _, (shard,) = traced(mesh, SUMS)
+        monkeypatch.undo()
+        assert rows == loader.execute(SUMS).rows
+        assert (shard["cached"], shard["put"], hits("stale") - stale) == (0, 2, 2)
+
+    @pytest.mark.parametrize("answer", ["no_token", "bypass", "unhashable_handle"])
+    def test_a_table_without_a_token_is_resharded_every_statement(self, stored, answer, monkeypatch):
+        from trino_tpu.connectors.memory import MemoryConnector
+
+        mesh, loader, memory = stored
+        if answer == "no_token":
+            monkeypatch.setattr(MemoryConnector, "cache_table_version", None)
+        elif answer == "bypass":
+            monkeypatch.setattr(memory, "cache_bypass", True, raising=False)
+        else:
+            absorb = lambda handle, domain: type(handle)(handle.catalog, handle.schema_table, {"pushed": [1]})
+            monkeypatch.setattr(memory.metadata(), "apply_filter", absorb, raising=False)
+        sql = f"SELECT count(*), sum(k) FROM {T} WHERE q < 30"
+        want = loader.execute(sql).rows
+        moved = []
+        for _ in range(2):
+            rows, _, (shard,) = traced(mesh, sql)
+            assert rows == want and (shard["cached"], shard["put"]) == (0, 2)
+            moved.append(shard["h2d_bytes"])
+        assert moved[0] == moved[1] > 0
+        assert not mesh._shards.tables
+
+    def test_a_token_that_changes_under_the_load_keeps_nothing(self, stored, monkeypatch):
+        from trino_tpu.connectors.memory import MemoryConnector
+
+        mesh, loader, memory = stored
+        ticks = iter(range(10**6))
+        monkeypatch.setattr(
+            MemoryConnector, "cache_table_version", lambda self, schema, table: f"moving-{next(ticks)}"
+        )
+        rows, _, (shard,) = traced(mesh, SUMS)
+        assert rows == loader.execute(SUMS).rows and shard["put"] == 2
+        assert not mesh._shards.tables and mesh._shards.device_bytes() == 0
+
+    def test_a_write_under_a_partial_load_drops_what_was_kept(self, stored, monkeypatch):
+        mesh, loader, memory = stored
+        traced(mesh, f"SELECT sum(k) FROM {T}")
+        load = mesh._load_columns
+
+        def written_under(connector, handle, col_indexes):
+            page = load(connector, handle, col_indexes)
+            if len(col_indexes) == 1:    # the partial load of q: a writer gets in
+                loader.execute(f"DELETE FROM {T} WHERE k >= 100")
+            return page
+
+        monkeypatch.setattr(mesh, "_load_columns", written_under)
+        rows, (loaded,), (shard,) = traced(mesh, SUMS)
+        # all of it loaded again after the write, the kept k not used
+        assert (loaded["cached"], shard["cached"], shard["put"]) == (0, 0, 2)
+        assert rows == loader.execute(SUMS).rows
+        monkeypatch.undo()
+        assert traced(mesh, SUMS)[0] == rows
+
+    def test_the_budget_evicts_the_least_recently_used_table(self, stored):
+        mesh, loader, _ = stored
+        u = "SELECT count(*), sum(k), sum(p) FROM memory.default.u"
+        three = f"SELECT count(*), sum(k), sum(p), sum(q) FROM {T}"
+        traced(mesh, three)
+        (t_key,) = mesh._shards.tables
+        mesh._shards.budget = mesh._shards.device_bytes()    # room for t's three columns, and no more
+        mesh._shards.clear()
+        traced(mesh, SUMS)
+        t_bytes = mesh._shards.device_bytes()
+        traced(mesh, u)
+        assert len(mesh._shards.tables) == 2    # two columns of each: both fit
+        traced(mesh, SUMS)                        # t is the most recent now
+        rows, _, (shard,) = traced(mesh, three)
+        assert shard["put"] == 1    # p joins t: u, the least recently used, goes
+        assert list(mesh._shards.tables) == [t_key]
+        assert t_bytes < mesh._shards.device_bytes() == mesh._shards.budget
+        # the evicted table is answered, and kept again at t's cost
+        got, _, (again,) = traced(mesh, u)
+        assert got == loader.execute(u).rows and (again["cached"], again["put"]) == (0, 2)
+        # a table over the budget by itself is used and not kept
+        mesh._shards.budget = 1
+        rows, _, (over,) = traced(mesh, SUMS)
+        assert rows == loader.execute(SUMS).rows and over["put"] == 2
+        assert not mesh._shards.tables
+
+    def test_the_program_donates_no_argument(self, stored):
+        mesh, _, _ = stored
+        subplan = mesh.plan_distributed(SUMS)
+        specs, counts = mesh._shard_scans(subplan)
+        pages = [s.page for s in specs]
+        points = mesh._points(subplan)
+        program = mesh._build_program(subplan, counts, [None] * len(points), 1.0)
+        lowered = program.fn.lower(*pages)
+        assert "donor" not in lowered.as_text() and "aliasing_output" not in lowered.as_text()
+        args = jax.tree_util.tree_leaves(lowered.args_info)
+        assert len(args) == len(jax.tree_util.tree_leaves(pages)) and not any(a.donated for a in args)
+        out, _ = program.fn(*pages)
+        jax.block_until_ready(out)
+        # the store's arrays are alive after the program that read them
+        for leaf in jax.tree_util.tree_leaves(pages):
+            assert not leaf.is_deleted()
+            np.asarray(leaf[:1])
+
+    def test_the_page_a_hit_assembles_is_the_page_a_cold_scan_builds(self, stored):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        mesh, _, memory = stored
+        sql = f"SELECT f, sum(q), max(k) FROM {T} GROUP BY f"
+        subplan = mesh.plan_distributed(sql)
+        (cold,), _ = mesh_over(memory)._shard_scans(subplan)    # another runner: an empty store
+        mesh._shard_scans(subplan)
+        (hit,), _ = mesh._shard_scans(subplan)
+        assert hit.symbols == cold.symbols
+        assert jax.tree_util.tree_structure(hit.page) == jax.tree_util.tree_structure(cold.page)
+        sharding = NamedSharding(mesh.mesh, P(mesh.axis))
+        for a, b in zip(jax.tree_util.tree_leaves(hit.page), jax.tree_util.tree_leaves(cold.page)):
+            assert (a.shape, a.dtype) == (b.shape, b.dtype)
+            assert a.sharding.is_equivalent_to(sharding, a.ndim)
+            assert [s.data.shape for s in a.addressable_shards] == [s.data.shape for s in b.addressable_shards]
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        # dictionaries are the same objects, so the jitted program is found again
+        assert [c.dictionary for c in hit.page.columns] == [c.dictionary for c in cold.page.columns]
+
+    def test_a_put_that_runs_out_of_memory_empties_the_store_and_is_tried_again(self, stored, monkeypatch):
+        from trino_tpu.parallel import mesh_runner as mr
+
+        mesh, loader, _ = stored
+        traced(mesh, "SELECT count(*), sum(k), sum(p) FROM memory.default.u")
+        assert len(mesh._shards.tables) == 1
+        put, failed = jax.device_put, []
+
+        def full_once(tree, sharding):
+            if not failed:
+                failed.append(True)
+                raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory while trying to allocate")
+            return put(tree, sharding)
+
+        monkeypatch.setattr(mr.jax, "device_put", full_once)
+        rows, _, (shard,) = traced(mesh, SUMS)
+        assert failed and rows == loader.execute(SUMS).rows and shard["put"] == 2
+        assert [str(t.handle.schema_table) for t in mesh._shards.tables.values()] == ["default.t"]
+
+    def test_two_statements_that_miss_the_same_columns_put_them_once(self, stored):
+        import sys
+        import threading
+
+        mesh, loader, _ = stored
+        want = loader.execute(SUMS).rows
+        mesh.execute(SUMS)    # the program is compiled; the threads race for the shards alone
+        mesh._shards.clear()
+        miss, got, errors = hits("miss"), [], []
+
+        def run():
+            try:
+                got.append(mesh.execute(SUMS).rows)
+            except Exception as e:  # noqa: BLE001
+                errors.append(e)
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=run) for _ in range(6)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=240)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads) and not errors
+        assert got == [want] * 6
+        assert hits("miss") - miss == 2    # k and q, put by the one that came first

@@ -14,6 +14,19 @@ No host round-trip between stages: stage outputs never leave HBM, the exchange
 rides ICI, and XLA overlaps the collectives with compute — the role Trino's
 pull/ack HTTP streams play between JVM workers (DirectExchangeClient.java:270).
 
+Where a table's shards live: the runner keeps them over the mesh between
+statements (`_ShardStore`, one per `MeshQueryRunner`): each column a statement
+scans, and the table's `active`, padded to the shards' capacity and placed
+along the mesh axis once per VERSION of its table (`cachestore.table_version`,
+read before and after the load), so the next statement assembles its scan
+pages from arrays that are there already and moves nothing. What drops them:
+a version token that has changed (any write: checked at every statement,
+before anything is put), the byte budget (least recently used tables beyond
+`_STORE_SHARE` of a device's memory) and a put that ends in
+RESOURCE_EXHAUSTED. A connector that gives no token, or answers BYPASS, is
+resharded on every statement. Data is kept where the program reads it; no
+answer is kept. The program donates none of its inputs.
+
 Static-shape discipline: every page between two stages is sized by what it
 holds. Each fragment runs on runtime/adaptive.py's narrowing executor: per
 shard, a filter's, scan's or aggregation's output is compacted to its hint, a
@@ -32,6 +45,8 @@ from __future__ import annotations
 
 import hashlib
 import math
+import threading
+from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -50,7 +65,7 @@ from ..planner.fragmenter import (
 )
 from ..planner.plan import LogicalPlan, OutputNode, PlanNode, TableScanNode, visit_plan
 from ..planner.stats import StatsEstimator
-from ..runtime import capstore, kernelcost
+from ..runtime import cachestore, capstore, kernelcost
 from ..runtime.adaptive import (
     _AdaptiveTracedExecutor,
     candidate_nodes,
@@ -78,20 +93,15 @@ class MeshLoweringError(Exception):
     """Plan cannot lower to a single shard_map program (host syncs needed)."""
 
 
-def _pad_page(page: Page, capacity: int) -> Page:
-    if page.capacity == capacity:
-        return page
-    pad = capacity - page.capacity
-    cols = tuple(
-        Column(
-            c.type,
-            jnp.pad(c.data, [(0, pad)] + [(0, 0)] * (c.data.ndim - 1)),
-            jnp.pad(c.valid, (0, pad)),
-            c.dictionary,
-        )
-        for c in page.columns
+def _pad_column(c: Column, pad: int) -> Column:
+    if not pad:
+        return c
+    return Column(
+        c.type,
+        jnp.pad(c.data, [(0, pad)] + [(0, 0)] * (c.data.ndim - 1)),
+        jnp.pad(c.valid, (0, pad)),
+        c.dictionary,
     )
-    return Page(cols, jnp.pad(page.active, (0, pad)))
 
 
 @dataclass
@@ -109,6 +119,11 @@ _REPLICATED = (Partitioning.SINGLE, Partitioning.COORDINATOR_ONLY)
 _MAX_ATTEMPTS = 6
 # the estimator's margin (plan_capacities' own), over a shard's even share
 _SEED_MARGIN = 2.0
+# the share of a device's memory (its allocator's `bytes_limit`) that the
+# tables' shards kept between statements may take; a backend that reports no
+# limit (the CPU's) is reckoned as one v5e chip's 16 GiB
+_STORE_SHARE = 0.25
+_UNREPORTED_BYTES_LIMIT = 16 << 30
 
 ATTEMPTS_COUNTER = "trino_tpu_mesh_program_attempts_total"
 ATTEMPTS_HELP = (
@@ -120,6 +135,112 @@ RETRIES_HELP = (
     "mesh tier programs rebuilt because a narrowing point or an exchange "
     "bucket overflowed"
 )
+COLUMNS_COUNTER = "trino_tpu_mesh_shard_columns_total"
+COLUMNS_HELP = (
+    "columns of the mesh tier's scans by where their shards came from: hit "
+    "(on the mesh since an earlier statement), miss (padded and put by this "
+    "one), stale (dropped because their table's version token had changed)"
+)
+
+
+def _count_columns(result: str, columns: int) -> None:
+    if columns:
+        REGISTRY.counter(
+            COLUMNS_COUNTER, {"result": result}, help=COLUMNS_HELP
+        ).inc(columns)
+
+
+def _table_token(metadata, handle) -> Optional[str]:
+    """The version token of the table a scan reads, as the warm-path caches
+    read it (`cachestore.table_version`: equal tokens imply equal data), or
+    None where nothing may be kept by it: a connector that gives none, or one
+    that answers BYPASS."""
+    pin = handle.connector_handle
+    pinned = str(pin["snapshot_id"]) if isinstance(pin, dict) and "snapshot_id" in pin else None
+    name = handle.schema_table
+    token = cachestore.table_version(metadata, handle.catalog, name.schema, name.table, pinned)
+    return None if token == cachestore.BYPASS else token
+
+
+def _column_bytes(column: Column) -> int:
+    """`page_bytes`' count of one column."""
+    return page_bytes(Page((column,), np.empty(0, bool)))
+
+
+@dataclass
+class _TableShards:
+    """One version of one table as it lies on the mesh: its `active` and
+    every column a statement has scanned so far, each padded to
+    ``per_shard * n`` rows and placed with ``P(axis)``."""
+
+    connector: object  # held, so that its id stays its own
+    handle: object  # the scan's handle as resolved: the version is read by it
+    token: str
+    rows: int  # the capacity of the scan's page as loaded, before padding
+    active: jax.Array
+    columns: Dict[int, Column] = field(default_factory=dict)  # by the table's column index
+    loaded_bytes: Dict[int, int] = field(default_factory=dict)  # a column as loaded, unpadded
+    device_bytes: int = 0  # what one device holds of `active` and `columns`
+
+
+class _ShardStore:
+    """The tables' shards that stay on the mesh between statements, so that a
+    column is resharded once per version of its table and not once per
+    statement. Keyed by what decides the bytes: the connector instance and
+    the scan's handle as resolved (the owner's mesh and the per-shard
+    capacity follow from them), each entry held to the version token it was
+    loaded under. Least recently used tables go once a device's share
+    (``budget``) is passed. One lock: the statement that misses a column puts
+    it while the others wait, and then find it."""
+
+    def __init__(self, mesh):
+        self.lock = threading.Lock()
+        self.tables: "OrderedDict[tuple, _TableShards]" = OrderedDict()  # oldest use first
+        limits = [(d.memory_stats() or {}).get("bytes_limit") for d in mesh.devices.flat]
+        self.budget = int(_STORE_SHARE * min(b or _UNREPORTED_BYTES_LIMIT for b in limits))
+        self._n = mesh.devices.size
+
+    def device_bytes(self) -> int:
+        return sum(t.device_bytes for t in self.tables.values())
+
+    def drop_stale(self, metadata) -> None:
+        """Drop every table whose version token is no longer the one its
+        shards were loaded under (INSERT, DELETE / UPDATE / MERGE, DROP,
+        CREATE and CTAS all advance it)."""
+        for key, table in list(self.tables.items()):
+            if _table_token(metadata, table.handle) != table.token:
+                self._drop_stale(key)
+
+    def _drop_stale(self, key) -> None:
+        _count_columns("stale", len(self.tables.pop(key).columns))
+
+    def find(self, key, token) -> Optional[_TableShards]:
+        """The table kept under ``key``, now the most recently used one, if
+        ``token`` is still the one it was loaded under; else it is dropped."""
+        table = self.tables.get(key)
+        if table is None:
+            return None
+        if table.token != token:
+            self._drop_stale(key)
+            return None
+        self.tables.move_to_end(key)
+        return table
+
+    def keep(self, key, table: _TableShards, columns: Dict[int, Column], loaded_bytes) -> None:
+        """Add ``columns`` to ``table`` under ``key``, then let the least
+        recently used other tables go while a device holds more than the
+        budget; a table that passes it alone is not kept."""
+        table.columns.update(columns)
+        table.loaded_bytes.update(loaded_bytes)
+        leaves = jax.tree_util.tree_leaves((table.active, tuple(table.columns.values())))
+        table.device_bytes = sum(x.nbytes for x in leaves) // self._n
+        self.tables[key] = table
+        self.tables.move_to_end(key)
+        while self.device_bytes() > self.budget:
+            self.tables.popitem(last=False)
+
+    def clear(self) -> None:
+        self.tables.clear()
 
 
 class _MeshFragmentExecutor(_AdaptiveTracedExecutor):
@@ -270,6 +391,9 @@ class MeshQueryRunner:
         # repeated queries reuse the XLA executable (the PageFunctionCompiler
         # cache discipline applied to whole multi-fragment programs)
         self._program_cache: Dict[tuple, object] = {}
+        # the scanned tables' shards, kept over the mesh between statements
+        self._shards = _ShardStore(self.mesh)
+        self._sharding = NamedSharding(self.mesh, P(self.axis))
 
     @staticmethod
     def tpch(scale: float = 0.01, n_devices: Optional[int] = None, **kw):
@@ -487,12 +611,15 @@ class MeshQueryRunner:
                 )
 
     def _shard_scans(self, subplan: SubPlan):
-        """Load every fragment's scans as mesh-sharded global pages (splits ->
+        """Every fragment's scans as mesh-sharded global pages (splits ->
         shards), with per-column dictionaries unified BEFORE sharding so the
-        static dictionary aux is identical on every shard."""
+        static dictionary aux is identical on every shard. The shards of a
+        table that gives a version token stay on the mesh (`_ShardStore`):
+        what a token no longer vouches for is dropped before anything is put."""
         scan_specs: List[_ScanSpec] = []
         scan_counts: Dict[int, int] = {}
-        sharding = NamedSharding(self.mesh, P(self.axis))
+        with self._shards.lock:
+            self._shards.drop_stale(self.metadata)
         for frag in subplan.fragments:
             scans: List[TableScanNode] = []
 
@@ -503,37 +630,104 @@ class MeshQueryRunner:
             visit_plan(frag.root, collect)
             scan_counts[frag.fragment_id] = len(scans)
             for node in scans:
-                with TRACER.span(
-                    "mesh:load_scan", table=str(node.table.schema_table)
-                ) as loaded:
-                    page = self._load_scan(node)
-                    # the splits' pages concatenated on device 0
-                    loaded.attributes["rows"] = page.capacity
-                    loaded.attributes["bytes"] = page_bytes(page)
-                per_shard = _round_capacity(
-                    max(math.ceil(page.capacity / self.n), 1), base=8
-                )
-                # awaited here: the program then starts on every device at
-                # once, with no collective left waiting for a shard still on
-                # its way, and the span covers the transfer it names
-                with TRACER.span("mesh:shard") as sharding_span:
-                    padded = _pad_page(page, per_shard * self.n)
-                    sharded = jax.block_until_ready(jax.device_put(padded, sharding))
-                    sharding_span.attributes["h2d_bytes"] = page_bytes(padded)
                 symbols = tuple(s for s, _ in node.assignments)
-                scan_specs.append(_ScanSpec(frag.fragment_id, sharded, symbols))
+                scan_specs.append(_ScanSpec(frag.fragment_id, self._shard_scan(node), symbols))
         return scan_specs, scan_counts
 
-    def _load_scan(self, node: TableScanNode) -> Page:
+    def _shard_scan(self, node: TableScanNode) -> Page:
+        """One scan's page over the mesh (spans `mesh:load_scan`, then
+        `mesh:shard`), assembled from the columns the store holds and the
+        ones this statement loads on device 0, pads and puts, one column at a
+        time. What it puts is kept where the table's version token reads the
+        same before and after the load (the mixed-snapshot guard of
+        `cachestore.resolve_versions`); a statement whose tokens differ, or
+        whose connector gives none, uses what it loaded and keeps nothing."""
+        connector, handle, col_indexes = self._resolve_scan(node)
+        wanted = list(dict.fromkeys(col_indexes))
+        token = _table_token(self.metadata, handle)
+        key = None
+        if token is not None:
+            try:
+                hash(handle)
+                key = (id(connector), handle)
+            except TypeError:  # a handle that cannot key a dictionary
+                pass
+        with self._shards.lock:
+            kept = self._shards.find(key, token) if key is not None else None
+            with TRACER.span(
+                "mesh:load_scan", table=str(node.table.schema_table)
+            ) as loaded:
+                missing = [i for i in wanted if kept is None or i not in kept.columns]
+                page = None
+                if missing or kept is None:
+                    # the splits' pages concatenated on device 0
+                    page = self._load_columns(connector, handle, missing)
+                    consistent = kept is None or page.capacity == kept.rows
+                    if key is not None and (
+                        not consistent or _table_token(self.metadata, handle) != token
+                    ):
+                        # written under the load, or packed another way than
+                        # the columns kept: nothing kept is used, nothing is kept
+                        key = None
+                        if kept is not None:
+                            kept, missing = None, wanted
+                            page = self._load_columns(connector, handle, wanted)
+                fresh = dict(zip(missing, page.columns)) if page is not None else {}
+                fresh_bytes = {i: _column_bytes(c) for i, c in fresh.items()}
+                rows = page.capacity if kept is None else kept.rows
+                cached = len(wanted) - len(fresh)
+                loaded.attributes.update(
+                    rows=rows, cached=cached,
+                    bytes=rows + sum(
+                        fresh_bytes[i] if i in fresh else kept.loaded_bytes[i] for i in wanted
+                    ),
+                )
+            per_shard = _round_capacity(max(math.ceil(rows / self.n), 1), base=8)
+            # awaited here: the program then starts on every device at
+            # once, with no collective left waiting for a shard still on
+            # its way, and the span covers the transfer it names
+            with TRACER.span("mesh:shard") as sharding_span:
+                pad = per_shard * self.n - rows
+                put = {i: self._put(_pad_column(c, pad)) for i, c in fresh.items()}
+                moved = sum(_column_bytes(c) for c in put.values())
+                new = kept is None
+                if new:
+                    active = self._put(jnp.pad(page.active, (0, pad)))
+                    kept = _TableShards(connector, handle, token, rows, active)
+                    moved += active.size
+                columns = {**kept.columns, **put}
+                if key is not None and (put or new):
+                    self._shards.keep(key, kept, put, fresh_bytes)
+                sharding_span.attributes.update(h2d_bytes=moved, cached=cached, put=len(put))
+            _count_columns("hit", cached)
+            _count_columns("miss", len(put))
+        return Page(tuple(columns[i] for i in col_indexes), kept.active)
+
+    def _put(self, tree):
+        """``tree`` over the mesh axis, waited for; a put that ends in
+        RESOURCE_EXHAUSTED empties the store and is tried once more."""
+        try:
+            return jax.block_until_ready(jax.device_put(tree, self._sharding))
+        except jax.errors.JaxRuntimeError as e:
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            self._shards.clear()
+            return jax.block_until_ready(jax.device_put(tree, self._sharding))
+
+    def _resolve_scan(self, node: TableScanNode):
+        """(the connector, the handle with the scan's constraint applied, the
+        table's index of each column the scan assigns)."""
         connector = self.metadata.connector_for(node.table)
         handle = node.table
         if node.constraint.domains:
             absorbed = self.metadata.apply_filter(handle, node.constraint)
             if absorbed is not None:
                 handle = absorbed
-        splits = connector.split_manager().get_splits(handle)
         meta = self.metadata.get_table_metadata(node.table)
-        col_indexes = [meta.column_index(c) for _, c in node.assignments]
+        return connector, handle, [meta.column_index(c) for _, c in node.assignments]
+
+    def _load_columns(self, connector, handle, col_indexes) -> Page:
+        splits = connector.split_manager().get_splits(handle)
         provider = connector.page_source_provider()
         pages = _load_splits(provider, splits, col_indexes, self.session)
         if not pages:
@@ -541,6 +735,10 @@ class MeshQueryRunner:
             # mesh program's scan layout uniform instead of special-casing
             raise MeshLoweringError("empty scan (fully pruned) on mesh path")
         return _concat_scan_pages(pages)
+
+    def _load_scan(self, node: TableScanNode) -> Page:
+        connector, handle, col_indexes = self._resolve_scan(node)
+        return self._load_columns(connector, handle, col_indexes)
 
     @staticmethod
     def _points(subplan: SubPlan) -> List[PlanNode]:
