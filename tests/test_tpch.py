@@ -535,12 +535,17 @@ def test_q16(runner):
         WHERE p_partkey = ps_partkey AND p_brand <> 'Brand#45'
           AND p_type NOT LIKE 'MEDIUM POLISHED%'
           AND p_size IN (49, 14, 23, 45, 19, 3, 36, 9)
+          AND ps_suppkey NOT IN (SELECT s_suppkey FROM supplier
+                                 WHERE s_comment LIKE '%Customer%Complaints%')
         GROUP BY p_brand, p_type, p_size
         ORDER BY supplier_cnt DESC, p_brand, p_type, p_size
         """
     )
     ps = tpch_df("partsupp", SCALE)
     p = tpch_df("part", SCALE)
+    s = tpch_df("supplier", SCALE)
+    complained = s[s.s_comment.str.contains("Customer.*Complaints", regex=True)].s_suppkey
+    ps = ps[~ps.ps_suppkey.isin(complained)]
     pf = p[
         (p.p_brand != "Brand#45")
         & ~p.p_type.str.startswith("MEDIUM POLISHED")

@@ -315,6 +315,22 @@ def fold_constant_call(name: str, args: Sequence[Constant], out_type: Type) -> O
 # --------------------------------------------------------------------------- #
 
 
+def _exact_comparison_type(a, b) -> Optional[DecimalType]:
+    """The type two decimals of different scales are compared in where the
+    short common type would lose digits: the common super type stays at 18
+    digits while both sides are short (`spi/types.py`), and casting the side
+    of the smaller scale up to it wraps in int64 once its integer digits do
+    not fit (Q11's `sum(ps_supplycost * ps_availqty) > total * 0.0000333333`,
+    decimal(18,2) against decimal(18,12), dropped every part worth 9.2M or
+    more). A comparison yields a boolean, so the Int128 operands live only
+    inside it. None where the short common type is exact."""
+    if not (isinstance(a, DecimalType) and isinstance(b, DecimalType)) or a.scale == b.scale:
+        return None
+    scale = max(a.scale, b.scale)
+    precision = max(a.precision - a.scale, b.precision - b.scale) + scale
+    return decimal_type(min(precision, 38), scale) if precision > 18 else None
+
+
 class ExpressionTranslator:
     """ref: sql/analyzer/ExpressionAnalyzer.java + planner TranslationMap."""
 
@@ -531,7 +547,11 @@ class ExpressionTranslator:
             t.ComparisonOp.GREATER_THAN_OR_EQUAL: "$gte",
             t.ComparisonOp.IS_DISTINCT_FROM: "$distinct_from",
         }[e.op]
-        left, right = self._coerce_pair(left, right, f"comparison {name}")
+        wide = _exact_comparison_type(left.type, right.type)
+        if wide is not None:
+            left, right = self._cast_to(left, wide), self._cast_to(right, wide)
+        else:
+            left, right = self._coerce_pair(left, right, f"comparison {name}")
         return self._call(name, [left, right], BOOLEAN)
 
     def _coerce_pair(self, left: IrExpr, right: IrExpr, what: str):
@@ -1225,7 +1245,7 @@ class LogicalPlanner:
         self.metadata = metadata
         self.session = session
         self.symbols = SymbolAllocator()
-        self._cte: Dict[str, t.Query] = {}
+        self._cte: Dict[str, t.WithQuery] = {}
         # sub-queries this planner rewrote to joins (the `planner` span's ``decorrelated``)
         self.decorrelated = 0
 
@@ -1263,9 +1283,7 @@ class LogicalPlanner:
         saved_cte = dict(self._cte)
         try:
             for wq in query.with_queries:
-                if wq.column_names:
-                    raise SemanticError("WITH column aliases not supported yet")
-                self._cte[wq.name] = wq.query
+                self._cte[wq.name] = wq
             rel = self._plan_query_body(query.body, parent_scope)
             if query.order_by or query.limit is not None or query.offset:
                 rel = self._apply_order_limit(
@@ -1859,8 +1877,15 @@ class LogicalPlanner:
     def _plan_table(self, rel: t.Table, parent_scope) -> RelationPlan:
         name = rel.name
         if len(name.parts) == 1 and name.parts[0] in self._cte:
-            inner = self.plan_query(self._cte[name.parts[0]], parent_scope)
-            fields = [replace(f, qualifier=name.parts[0]) for f in inner.fields]
+            wq = self._cte[name.parts[0]]
+            inner = self.plan_query(wq.query, parent_scope)
+            names = list(wq.column_names) or [f.name for f in inner.fields]
+            if len(names) != len(inner.fields):
+                raise SemanticError(
+                    f"WITH query {wq.name} has {len(inner.fields)} columns "
+                    f"but {len(names)} column aliases"
+                )
+            fields = [replace(f, name=n, qualifier=wq.name) for f, n in zip(inner.fields, names)]
             return RelationPlan(inner.node, fields)
         # view expansion (ref: StatementAnalyzer.Visitor.visitTable's
         # analyzeView path): a stored view is re-parsed and planned inline

@@ -343,6 +343,12 @@ GROUP_ROWS_HELP = (
     "capacity), by path: direct, presorted, sort, global"
 )
 
+DISTINCT_COUNTER = "trino_tpu_distinct_aggregations_total"
+DISTINCT_HELP = (
+    "aggregations with a DISTINCT argument, each run as a dedup on the group "
+    "keys and the column, then the aggregation over the dedup"
+)
+
 
 def _note(**attributes) -> None:
     """Attributes on the operator's span (``op:<PlanNode>``, the current one)."""
@@ -1336,6 +1342,9 @@ class PlanExecutor:
             step=AggregationStep.SINGLE,
         )
         deduped = aggregate_relation(rel, dedup_node, self.types, self._pallas_mode())
+        # the dedup's own numbers, before the aggregations after it note theirs
+        _note(distinct=dcol, distinct_rows_in=_rows_or_capacity(rel), distinct_groups=deduped.rows)
+        REGISTRY.counter(DISTINCT_COUNTER, help=DISTINCT_HELP).inc()
         dist_part = AggregationNode(
             source=node.source,  # unused
             group_keys=node.group_keys,

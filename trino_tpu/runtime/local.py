@@ -831,7 +831,11 @@ class LocalQueryRunner:
                             names, page.to_pylist(),
                             [c.type for c in page.columns],
                         )
-                        encoded.attributes["rows"] = len(result.rows)
+                        # the rows sent beside the padded page walked for them
+                        encoded.attributes.update(
+                            rows=len(result.rows), capacity=page.capacity,
+                            columns=len(page.columns),
+                        )
                     result.trace_id = root.trace_id
                     root.attributes["rows"] = len(result.rows)
                     if executor.fragment_cache_hits and cache_tier is None:

@@ -550,8 +550,10 @@ class Page:
     def to_pylist(self) -> list:
         """Host materialization: list of row tuples in storage order (active only)."""
         active = np.asarray(self.active)
-        cols = [c.decode(active) for c in self.columns]
-        return [tuple(col[i] for col in cols) for i in range(int(active.sum()))]
+        if not self.columns:
+            return [()] * int(active.sum())
+        # each column decodes to a 1-D array of python objects: zip their lists
+        return list(zip(*(c.decode(active).tolist() for c in self.columns)))
 
 
 def _column_layout(c: Column) -> tuple:
