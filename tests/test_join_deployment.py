@@ -289,8 +289,8 @@ def test_semijoin_mask_is_membership_among_the_active_builds(case):
 
 def _gather_zoo(rng, n: int) -> list:
     """A 64-bit integer, a double, a boolean, an 8-bit and a 32-bit integer, a
-    float, a two-dimensional array that rides along, and more flags than one
-    word holds."""
+    float, a two-dimensional array, and more flags than one word holds; the
+    double and the two-dimensional array ride along beside the matrix."""
     return [
         rng.integers(-(2**62), 2**62, size=n), rng.normal(size=n), rng.random(n) < 0.5,
         rng.integers(-100, 100, size=n).astype(np.int8), rng.integers(0, 9, size=n).astype(np.int32),
@@ -304,8 +304,8 @@ def _gathers(lowered_text: str) -> list:
 
 
 GATHER_N = 65536
-ZOO_SHAPE = (48, 9)  # the zoo's one-dimensional arrays: 48 gathers of their own, or 9 words
-# the fewest rows moved at which the zoo travels packed: about one row in 2,500 of 65,536
+ZOO_SHAPE = (46, 7)  # the zoo's packable arrays (all but the double): 46 gathers of their own, or 7 words
+# the fewest rows moved at which the zoo travels packed: about one row in 3,100 of 65,536
 ZOO_CROSSOVER = next(m for m in range(1, GATHER_N) if K.gather_form(GATHER_N, m, *ZOO_SHAPE) == "packed")
 
 
@@ -325,10 +325,10 @@ def test_gather_rows_is_a_gather_of_each_array(m, order):
     moved = program(*args)
     for got, a in zip(moved, arrays):
         assert got.dtype == a.dtype and np.array_equal(np.asarray(got), a[idx])
-    # the rule is the program: the matrix and the array that rides along, or a gather an array
+    # the rule is the program: the matrix and the two arrays that ride along, or a gather an array
     form = K.gather_form(GATHER_N, m, *ZOO_SHAPE)
     assert form == ("plain" if m < ZOO_CROSSOVER else "packed")
-    assert len(_gathers(program.lower(*args).as_text())) == (2 if form == "packed" else len(arrays))
+    assert len(_gathers(program.lower(*args).as_text())) == (3 if form == "packed" else len(arrays))
 
 
 def test_each_form_alone_is_a_gather_of_each_array():
@@ -378,7 +378,8 @@ def test_gather_shape_counts_gathers_and_words():
     assert K.gather_shape(q14) == (11, 8)
     assert K.gather_shape(q14 + [S((64, 2), jnp.int64)]) == (11, 8)  # limbs are rows already
     assert K.gather_shape([S((64,), jnp.bool_)] * 33) == (33, 2)
-    assert K.gather_shape([S((64,), jnp.int8), S((64,), jnp.float64)]) == (3, 3)
+    # a double is gathered as it is in either form: the TPU's compiler cannot bitcast it
+    assert K.gather_shape([S((64,), jnp.int8), S((64,), jnp.float64)]) == (1, 1)
     assert K.gather_shape([]) == (0, 0)
 
 

@@ -155,3 +155,18 @@ def test_grouping_lineitem_by_the_key_it_is_stored_by(one_chip):
         (_column(BIGINT, rows, one_chip, jnp.int64), _column(BIGINT, rows, one_chip, jnp.int64)),
         jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip))
     assert _compile_seconds(E._jit_presorted_group, ("k",), ("k", "v"), ("k", "v"), page) < BUDGET_S
+
+
+@pytest.mark.parametrize("rows", [16, 1_048_576])
+def test_a_page_holding_a_double_is_sorted(one_chip, rows):
+    """TPC-H Q8's answer: (o_year, mkt_share) ORDER BY o_year, where
+    mkt_share is a double, in its 16 slots and at a million. Packed into 32-bit
+    words, the double's bitcast was refused (UNIMPLEMENTED: "While rewriting
+    computation to not contain X64 element types"); it is gathered as it is."""
+    from trino_tpu.planner.plan import Ordering
+    from trino_tpu.spi.types import DOUBLE
+
+    page = Page(
+        (_column(BIGINT, rows, one_chip, jnp.int64), _column(DOUBLE, rows, one_chip, jnp.float64)),
+        jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip))
+    assert _compile_seconds(E._jit_sort, (Ordering("y"),), ("y", "share"), None, page) < BUDGET_S

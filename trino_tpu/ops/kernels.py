@@ -296,7 +296,9 @@ def gather_form(n: int, m: int, gathers: int, words: int) -> str:
 
 
 def _packable(a) -> bool:
-    return a.ndim == 1 and a.dtype.itemsize <= 8
+    # a double is gathered as it is: the TPU emulates it, and its compiler
+    # cannot bitcast it into the two words a 64-bit integer makes
+    return a.ndim == 1 and a.dtype.itemsize <= 8 and a.dtype != jnp.float64
 
 
 def gather_shape(arrays: Sequence) -> Tuple[int, int]:

@@ -663,7 +663,7 @@ def plan_fragments(sql: str, metadata, session, max_parts: Optional[int] = None)
         planner = LogicalPlanner(metadata, session)
         plan = planner.plan(stmt)
         planning.attributes["decorrelated"] = planner.decorrelated
-    with TRACER.span("optimizer", root=False):
+    with TRACER.span("optimizer", root=False, derived_predicates=0):
         plan = optimize(plan, metadata, session)
     with TRACER.span("fragment", root=False) as fragmenting:
         subplan = create_fragments(add_exchanges(plan, metadata, session))

@@ -6,6 +6,8 @@ highest-leverage subset as whole-plan passes:
 
 - merge_projections     (rule/InlineProjections + removeRedundantIdentityProjections)
 - merge_filters         (rule/MergeFilters)
+- derive_join_disjuncts (an OR across a join implies an OR of each side's own
+                         conjuncts, pushed below the join beside the original)
 - simplify_predicates   (IR constant simplification)
 - pushdown_predicates   (optimizations/PredicatePushDown.java — through Project,
                          Filter into TableScan constraint via TupleDomain extraction)
@@ -77,6 +79,7 @@ def optimizer_passes(metadata: Metadata, types: Dict[str, Type], session: Sessio
         ("merge_projections", merge_projections),
         ("merge_filters", merge_filters),
         ("extract_common_predicates", extract_common_predicates),
+        ("derive_join_disjuncts", derive_join_disjuncts),
         ("eliminate_cross_joins",
          lambda r: eliminate_cross_joins(r, metadata, types, session)),
         ("pushdown_predicates", lambda r: pushdown_predicates(r, types)),
@@ -268,18 +271,18 @@ def merge_filters(root: PlanNode) -> PlanNode:
 # --------------------------------------------------------------------------- #
 
 
+def _or_terms(e: IrExpr) -> List[IrExpr]:
+    if isinstance(e, Call) and e.name == "$or":
+        return _or_terms(e.args[0]) + _or_terms(e.args[1])
+    return [e]
+
+
 def _factor_or(expr: IrExpr) -> IrExpr:
     if isinstance(expr, Call) and expr.name == "$and":
         return combine_conjuncts([_factor_or(c) for c in split_conjuncts(expr)])
     if not (isinstance(expr, Call) and expr.name == "$or"):
         return expr
-
-    def or_terms(e: IrExpr) -> List[IrExpr]:
-        if isinstance(e, Call) and e.name == "$or":
-            return or_terms(e.args[0]) + or_terms(e.args[1])
-        return [e]
-
-    branches = [split_conjuncts(_factor_or(b)) for b in or_terms(expr)]
+    branches = [split_conjuncts(_factor_or(b)) for b in _or_terms(expr)]
     common = [c for c in branches[0] if all(c in b for b in branches[1:])]
     if not common:
         return expr
@@ -300,6 +303,90 @@ def extract_common_predicates(root: PlanNode) -> PlanNode:
         return node
 
     return rewrite_plan(root, fn)
+
+
+# --------------------------------------------------------------------------- #
+# disjunctions across a join: from (A1 AND B1) OR (A2 AND B2) over a join
+# whose left side the A's reference and whose right side the B's do,
+# (A1 OR A2) and (B1 OR B2) follow, also under three-valued logic: where the
+# OR is TRUE, a branch is, and so are its A and its B. The derived predicates
+# go below the join and the original stays where it is. TPC-H Q7's nation
+# pair and Q19's three part classes.
+# --------------------------------------------------------------------------- #
+
+DERIVED_PREDICATES_COUNTER = "trino_tpu_derived_predicates_total"
+
+
+def _disjunct_over(branches: List[List[IrExpr]], syms: Set[str]) -> Optional[IrExpr]:
+    """The OR, over `branches`, of each branch's conjuncts that reference
+    `syms` alone; None where some branch has no such conjunct."""
+    parts = []
+    for branch in branches:
+        own = [c for c in branch if references(c) and references(c) <= syms]
+        if not own:
+            return None
+        parts.append(combine_conjuncts(own))
+    out = parts[0]
+    for p in parts[1:]:
+        out = Call("$or", (out, p), BOOLEAN)
+    return out
+
+
+def _derive_below(c: IrExpr, node: PlanNode, out: List[IrExpr]) -> None:
+    """Appends to `out` what the disjunction `c` implies of each side of the
+    INNER or CROSS join `node` where it spans both, and recursively below.
+    An outer join is left alone: its null-supplying side takes nothing."""
+    if not (isinstance(node, JoinNode) and node.kind in (JoinKind.INNER, JoinKind.CROSS)):
+        return
+    refs = references(c)
+    for side in (node.left, node.right):
+        if refs <= set(side.output_symbols):
+            _derive_below(c, side, out)
+            return
+    branches = [split_conjuncts(b) for b in _or_terms(c)]
+    for side in (node.left, node.right):
+        derived = _disjunct_over(branches, set(side.output_symbols))
+        if derived is not None and derived not in out:
+            out.append(derived)
+            _derive_below(derived, side, out)
+
+
+def derive_join_disjuncts(root: PlanNode) -> PlanNode:
+    """Adds to a filter over a join the disjunctions its ORs across the join
+    imply of each side (`_derive_below`); `pushdown_predicates` then takes each
+    to the smallest input it references, and the join order sees them."""
+
+    def fn(node: PlanNode) -> PlanNode:
+        if not (isinstance(node, FilterNode) and isinstance(node.source, JoinNode)):
+            return node
+        conjuncts = split_conjuncts(node.predicate)
+        derived: List[IrExpr] = []
+        for c in conjuncts:
+            if isinstance(c, Call) and c.name == "$or":
+                _derive_below(c, node.source, derived)
+        derived = [d for d in derived if d not in conjuncts]
+        if not derived:
+            return node
+        _note_derived(len(derived))
+        return replace(node, predicate=combine_conjuncts(conjuncts + derived))
+
+    return rewrite_plan(root, fn)
+
+
+def _note_derived(n: int) -> None:
+    """`trino_tpu_derived_predicates_total` += n, and the `optimizer` span's
+    `derived_predicates` where one is current."""
+    from ..runtime.metrics import REGISTRY  # the runtime package imports the planner
+    from ..runtime.tracing import TRACER
+
+    REGISTRY.counter(
+        DERIVED_PREDICATES_COUNTER,
+        help="predicates the optimizer derived for one side of a join from an "
+             "OR across it (derive_join_disjuncts)",
+    ).inc(n)
+    span = TRACER.current()
+    if span is not None and span.name == "optimizer":
+        span.attributes["derived_predicates"] = span.attributes.get("derived_predicates", 0) + n
 
 
 # --------------------------------------------------------------------------- #

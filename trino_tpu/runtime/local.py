@@ -724,7 +724,7 @@ class LocalQueryRunner:
                                 )
                                 p = planner.plan(stmt)
                                 planning.attributes["decorrelated"] = planner.decorrelated
-                            with TRACER.span("optimizer"):
+                            with TRACER.span("optimizer", derived_predicates=0):
                                 return optimize(
                                     p, self.metadata, self.session
                                 )
