@@ -200,7 +200,7 @@ class TestMegakernelEdgeCases:
             )
             cap = E._round_capacity(max(int(jnp.sum(emit)), 1))
             want = E._jit_join_expand(
-                cap, emit, count, lo, perm_b, probe_page, build_page
+                cap, False, emit, count, lo, perm_b, probe_page, build_page
             )
             pr = MK.probe_phase(pkeys, bkeys, luts, pa, ba, False, True)
             assert pr is not None, (n, m)

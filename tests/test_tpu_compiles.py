@@ -5,6 +5,7 @@ file; the topology is described inside a fixture and nothing touches the TPU's
 library while a module is imported. A compile that passes is not a chip run."""
 
 import os
+import re
 import time
 
 import jax
@@ -170,3 +171,51 @@ def test_a_page_holding_a_double_is_sorted(one_chip, rows):
         (_column(BIGINT, rows, one_chip, jnp.int64), _column(DOUBLE, rows, one_chip, jnp.float64)),
         jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip))
     assert _compile_seconds(E._jit_sort, (Ordering("y"),), ("y", "share"), None, page) < BUDGET_S
+
+
+def _scatter_updates(text: str) -> list:
+    """The number of updates of every scatter in an optimized HLO text: the
+    length of its last operand, looked up where that operand is defined."""
+    shapes = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", text))
+    updates = []
+    for operands in re.findall(r" scatter\(([^)]*)\)", text):
+        dims = shapes[operands.split(",")[-1].strip().lstrip("%")]
+        updates.append(int(dims.split(",")[0]) if dims else 1)
+    return updates
+
+
+@pytest.mark.parametrize(
+    "probe,build,out,unique",
+    [
+        # Q3: `lineitem` x `orders`, 93,314 rows out in 131,072 slots at SF3: the walk of `live_indices`
+        pytest.param(16_777_216, 524_288, 131_072, True, id="q3_unique"),
+        # Q5: `lineitem` x `supplier`, 3.6M rows out: `live_indices`' sort of the positions
+        pytest.param(18_874_368, 8_192, 4_194_304, True, id="q5_unique"),
+        # the witness: the general form at Q3's shape scatters over every probe row
+        pytest.param(16_777_216, 524_288, 131_072, False, id="q3_general"),
+    ],
+)
+def test_a_unique_expansion_scatters_nothing_over_the_probe(one_chip, probe, build, out, unique):
+    """`_jit_join_expand` at the join cell's shapes: in the unique form the
+    slots are the emitting rows in order (`K.unique_slots`), and no scatter's
+    updates number the probe's rows; the general form's `expand_probe_slots`
+    makes one update for every probe row (its `.at[start].max`)."""
+
+    def ints(rows):
+        return jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+
+    probe_page = Page(
+        (_column(BIGINT, probe, one_chip, jnp.int64), _column(decimal_type(12, 2), probe, one_chip, jnp.int64),
+         _column(decimal_type(12, 2), probe, one_chip, jnp.int64)),
+        jax.ShapeDtypeStruct((probe,), jnp.bool_, sharding=one_chip))
+    build_page = Page(
+        (_column(BIGINT, build, one_chip, jnp.int64), _column(DATE, build, one_chip, jnp.int32)),
+        jax.ShapeDtypeStruct((build,), jnp.bool_, sharding=one_chip))
+    start = time.perf_counter()
+    compiled = E._jit_join_expand.lower(
+        out, unique, ints(probe), ints(probe), ints(probe), ints(build), probe_page, build_page
+    ).compile()
+    assert time.perf_counter() - start < BUDGET_S
+    updates = _scatter_updates(compiled.as_text())
+    # the walk of `live_indices` scatters its row starts, one update for 256 rows
+    assert (probe not in updates) if unique else (probe in updates)

@@ -1030,18 +1030,45 @@ def expand_probe_slots(emit: jnp.ndarray, out_capacity: int):
     return probe_idx, d, out_active, total
 
 
+def unique_slots(emit: jnp.ndarray, out_capacity: int):
+    """``expand_probe_slots`` where no probe row emits more than one row: the
+    output is the emitting rows in row order, so slot p holds the p-th of
+    them (``live_indices``: a walk over the mask where the slots are few, one
+    sort of the positions where they are not). No prefix sum of ``emit`` and
+    no scatter over the probe rows, whose cost grows with the probe's
+    capacity and not with the rows emitted. Returns (probe_idx, out_active);
+    a slot past the rows emitted holds the last probe row."""
+    n = emit.shape[0]
+    idx = live_indices(emit > 0, out_capacity)
+    return jnp.minimum(idx, n - 1), idx < n
+
+
+def unique_build_rows(lo_at, count_at, perm_b, out_active):
+    """(build_pos, matched) of a unique expansion's slots from ``lo`` and
+    ``match_count`` read at each slot's probe row: the slot is that row's one
+    emission, its first match (d = 0) or, where the row matched nothing (a
+    LEFT join's null-padded row), no match."""
+    build_pos = perm_b[jnp.clip(lo_at, 0, perm_b.shape[0] - 1)]
+    return build_pos, out_active & (count_at > 0)
+
+
 def expand_matches(
     emit: jnp.ndarray,
     match_count: jnp.ndarray,
     lo: jnp.ndarray,
     perm_b: jnp.ndarray,
     out_capacity: int,
+    *,
+    unique: bool = False,
 ):
     """Rank-space expansion of 1:N matches into a static output.
 
     ``emit[i]``: output slots probe row i produces (0 for inactive rows; for a
     left outer join, 1 for active-but-unmatched rows). ``match_count[i]``: how
     many of those slots are real matches (the rest are null-padded).
+    ``unique`` (static): the caller knows that no ``emit[i]`` exceeds 1, and
+    the slots are found by ``unique_slots``; every active slot gets what the
+    general form gives it.
 
     Returns (probe_idx, build_pos, matched, out_active, total):
     - probe_idx[p]: probe row for output slot p
@@ -1054,6 +1081,11 @@ def expand_matches(
     zero-emit rows share their successor's start and are never selected within
     [0, total).
     """
+    if unique:
+        probe_idx, out_active = unique_slots(emit, out_capacity)
+        lo_at, count_at = gather_rows([lo, match_count], probe_idx)
+        build_pos, matched = unique_build_rows(lo_at, count_at, perm_b, out_active)
+        return probe_idx, build_pos, matched, out_active, jnp.sum(emit)
     probe_idx, d, out_active, total = expand_probe_slots(emit, out_capacity)
     matched = d < match_count[probe_idx]
     build_sorted_pos = jnp.clip(lo[probe_idx] + d, 0, perm_b.shape[0] - 1)

@@ -269,7 +269,7 @@ class _AdaptiveTracedExecutor(_TracedExecutor):
         finally:
             self._join_key = prev
 
-    def _choose_join_capacity(self, emit, probe_cap: int, build_cap: int, totals=None) -> int:
+    def _choose_join_capacity(self, emit, probe_cap: int, build_cap: int, totals=None):
         key = self._join_key
         hint = self.capacities.get(key) if key is not None else None
         unhinted = _round_capacity(max(int(probe_cap * self.join_capacity_factor), 1))
@@ -280,7 +280,7 @@ class _AdaptiveTracedExecutor(_TracedExecutor):
         # every join) so the tuner can grow ANY overflowing join — an
         # unkeyed overflow could never converge
         self._record(key, ovf, actual, cap, unhinted)
-        return cap
+        return cap, None  # nothing read: the expansion keeps its general form
 
 
 def candidate_nodes(plan: LogicalPlan, extra: tuple = ()) -> List[PlanNode]:

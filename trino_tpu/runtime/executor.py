@@ -337,6 +337,11 @@ JOINS_HELP = (
     "joins executed, by kind as executed: INNER, LEFT (a RIGHT join runs as a LEFT "
     "one with its sides swapped), FULL, CROSS"
 )
+JOIN_EXPANSIONS_COUNTER = "trino_tpu_join_expansions_total"
+JOIN_EXPANSIONS_HELP = (
+    "join expansions, by form: unique (no probe row emits more than one row, the "
+    "slots are the emitting rows in order) or general (a scatter over the probe rows)"
+)
 GROUP_ROWS_COUNTER = "trino_tpu_group_rows_total"
 GROUP_ROWS_HELP = (
     "rows that entered an aggregation (live rows where counted, else the page's "
@@ -486,21 +491,23 @@ class PlanExecutor:
     # may happen mid-plan — everything stays inside one XLA program.
     allow_host_sync = True
 
-    def _choose_join_capacity(self, emit, probe_cap: int, build_cap: int, totals=None) -> int:
+    def _choose_join_capacity(self, emit, probe_cap: int, build_cap: int, totals=None):
         """Join output capacity: host-sync the exact emitted row count (the
         operator-at-a-time model; traced executors override with a static
-        bound + overflow accounting). ``totals`` is an outer join's (rows
-        emitted, matches among them), which ``_jit_join_match`` summed: the
-        same one read carries both, and the rows emitted beyond the matches
-        are the probe rows no build row matched (``unmatched_rows``)."""
+        bound + overflow accounting). ``totals`` is (rows emitted, matches
+        among them, most rows one probe row emits), which ``_jit_join_match``
+        computed: the one read carries all three. Returns (capacity, what was
+        read): the three, or None where nothing but the rows emitted was
+        read (the fused join's ``emit``) or nothing at all (traced)."""
         if totals is None:
+            read = None
             total = _sync_int(jnp.sum(emit), "join_capacity")
         else:
-            total, matched = _sync_ints(totals, "join_capacity")
-            _note(unmatched_rows=total - matched)
+            read = _sync_ints(totals, "join_capacity")
+            total = read[0]
         _note(rows_out=total)
         _count_join_rows(out=total)
-        return _round_capacity(max(total, 1))
+        return _round_capacity(max(total, 1)), read
 
     def __init__(
         self,
@@ -1083,7 +1090,7 @@ class PlanExecutor:
             )
             if pr is None:
                 return None  # bucket skew; fallback already ticked
-            out_capacity = self._choose_join_capacity(
+            out_capacity, _ = self._choose_join_capacity(
                 pr["emit"], probe.capacity, build.capacity
             )
             epi_spec = self._epilogue_spec_for(out_symbols)
@@ -1186,7 +1193,7 @@ class PlanExecutor:
             )
             if pr is None:
                 return serial_finish()
-            out_capacity = self._choose_join_capacity(
+            out_capacity, _ = self._choose_join_capacity(
                 pr["emit"], probe.capacity, build.capacity
             )
             # fold the intermediate projection into the kernel: the same
@@ -1531,11 +1538,18 @@ class PlanExecutor:
             left_outer, pkeys, bkeys, luts, probe.page.active, build.page.active,
             key_bits, key_bases,
         )
-        out_capacity = self._choose_join_capacity(emit, probe.capacity, build.capacity, totals)
+        out_capacity, read = self._choose_join_capacity(emit, probe.capacity, build.capacity, totals)
+        if read is not None and left_outer:
+            # the probe rows no build row matched: rows emitted beyond the matches
+            _note(unmatched_rows=read[0] - read[1])
+        # no probe row emits more than one row (a key unique on the build
+        # side, or a LEFT join's rows matching one build row or none): the
+        # expansion's slots are the emitting rows in order
+        unique = read is not None and read[2] <= 1
         page = _jit_join_expand(
-            out_capacity, emit, count, lo, perm_b, probe.page, build.page
+            out_capacity, unique, emit, count, lo, perm_b, probe.page, build.page
         )
-        rows_out = self._note_join(node, probe, build, out_capacity, key_bits)
+        rows_out = self._note_join(node, probe, build, out_capacity, key_bits, unique)
 
         if kind == JoinKind.FULL:
             # append unmatched build rows with a null probe side (the join is
@@ -1567,6 +1581,7 @@ class PlanExecutor:
                     fn,
                     out.symbols,
                     out_capacity,
+                    unique,
                     emit,
                     count,
                     lo,
@@ -1613,17 +1628,19 @@ class PlanExecutor:
         return tuple(bits), tuple(bases)
 
     def _note_join(self, node, probe: Relation, build: Relation, out_capacity: int,
-                   key_bits=None):
+                   key_bits=None, unique: bool = False):
         """What the join moved, on its span and in the counters; every value
-        is on the host already. Returns the rows emitted where
+        is on the host already. ``unique``: the expansion took the form in
+        which each probe row emits one row at most. Returns the rows emitted where
         ``_choose_join_capacity`` counted them (``sync:join_capacity``), else
         None; a FULL join's tail and a residual filter come after it."""
         keys = [probe.column_for(l) for l, _ in node.criteria]
+        form = "unique" if unique else "general"
         _note(
             kind=node.kind.name, key_words=_join_key_words(node, probe, build, key_bits),
             probe_rows=_rows_or_capacity(probe), build_rows=_rows_or_capacity(build),
             probe_capacity=probe.capacity, build_capacity=build.capacity,
-            capacity_out=out_capacity, key_types=[c.type.display() for c in keys],
+            capacity_out=out_capacity, expand=form, key_types=[c.type.display() for c in keys],
             key_bits=None if key_bits is None else list(key_bits),
             probe_types=_type_counts(probe.page.columns),
             build_types=_type_counts(build.page.columns), sort_passes=_sort_passes(1),
@@ -1631,6 +1648,7 @@ class PlanExecutor:
         )
         _count_join_rows(probe=_rows_or_capacity(probe), build=_rows_or_capacity(build))
         REGISTRY.counter(JOINS_COUNTER, {"kind": node.kind.name}, help=JOINS_HELP).inc()
+        REGISTRY.counter(JOIN_EXPANSIONS_COUNTER, {"form": form}, help=JOIN_EXPANSIONS_HELP).inc()
         if node.kind == JoinKind.FULL or node.filter is not None:
             return None
         span = TRACER.current()
@@ -3475,33 +3493,56 @@ def _jit_join_match(
     pa = probe_active & probe_valid
     ba = build_active & build_valid
     perm_b, lo, hi, count = K.join_match(build_key, ba, probe_key, pa, key_bits)
-    if not left_outer:
-        return count, count, lo, perm_b, None
-    emit = jnp.where(probe_active, jnp.maximum(count, 1), 0)
-    # (rows emitted, matches among them): the rest are the probe rows no build
-    # row matched, and the one read that sizes the output carries both
-    totals = jnp.stack([jnp.sum(emit.astype(jnp.int64)), jnp.sum(count.astype(jnp.int64))])
+    emit = jnp.where(probe_active, jnp.maximum(count, 1), 0) if left_outer else count
+    # (rows emitted, matches among them, most rows one probe row emits): the
+    # one read that sizes the output carries all three; an outer join's rows
+    # emitted beyond its matches are the probe rows no build row matched
+    totals = jnp.stack([
+        jnp.sum(emit.astype(jnp.int64)), jnp.sum(count.astype(jnp.int64)),
+        jnp.max(emit).astype(jnp.int64),
+    ])
     return emit, count, lo, perm_b, totals
 
 
-@partial(kernelcost.jit, static_argnums=(0,))
-def _jit_join_expand(
-    out_capacity: int, emit, count, lo, perm_b, probe_page: Page, build_page: Page
-) -> Page:
-    probe_idx, build_pos, matched, out_active, _ = K.expand_matches(
-        emit, count, lo, perm_b, out_capacity
-    )
-    cols = list(_permute_columns(probe_page.columns, probe_idx)[0])
+def _expand_join(out_capacity: int, unique: bool, emit, count, lo, perm_b,
+                 probe_page: Page, build_page: Page):
+    """The expansion's columns, probe side then build side (a null-padded
+    slot's build columns null), and (probe_idx, matched, out_active) of its
+    slots (``K.expand_matches``). In the ``unique`` form ``lo`` and ``count``
+    ride the probe columns' one gather to the slots."""
+    if unique:
+        probe_idx, out_active = K.unique_slots(emit, out_capacity)
+        probe_cols, (lo_at, count_at) = _permute_columns(
+            probe_page.columns, probe_idx, extra=(lo, count)
+        )
+        build_pos, matched = K.unique_build_rows(lo_at, count_at, perm_b, out_active)
+    else:
+        probe_idx, build_pos, matched, out_active, _ = K.expand_matches(
+            emit, count, lo, perm_b, out_capacity
+        )
+        probe_cols, _ = _permute_columns(probe_page.columns, probe_idx)
+    cols = list(probe_cols)
     for pc in _permute_columns(build_page.columns, build_pos)[0]:
         cols.append(replace(pc, valid=pc.valid & matched))
+    return cols, probe_idx, matched, out_active
+
+
+@partial(kernelcost.jit, static_argnums=(0, 1))
+def _jit_join_expand(
+    out_capacity: int, unique: bool, emit, count, lo, perm_b, probe_page: Page, build_page: Page
+) -> Page:
+    cols, _, _, out_active = _expand_join(
+        out_capacity, unique, emit, count, lo, perm_b, probe_page, build_page
+    )
     return Page(tuple(cols), out_active)
 
 
-@partial(kernelcost.jit, static_argnums=(0, 1, 2))
+@partial(kernelcost.jit, static_argnums=(0, 1, 2, 3))
 def _jit_left_join_residual(
     residual_fn,
     symbols: Tuple[str, ...],
     out_capacity: int,
+    unique: bool,
     emit,
     count,
     lo,
@@ -3512,12 +3553,9 @@ def _jit_left_join_residual(
     """LEFT JOIN with an ON residual: filter the expanded matches, then append
     one null-padded row for every probe row whose matches all failed (including
     rows that never matched — their placeholder also fails the residual)."""
-    probe_idx, build_pos, matched, out_active, _ = K.expand_matches(
-        emit, count, lo, perm_b, out_capacity
+    cols, probe_idx, matched, out_active = _expand_join(
+        out_capacity, unique, emit, count, lo, perm_b, probe_page, build_page
     )
-    cols = list(_permute_columns(probe_page.columns, probe_idx)[0])
-    for pc in _permute_columns(build_page.columns, build_pos)[0]:
-        cols.append(replace(pc, valid=pc.valid & matched))
     env = {s: _cval_of(c) for s, c in zip(symbols, cols)}
     v = residual_fn(env)
     keep = out_active & matched & v.valid & v.data.astype(jnp.bool_)

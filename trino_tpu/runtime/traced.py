@@ -107,12 +107,12 @@ class _TracedExecutor(PlanExecutor):
         self.join_capacity_factor = join_capacity_factor
         self.overflows: List[jnp.ndarray] = []
 
-    def _choose_join_capacity(self, emit, probe_cap: int, build_cap: int, totals=None) -> int:
+    def _choose_join_capacity(self, emit, probe_cap: int, build_cap: int, totals=None):
         cap = _round_capacity(max(int(probe_cap * self.join_capacity_factor), 1))
         self.overflows.append(
             jnp.maximum(jnp.sum(emit).astype(jnp.int64) - cap, 0)
         )
-        return cap
+        return cap, None  # nothing read: the expansion keeps its general form
 
     def _choose_group_capacity(self, node, num_groups, in_cap: int) -> int:
         """Static capacity of a grouped aggregation's output and of the
