@@ -219,3 +219,44 @@ def test_a_unique_expansion_scatters_nothing_over_the_probe(one_chip, probe, bui
     updates = _scatter_updates(compiled.as_text())
     # the walk of `live_indices` scatters its row starts, one update for 256 rows
     assert (probe not in updates) if unique else (probe in updates)
+
+
+def _sort_rows_of(text: str) -> list:
+    """The rows of every sort in an optimized HLO text (its first operand's length)."""
+    return [int(r) for r in re.findall(r"= \(?[su]\d+\[(\d+)\][^=]*? sort\(", text)]
+
+
+@pytest.mark.parametrize(
+    "probe,build,out,slots,unique",
+    [
+        # Q3: 93,314 of `lineitem`'s 16,777,216 probe rows emit against 524,288 orders
+        pytest.param(16_777_216, 524_288, 131_072, 131_072, True, id="q3_emitting"),
+        # Q12: `orders` x `lineitem`, one-to-many, the general form over the listed orders
+        pytest.param(5_242_880, 16_777_216, 524_288, 262_144, False, id="q12_emitting"),
+    ],
+)
+def test_the_ways_back_compile_at_the_cells_shapes(one_chip, probe, build, out, slots, unique):
+    """`_jit_join_expand` taking the ranks from the merged order in the
+    emitting form (`RanksWay`): no sort holds the n + m merged rows and no
+    scatter's updates number the probe's rows (the merged form sorts them as
+    `_jit_join_match` does: `test_join_match_of_one_bigint_key`)."""
+
+    def ints(rows):
+        return jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+
+    probe_page = Page(
+        (_column(BIGINT, probe, one_chip, jnp.int64), _column(decimal_type(12, 2), probe, one_chip, jnp.int64)),
+        jax.ShapeDtypeStruct((probe,), jnp.bool_, sharding=one_chip))
+    build_page = Page(
+        (_column(BIGINT, build, one_chip, jnp.int64), _column(DATE, build, one_chip, jnp.int32)),
+        jax.ShapeDtypeStruct((build,), jnp.bool_, sharding=one_chip))
+    merged = ints(probe + build)
+    start = time.perf_counter()
+    compiled = E._jit_join_expand.lower(
+        out, unique, merged, merged, merged, ints(build), probe_page, build_page, merged,
+        E.RanksWay("emitting", False, slots),
+    ).compile()
+    assert time.perf_counter() - start < BUDGET_S
+    text = compiled.as_text()
+    sorts = _sort_rows_of(text)
+    assert slots in sorts and probe + build not in sorts and probe not in _scatter_updates(text)

@@ -4,6 +4,7 @@ with no scatter over the probe rows. The kernel against the general form on
 every active slot, and the executor's choice between the forms, made on the
 one `sync:join_capacity` read, against a plain reference."""
 
+import time
 from collections import Counter
 
 import jax
@@ -162,3 +163,76 @@ def test_both_forms_make_the_same_reads(runner):
         syncs[dim] = spans[0].attributes["host_syncs"]
     assert reads["dim_pk"] == reads["dim_dup"] and reads["dim_pk"].count("sync:join_capacity") == 1
     assert syncs["dim_pk"] == syncs["dim_dup"] == len(reads["dim_pk"])
+
+
+# ------------------------------------------ how the ranks came back, served
+
+
+@pytest.fixture(scope="module")
+def client(runner):
+    from trino_tpu.client.client import StatementClient
+    from trino_tpu.server import CoordinatorServer
+
+    server = CoordinatorServer(runner).start()
+    yield StatementClient(f"http://{server.address}")
+    server.stop()
+
+
+def _finished(query_id, timeout=5.0):
+    """The statement's spans once its root has closed (just after the last page)."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        tree = TRACER.spans(query_id)
+        if tree and tree[0].end_ns is not None:
+            return tree
+        time.sleep(0.005)
+    raise AssertionError(f"the root of {query_id} never closed")
+
+
+@pytest.mark.parametrize("dim,kind", [("dim_pk", "JOIN"), ("dim_dup", "JOIN"), ("dim_part", "LEFT JOIN")])
+def test_a_served_join_states_how_its_ranks_came_back(runner, client, dim, kind):
+    forms = ("emitting", "merged")
+    before = {f: REGISTRY.counter(E.JOIN_RANK_FORMS_COUNTER, {"form": f}).value for f in forms}
+    res = client.execute(f"SELECT f.id, d.name FROM memory.default.fact f {kind} memory.default.{dim} d ON f.k = d.k")
+    (join,) = [s.attributes for s in _finished(res.query_id) if s.name == "op:JoinNode"]
+    # E, the probe rows that emit: each emits one row in the unique form, a LEFT join's live rows all emit
+    emitting = join["emitting_rows"]
+    assert 1 <= emitting <= join["rows_out"] and (join["expand"] == "general" or emitting == join["rows_out"])
+    if kind == "LEFT JOIN":
+        assert emitting == len(_rows(runner, "fact"))
+    way = E._ranks_way(kind == "LEFT JOIN", join["probe_capacity"], join["build_capacity"],
+                       join["expand"] == "unique", [join["rows_out"], 0, 0, emitting])
+    assert join["ranks"] == way.form
+    for f, value in before.items():
+        assert REGISTRY.counter(E.JOIN_RANK_FORMS_COUNTER, {"form": f}).value - value == (f == way.form)
+
+
+def test_the_mesh_tier_s_join_programs_keep_the_match_s_own_way_back(monkeypatch):
+    """The traced executors read nothing: the mesh tier's programs call
+    `_jit_join_match` and `_jit_join_expand` as they did before the match
+    could stop at the merge (no `merged`, no `RanksWay`), so their text is
+    unchanged, and none of the merged order's kernels is traced."""
+    from trino_tpu.parallel.mesh_runner import MeshQueryRunner
+
+    calls = {}
+    for name in ("_jit_join_match", "_jit_join_expand"):
+        jitted = getattr(E, name)
+
+        def spy(*args, _real=jitted._jit, _name=name, **kwargs):
+            calls.setdefault(_name, []).append(len(args) + len(kwargs))
+            return _real(*args, **kwargs)
+
+        monkeypatch.setattr(jitted, "_jit", spy)
+    for kernel in ("join_merge", "emitting_ranks", "merged_ranks", "expand_listed"):
+        monkeypatch.setattr(K, kernel, lambda *a, _k=kernel, **k: pytest.fail(f"K.{_k} traced"))
+    mesh = MeshQueryRunner.tpch(scale=0.001, n_devices=4)
+    subplan = mesh.plan_distributed(
+        "SELECT sum(l_extendedprice), count(*) FROM lineitem JOIN part ON l_partkey = p_partkey "
+        "WHERE p_type LIKE 'PROMO%'"
+    )
+    specs, counts = mesh._shard_scans(subplan)
+    program = mesh._build_program(subplan, counts, [None] * len(mesh._points(subplan)), 1.0)
+    program.fn.lower(*[s.page for s in specs])
+    # the match with its eight arguments, the expansion with its eight: the parent's calls
+    assert calls["_jit_join_match"] and set(calls["_jit_join_match"]) == {8}
+    assert calls["_jit_join_expand"] and set(calls["_jit_join_expand"]) == {8}

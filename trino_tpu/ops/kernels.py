@@ -878,19 +878,34 @@ def join_keys(
     return [d for d, _ in probe_cols], p_valid, [d for d, _ in build_cols], b_valid
 
 
-def _merge_match(build_keys, build_active, probe_keys, probe_active, key_bits):
+# A row's class in the merged order, where the tag carries one
+# (``_merge_match`` given ``probe_live``): nothing (an inactive build, a probe
+# row no longer live), an active build, a probe row whose key can match, a live
+# probe row whose key cannot (null, or outside the narrowed range: a LEFT join
+# still emits one null-padded row for it).
+BUILD, PROBE_KEYED, PROBE_KEYLESS = 1, 2, 3
+
+
+def _merge_match(build_keys, build_active, probe_keys, probe_active, key_bits, probe_live=None):
     """The one merge of a match: every build row and every probe row once,
-    n + m rows. Returns, in merged order, (position, is_build, lo, count):
-    the row's place in [builds, probes], whether it is an active build, and,
-    for a probe's row, how many active builds hold a smaller key and how many
-    hold its own."""
+    n + m rows. Returns, in merged order, (position, class, lo, count): the
+    row's place in [builds, probes], 1 where it is an active build, and, for
+    a probe's row, how many active builds hold a smaller key and how many hold
+    its own. Where ``probe_live`` (the probe's live rows, whatever their key)
+    is given, the tag carries two bits of the row's class (``BUILD``,
+    ``PROBE_KEYED``, ``PROBE_KEYLESS``; 0 for the rest) in place of one bit of
+    is_build, so that the expansion knows each probe row's part without a
+    gather over the n + m rows."""
     if not isinstance(build_keys, (list, tuple)):
         build_keys, probe_keys = [build_keys], [probe_keys]
     n = probe_active.shape[0]
     m = build_active.shape[0]
     total = n + m
-    if total >= 1 << 30:
-        raise ValueError(f"join of {n} probe and {m} build rows: the tag needs n + m < 2**30")
+    tag_bits = 1 if probe_live is None else 2
+    if total >= 1 << (31 - tag_bits):
+        raise ValueError(
+            f"join of {n} probe and {m} build rows: the tag needs n + m < 2**{31 - tag_bits}"
+        )
     fields = []
     for i, (bk, pk) in enumerate(zip(build_keys, probe_keys)):
         bits = key_bits[i] if key_bits is not None else None
@@ -906,12 +921,21 @@ def _merge_match(build_keys, build_active, probe_keys, probe_active, key_bits):
         bk = jnp.where(build_active, bk, jnp.zeros((), bk.dtype))
         fields.append(order_field(jnp.concatenate([bk, pk])))
     pos = jnp.arange(total, dtype=jnp.int32)
-    is_build = jnp.concatenate([build_active.astype(jnp.int32), jnp.zeros(n, jnp.int32)])
+    builds = build_active.astype(jnp.int32)
+    if probe_live is None:
+        probes = jnp.zeros(n, jnp.int32)
+    else:
+        probes = jnp.where(probe_active, PROBE_KEYED, jnp.where(probe_live, PROBE_KEYLESS, 0)).astype(jnp.int32)
+    classes = jnp.concatenate([builds, probes])
     words = sort_words(fields)[::-1]  # most significant first, as lax.sort compares
     *s_words, s_tag = jax.lax.sort(
-        (*words, pos * 2 + is_build), num_keys=len(words) + 1, is_stable=False
+        (*words, pos * (1 << tag_bits) + classes), num_keys=len(words) + 1, is_stable=False
     )
-    s_is_build = s_tag & 1
+    if probe_live is None:
+        s_class = s_is_build = s_tag & 1
+    else:
+        s_class = s_tag & 3
+        s_is_build = (s_class == BUILD).astype(jnp.int32)
     # the builds stand before the probes, so a probe stands behind every build
     # of its key: the builds before it are those at or below its key (hi)
     builds_before = cumsum(s_is_build) - s_is_build  # exclusive
@@ -924,15 +948,20 @@ def _merge_match(build_keys, build_active, probe_keys, probe_active, key_bits):
         differs = differs | (w[1:] != w[:-1])
     run_starts = jnp.concatenate([jnp.ones((1,), jnp.bool_), differs])
     lo = cummax(jnp.where(run_starts, builds_before, 0))
-    return s_tag >> 1, s_is_build, lo, builds_before - lo
+    return s_tag >> tag_bits, s_class, lo, builds_before - lo
 
 
 def _ranks_back(s_pos, m: int, n: int, payloads):
-    """``payloads`` of the merged order's probe rows, in the probe's order: one
-    sort by the probe's row number, which is distinct; the builds sort behind
-    the probes. A sort and not a scatter: the TPU's scatter sorts its indices
-    itself, with more operands."""
-    qid = jnp.where(s_pos >= m, s_pos - m, n)
+    """``payloads`` of the merged order's probe rows, in the probe's order."""
+    return _by_probe_row(jnp.where(s_pos >= m, s_pos - m, n), n, payloads)
+
+
+def _by_probe_row(qid, n: int, payloads):
+    """``payloads`` of the merged order's rows whose probe row number ``qid``
+    is below ``n``, in the probe's order: one sort by that number, which is
+    distinct; the builds (``qid`` = n) sort behind the probes. A sort and not
+    a scatter: the TPU's scatter sorts its indices itself, with more
+    operands."""
     return [p[:n] for p in jax.lax.sort((qid, *payloads), num_keys=1, is_stable=False)[1:]]
 
 
@@ -941,6 +970,23 @@ def rank_words(m: int) -> int:
     and ``count`` lie in [0, m], and where two such fit 32 bits they share a
     word."""
     return 1 if 2 * m.bit_length() <= 32 else 2
+
+
+def _pack_ranks(lo, count, m: int) -> list:
+    """``lo`` and ``count`` as the payload words they travel in (``rank_words``)."""
+    if rank_words(m) == 1:
+        shift = m.bit_length()  # unsigned: at m = 65,535 the word is full
+        return [(lo.astype(jnp.uint32) << shift) | count.astype(jnp.uint32)]
+    return [lo, count]
+
+
+def _unpack_ranks(words, m: int):
+    """(lo, count) from the words ``_pack_ranks`` made."""
+    if rank_words(m) == 1:
+        shift = m.bit_length()
+        (packed,) = words
+        return (packed >> shift).astype(jnp.int32), (packed & jnp.uint32((1 << shift) - 1)).astype(jnp.int32)
+    return tuple(words)
 
 
 def join_match(build_keys, build_active, probe_keys, probe_active, key_bits=None):
@@ -976,24 +1022,162 @@ def join_match(build_keys, build_active, probe_keys, probe_active, key_bits=None
     s_pos, s_is_build, s_lo, s_count = _merge_match(
         build_keys, build_active, probe_keys, probe_active, key_bits
     )
-    if rank_words(m) == 1:
-        shift = m.bit_length()  # unsigned: at m = 65,535 the word is full
-        packed = (s_lo.astype(jnp.uint32) << shift) | s_count.astype(jnp.uint32)
-        (packed,) = _ranks_back(s_pos, m, n, [packed])
-        lo = (packed >> shift).astype(jnp.int32)
-        count = (packed & jnp.uint32((1 << shift) - 1)).astype(jnp.int32)
-    else:
-        lo, count = _ranks_back(s_pos, m, n, [s_lo, s_count])
+    lo, count = _unpack_ranks(_ranks_back(s_pos, m, n, _pack_ranks(s_lo, s_count, m)), m)
     count = jnp.where(probe_active, count, 0)
-    # the builds in sorted order ARE perm_b: where the r-th active build stands
-    # in the merged order, read off the mask (``live_indices``: a walk over it
-    # where the builds are few among the probes, a sort of the positions
-    # where they are not). Slots past the active builds hold row 0: nothing
-    # matches there.
+    return _builds_in_order(s_pos, s_is_build, n, m), lo, lo + count, count
+
+
+def _builds_in_order(s_pos, s_is_build, n: int, m: int):
+    """``perm_b``: the builds in sorted order ARE it, so where the r-th active
+    build stands in the merged order is read off the mask (``live_indices``: a
+    walk over it where the builds are few among the probes, a sort of the
+    positions where they are not). Slots past the active builds hold row 0:
+    nothing matches there."""
     total = n + m
     at = live_indices(s_is_build == 1, m)
-    perm_b = jnp.where(at < total, s_pos[jnp.minimum(at, total - 1)], 0)
-    return perm_b, lo, lo + count, count
+    return jnp.where(at < total, s_pos[jnp.minimum(at, total - 1)], 0)
+
+
+def join_merge(build_keys, build_active, probe_keys, probe_active, probe_live, key_bits=None):
+    """``join_match`` that stops at the merge: the ranks stay in the merged
+    order, and the way back to the probe's order is the expansion's
+    (``emitting_ranks`` or ``merged_ranks``, by ``ranks_form``). Returns
+    (perm_b, qid, lo, count, live), the last four over the n + m merged rows:
+    the probe row number (n on a build row), ``lo`` and ``count`` as
+    ``join_match`` gives them (``count`` 0 where the probe row is not
+    active), and whether the row is a live probe row (``probe_live``; a LEFT
+    join emits for each). One sort, the merge, and ``perm_b``'s walk."""
+    n = probe_active.shape[0]
+    m = build_active.shape[0]
+    s_pos, s_class, s_lo, s_count = _merge_match(
+        build_keys, build_active, probe_keys, probe_active, key_bits, probe_live
+    )
+    qid = jnp.where(s_pos >= m, s_pos - m, n)
+    count = jnp.where(s_class == PROBE_KEYED, s_count, 0)
+    perm_b = _builds_in_order(s_pos, (s_class == BUILD).astype(jnp.int32), n, m)
+    return perm_b, qid, s_lo, count, s_class >= PROBE_KEYED
+
+
+# What the two ways back from the merged order cost on a v5e, read by
+# tools/ranks_probe.py (its part `ways`) on merged orders of the join
+# cells' shapes, ms:
+#
+#   merged_ranks      n + m = 17,301,504 (two rank words) 56.70; 22,020,096 (two) 66.55;
+#                     18,882,560 (one) 47.45
+#   emitting_ranks    n + m = 17,301,504, slots 32,768 / 262,144 / 1,048,576 (the walk)
+#                     5.90 / 15.75 / 42.75; slots 2,097,152 / 4,194,304 (the sort) 55.96 / 96.12
+#   expand_probe_slots  over a probe of 5,242,880 rows 47.44; over 262,144 listed rows 3.07
+#
+# A merged row costs 2.5 ns sorted with one rank word and 3.0 to 3.75 with two
+# (the whole expansion's `%sort` at Q3's shape: 64.9 ms). Listing
+# the emitting rows costs 0.27 ns a merged row and 17 ns a slot where
+# `live_indices` walks, 0.92 ns a merged row where it sorts the positions (a
+# top-k of `slots`), and then 19 ns a listed row (the gather, the sort of the
+# list). The general expansion's scatter costs 9 ns an update. The whole
+# `_jit_join_expand` (one bigint a side; the probe's part `programs`),
+# emitting against merged: Q3's shape 22.0 against 76.8 ms with
+# 131,072 rows emitting, 92.6 against 123.3 with 1,048,576; Q13's LEFT join
+# (524,288 x 5,242,880, every customer emits) 290.3 against 363.0; Q5's
+# (18,874,368 x 8,192, 3.6M emit) 218.2 against 197.5. The rule below picks
+# the cheaper form at all six.
+_MERGED_ROW_NS = {1: 2.5, 2: 3.4}   # a merged row, sorted by the probe row number with its rank words
+_MASK_ROW_NS = 0.27                 # a row of live_indices' mask, where it walks
+_WALK_SLOT_NS = 17.0                # a slot of live_indices' walk
+_POSITIONS_ROW_NS = 0.92            # a row of live_indices' mask, where it sorts the positions
+_LISTED_SLOT_NS = 19.0              # a listed row: gathered, then sorted by the probe row number
+_SCATTER_UPDATE_NS = 9.0            # an update of expand_probe_slots' scatter
+
+
+def _finding_ns(rows: int, slots: int) -> float:
+    """What ``live_indices`` costs to find ``slots`` entries in a mask of ``rows``."""
+    if slots * LIVE_INDEX_SHARE > rows:
+        return rows * _POSITIONS_ROW_NS
+    return rows * _MASK_ROW_NS + slots * _WALK_SLOT_NS
+
+
+def ranks_form(merged_rows: int, probe_rows: int, slots: int, words: int, unique: bool) -> str:
+    """How a join's ranks reach its expansion from the merged order:
+    ``emitting`` (``emitting_ranks``: the probe rows that emit, found in the
+    merged order and sorted alone, ``slots`` of them) or ``merged``
+    (``merged_ranks``: a sort of every merged row by the probe's row number),
+    whichever costs less on a v5e by the constants above. ``words`` is
+    ``rank_words``. After the merged way the unique expansion finds its
+    slots among the probe's rows (``unique_slots``) and the general one
+    scatters one update a probe row; after the emitting way the slots are the
+    list, and the general expansion scatters one update a listed row. Static
+    shapes only, as ``gather_form``."""
+    merged = merged_rows * _MERGED_ROW_NS[words]
+    emitting = _finding_ns(merged_rows, slots) + slots * _LISTED_SLOT_NS
+    if unique:
+        merged += _finding_ns(probe_rows, slots)
+    else:
+        merged += probe_rows * _SCATTER_UPDATE_NS
+        emitting += slots * _SCATTER_UPDATE_NS
+    return "emitting" if emitting < merged else "merged"
+
+
+def emitting_ranks(qid, lo, count, emit, m: int, slots: int):
+    """The probe rows that emit, in the probe's order, from the merged order
+    (``join_merge``): their merged positions by ``live_indices`` over
+    ``emit > 0`` (a walk where they are few), (qid, lo, count) gathered there
+    in one ``gather_rows``, and a sort of those ``slots`` entries alone by
+    the probe row number, ``lo`` and ``count`` in one word where
+    ``rank_words`` allows. The probe's last row is listed whether it emits or
+    not: a slot past the rows emitted takes it, as ``expand_probe_slots``'
+    do. Returns (qid, lo, count) of the ``slots`` entries; past the listed
+    rows ``qid`` is n."""
+    total = qid.shape[0]
+    n = total - m
+    at = live_indices((emit > 0) | (qid == n - 1), slots)
+    listed = at < total
+    e_qid, e_lo, e_count = gather_rows([qid, lo, count], jnp.minimum(at, total - 1))
+    e_qid = jnp.where(listed, e_qid, n)
+    e_qid, *words = jax.lax.sort((e_qid, *_pack_ranks(e_lo, e_count, m)), num_keys=1, is_stable=False)
+    return (e_qid, *_unpack_ranks(words, m))
+
+
+def merged_ranks(qid, lo, count, m: int):
+    """(lo, count) of every probe row, in the probe's order, from the merged
+    order (``join_merge``): one sort of the n + m rows by the probe row
+    number that carries them (``join_match``'s way back)."""
+    n = qid.shape[0] - m
+    return _unpack_ranks(_by_probe_row(qid, n, _pack_ranks(lo, count, m)), m)
+
+
+def expand_listed(e_qid, e_lo, e_count, n: int, last_live, perm_b, out_capacity: int, *,
+                  unique: bool, left_outer: bool):
+    """``expand_matches`` over the list ``emitting_ranks`` gives, for a probe
+    of ``n`` rows: the same (probe_idx, build_pos, matched, out_active,
+    total), slot for slot, with no work over the probe's rows. ``last_live``:
+    whether the probe's last row is live (a LEFT join emits for it). A slot
+    past the rows emitted is the general form's: the probe's last row holds
+    it, with that row's ``lo`` and ``count`` and ``d`` running on from that
+    row's start."""
+    is_last = e_qid == n - 1
+    if left_outer:  # every listed row is live, but for the last row perhaps
+        e_emit = jnp.where((e_qid < n) & (last_live | ~is_last), jnp.maximum(e_count, 1), 0)
+    else:
+        e_emit = jnp.where(e_qid < n, e_count, 0)
+    last_lo, last_count, last_emit = (jnp.sum(jnp.where(is_last, a, 0)) for a in (e_lo, e_count, e_emit))
+    if unique:
+        if e_qid.shape[0] < out_capacity:
+            raise ValueError(f"{e_qid.shape[0]} listed rows for {out_capacity} slots")
+        total = jnp.sum(e_emit)
+        out_active = jnp.arange(out_capacity) < total
+        probe_idx = jnp.where(out_active, e_qid[:out_capacity], n - 1)
+        lo_at = jnp.where(out_active, e_lo[:out_capacity], last_lo)
+        count_at = jnp.where(out_active, e_count[:out_capacity], last_count)
+        build_pos, matched = unique_build_rows(lo_at, count_at, perm_b, out_active)
+        return probe_idx, build_pos, matched, out_active, total
+    entry, d, out_active, total = expand_probe_slots(e_emit, out_capacity)
+    q_at, lo_at, count_at = gather_rows([e_qid, e_lo, e_count], entry)
+    probe_idx = jnp.where(out_active, q_at, n - 1)
+    lo_at = jnp.where(out_active, lo_at, last_lo)
+    count_at = jnp.where(out_active, count_at, last_count)
+    d = jnp.where(out_active, d, jnp.arange(out_capacity) - total + last_emit)
+    matched = d < count_at
+    build_pos = perm_b[jnp.clip(lo_at + d, 0, perm_b.shape[0] - 1)]
+    return probe_idx, build_pos, matched, out_active, total
 
 
 def expand_probe_slots(emit: jnp.ndarray, out_capacity: int):

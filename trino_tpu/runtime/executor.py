@@ -22,7 +22,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, replace
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -342,6 +342,11 @@ JOIN_EXPANSIONS_HELP = (
     "join expansions, by form: unique (no probe row emits more than one row, the "
     "slots are the emitting rows in order) or general (a scatter over the probe rows)"
 )
+JOIN_RANK_FORMS_COUNTER = "trino_tpu_join_rank_forms_total"
+JOIN_RANK_FORMS_HELP = (
+    "joins by the way their ranks came back from the match's merged order: emitting "
+    "(the probe rows that emit, sorted alone) or merged (a sort of every merged row)"
+)
 GROUP_ROWS_COUNTER = "trino_tpu_group_rows_total"
 GROUP_ROWS_HELP = (
     "rows that entered an aggregation (live rows where counted, else the page's "
@@ -374,8 +379,8 @@ def _count_join_rows(**sides: int) -> None:
 
 def _join_key_words(node, probe: "Relation", build: "Relation", key_bits) -> int:
     """32-bit words a join's key is matched in (the operands of the match's
-    merge sort of n + m rows beside its one tag, position * 2 + is_build;
-    the way back is a sort of its own), as ``K.join_match`` packs them: a narrowed
+    merge sort of n + m rows beside its one tag, the position and the row's
+    class; the way back is a sort of its own), as ``K.join_match`` packs them: a narrowed
     column takes its bits, an integer column its type's width where both sides
     agree, anything else 64 (an order key); a cross join matches one word."""
     bits = 0
@@ -495,10 +500,11 @@ class PlanExecutor:
         """Join output capacity: host-sync the exact emitted row count (the
         operator-at-a-time model; traced executors override with a static
         bound + overflow accounting). ``totals`` is (rows emitted, matches
-        among them, most rows one probe row emits), which ``_jit_join_match``
-        computed: the one read carries all three. Returns (capacity, what was
-        read): the three, or None where nothing but the rows emitted was
-        read (the fused join's ``emit``) or nothing at all (traced)."""
+        among them, most rows one probe row emits, probe rows that emit),
+        which ``_jit_join_match`` computed: the one read carries all four.
+        Returns (capacity, what was read): the four, or None where nothing
+        but the rows emitted was read (the fused join's ``emit``) or nothing
+        at all (traced)."""
         if totals is None:
             read = None
             total = _sync_int(jnp.sum(emit), "join_capacity")
@@ -1534,9 +1540,14 @@ class PlanExecutor:
                 return rel
 
         key_bits, key_bases = self._join_key_widths(node, probe, build)
-        emit, count, lo, perm_b, totals = _jit_join_match(
+        # where the executor reads the join's totals, the match stops at the
+        # merge and the expansion takes the ranks back to the probe's order
+        # (RanksWay); a traced executor reads nothing and keeps the match's
+        # own way back
+        merged = (True,) if self.allow_host_sync else ()
+        emit, count, lo, perm_b, totals, *qid = _jit_join_match(
             left_outer, pkeys, bkeys, luts, probe.page.active, build.page.active,
-            key_bits, key_bases,
+            key_bits, key_bases, *merged,
         )
         out_capacity, read = self._choose_join_capacity(emit, probe.capacity, build.capacity, totals)
         if read is not None and left_outer:
@@ -1546,10 +1557,16 @@ class PlanExecutor:
         # side, or a LEFT join's rows matching one build row or none): the
         # expansion's slots are the emitting rows in order
         unique = read is not None and read[2] <= 1
+        ranks = ()
+        if qid:
+            ranks = (qid[0], _ranks_way(left_outer, probe.capacity, build.capacity, unique, read))
         page = _jit_join_expand(
-            out_capacity, unique, emit, count, lo, perm_b, probe.page, build.page
+            out_capacity, unique, emit, count, lo, perm_b, probe.page, build.page, *ranks
         )
-        rows_out = self._note_join(node, probe, build, out_capacity, key_bits, unique)
+        rows_out = self._note_join(
+            node, probe, build, out_capacity, key_bits, unique,
+            ranks[1].form if ranks else "merged", None if read is None else read[3],
+        )
 
         if kind == JoinKind.FULL:
             # append unmatched build rows with a null probe side (the join is
@@ -1588,6 +1605,7 @@ class PlanExecutor:
                     perm_b,
                     probe.page,
                     build.page,
+                    *ranks,
                 )
                 out = Relation(page, out.symbols, out.sorted_by)
         self._tag_vector_broadcast(build, out)
@@ -1628,19 +1646,24 @@ class PlanExecutor:
         return tuple(bits), tuple(bases)
 
     def _note_join(self, node, probe: Relation, build: Relation, out_capacity: int,
-                   key_bits=None, unique: bool = False):
+                   key_bits=None, unique: bool = False, ranks: str = "merged",
+                   emitting_rows: Optional[int] = None):
         """What the join moved, on its span and in the counters; every value
         is on the host already. ``unique``: the expansion took the form in
-        which each probe row emits one row at most. Returns the rows emitted where
-        ``_choose_join_capacity`` counted them (``sync:join_capacity``), else
-        None; a FULL join's tail and a residual filter come after it."""
+        which each probe row emits one row at most. ``ranks``: the way its
+        ranks came back to the probe's order (``RanksWay``), and
+        ``emitting_rows`` the probe rows that emit, where read. Returns the
+        rows emitted where ``_choose_join_capacity`` counted them
+        (``sync:join_capacity``), else None; a FULL join's tail and a residual
+        filter come after it."""
         keys = [probe.column_for(l) for l, _ in node.criteria]
         form = "unique" if unique else "general"
         _note(
             kind=node.kind.name, key_words=_join_key_words(node, probe, build, key_bits),
             probe_rows=_rows_or_capacity(probe), build_rows=_rows_or_capacity(build),
             probe_capacity=probe.capacity, build_capacity=build.capacity,
-            capacity_out=out_capacity, expand=form, key_types=[c.type.display() for c in keys],
+            capacity_out=out_capacity, expand=form, ranks=ranks, emitting_rows=emitting_rows,
+            key_types=[c.type.display() for c in keys],
             key_bits=None if key_bits is None else list(key_bits),
             probe_types=_type_counts(probe.page.columns),
             build_types=_type_counts(build.page.columns), sort_passes=_sort_passes(1),
@@ -1649,6 +1672,7 @@ class PlanExecutor:
         _count_join_rows(probe=_rows_or_capacity(probe), build=_rows_or_capacity(build))
         REGISTRY.counter(JOINS_COUNTER, {"kind": node.kind.name}, help=JOINS_HELP).inc()
         REGISTRY.counter(JOIN_EXPANSIONS_COUNTER, {"form": form}, help=JOIN_EXPANSIONS_HELP).inc()
+        REGISTRY.counter(JOIN_RANK_FORMS_COUNTER, {"form": ranks}, help=JOIN_RANK_FORMS_HELP).inc()
         if node.kind == JoinKind.FULL or node.filter is not None:
             return None
         span = TRACER.current()
@@ -3455,16 +3479,20 @@ def _project_impl(compiled, env: Dict[str, CVal], page: Page) -> Page:
 _jit_project = partial(kernelcost.jit, static_argnums=(0,))(_project_impl)
 
 
-@partial(kernelcost.jit, static_argnums=(0, 6))
+@partial(kernelcost.jit, static_argnums=(0, 6, 8))
 def _jit_join_match(
     left_outer: bool, pkeys, bkeys, luts, probe_active, build_active,
-    key_bits=None, key_bases=None,
+    key_bits=None, key_bases=None, merged: bool = False,
 ):
     """Join phase 1: key normalization + sorted-build matching + emit counts.
     ``key_bits`` / ``key_bases`` (``PlanExecutor._join_key_widths``): where a
     key column's entry is a width, the column is matched as ``value - base``
     in that many bits; a probe value outside that range matches nothing, as
-    no live build value lies there."""
+    no live build value lies there. ``merged``: the match stops at the merge
+    (``K.join_merge``): ``emit``, ``count`` and ``lo`` come in the merged
+    order, with a sixth output, the probe row number of each merged row, and
+    the way back is the expansion's (``RanksWay``); the totals carry a fourth
+    number, the probe rows that emit."""
     if not pkeys:  # cross join: all-equal keys
         probe_key = [jnp.zeros(probe_active.shape, dtype=jnp.int32)]
         build_key = [jnp.zeros(build_active.shape, dtype=jnp.int32)]
@@ -3492,6 +3520,14 @@ def _jit_join_match(
             build_key[i] = build_key[i].astype(jnp.int64) - base
     pa = probe_active & probe_valid
     ba = build_active & build_valid
+    if merged:
+        perm_b, qid, lo, count, live = K.join_merge(build_key, ba, probe_key, pa, probe_active, key_bits)
+        emit = jnp.where(live, jnp.maximum(count, 1), 0) if left_outer else count
+        totals = jnp.stack([
+            jnp.sum(emit.astype(jnp.int64)), jnp.sum(count.astype(jnp.int64)),
+            jnp.max(emit).astype(jnp.int64), jnp.sum((emit > 0).astype(jnp.int64)),
+        ])
+        return emit, count, lo, perm_b, totals, qid
     perm_b, lo, hi, count = K.join_match(build_key, ba, probe_key, pa, key_bits)
     emit = jnp.where(probe_active, jnp.maximum(count, 1), 0) if left_outer else count
     # (rows emitted, matches among them, most rows one probe row emits): the
@@ -3504,12 +3540,49 @@ def _jit_join_match(
     return emit, count, lo, perm_b, totals
 
 
+class RanksWay(NamedTuple):
+    """How a join's ranks reach its expansion from the merged order
+    (``_jit_join_match`` with ``merged``): ``form`` is ``K.ranks_form``'s,
+    ``slots`` the emitting form's listed rows (``K.emitting_ranks``), and
+    ``left_outer`` whether a live probe row that matches nothing emits."""
+
+    form: str
+    left_outer: bool
+    slots: int = 0
+
+
+def _ranks_way(left_outer: bool, probe_rows: int, build_rows: int, unique: bool, read) -> RanksWay:
+    """The way back for a join whose ``sync:join_capacity`` read is ``read``
+    (None: nothing was read, the merged form). The emitting form lists the
+    probe rows that emit and the probe's last row: one slot more than they."""
+    if read is None or probe_rows == 0:
+        return RanksWay("merged", left_outer)
+    slots = _round_capacity(read[3] + 1)
+    form = K.ranks_form(probe_rows + build_rows, probe_rows, slots, K.rank_words(build_rows), unique)
+    return RanksWay(form, left_outer, slots if form == "emitting" else 0)
+
+
 def _expand_join(out_capacity: int, unique: bool, emit, count, lo, perm_b,
-                 probe_page: Page, build_page: Page):
+                 probe_page: Page, build_page: Page, qid=None, way: Optional[RanksWay] = None):
     """The expansion's columns, probe side then build side (a null-padded
     slot's build columns null), and (probe_idx, matched, out_active) of its
     slots (``K.expand_matches``). In the ``unique`` form ``lo`` and ``count``
-    ride the probe columns' one gather to the slots."""
+    ride the probe columns' one gather to the slots. Where ``way`` is given,
+    ``emit``, ``count`` and ``lo`` are in the merged order, ``qid`` the probe
+    row of each merged row, and the expansion first brings the ranks back
+    (``RanksWay``); either way the slots are the same."""
+    if way is not None and way.form == "emitting":
+        n, m = probe_page.capacity, perm_b.shape[0]
+        e_qid, e_lo, e_count = K.emitting_ranks(qid, lo, count, emit, m, way.slots)
+        probe_idx, build_pos, matched, out_active, _ = K.expand_listed(
+            e_qid, e_lo, e_count, n, probe_page.active[n - 1], perm_b, out_capacity,
+            unique=unique, left_outer=way.left_outer,
+        )
+        probe_cols, _ = _permute_columns(probe_page.columns, probe_idx)
+        return _with_build_columns(probe_cols, build_page, build_pos, matched), probe_idx, matched, out_active
+    if way is not None:
+        lo, count = K.merged_ranks(qid, lo, count, perm_b.shape[0])
+        emit = jnp.where(probe_page.active, jnp.maximum(count, 1), 0) if way.left_outer else count
     if unique:
         probe_idx, out_active = K.unique_slots(emit, out_capacity)
         probe_cols, (lo_at, count_at) = _permute_columns(
@@ -3521,23 +3594,30 @@ def _expand_join(out_capacity: int, unique: bool, emit, count, lo, perm_b,
             emit, count, lo, perm_b, out_capacity
         )
         probe_cols, _ = _permute_columns(probe_page.columns, probe_idx)
+    return _with_build_columns(probe_cols, build_page, build_pos, matched), probe_idx, matched, out_active
+
+
+def _with_build_columns(probe_cols, build_page: Page, build_pos, matched) -> list:
+    """The probe's columns at the slots, then the build's at ``build_pos``,
+    null where the slot matched nothing."""
     cols = list(probe_cols)
     for pc in _permute_columns(build_page.columns, build_pos)[0]:
         cols.append(replace(pc, valid=pc.valid & matched))
-    return cols, probe_idx, matched, out_active
+    return cols
 
 
-@partial(kernelcost.jit, static_argnums=(0, 1))
+@partial(kernelcost.jit, static_argnums=(0, 1, 9))
 def _jit_join_expand(
-    out_capacity: int, unique: bool, emit, count, lo, perm_b, probe_page: Page, build_page: Page
+    out_capacity: int, unique: bool, emit, count, lo, perm_b, probe_page: Page, build_page: Page,
+    qid=None, way: Optional[RanksWay] = None,
 ) -> Page:
     cols, _, _, out_active = _expand_join(
-        out_capacity, unique, emit, count, lo, perm_b, probe_page, build_page
+        out_capacity, unique, emit, count, lo, perm_b, probe_page, build_page, qid, way
     )
     return Page(tuple(cols), out_active)
 
 
-@partial(kernelcost.jit, static_argnums=(0, 1, 2, 3))
+@partial(kernelcost.jit, static_argnums=(0, 1, 2, 3, 11))
 def _jit_left_join_residual(
     residual_fn,
     symbols: Tuple[str, ...],
@@ -3549,12 +3629,14 @@ def _jit_left_join_residual(
     perm_b,
     probe_page: Page,
     build_page: Page,
+    qid=None,
+    way: Optional[RanksWay] = None,
 ) -> Page:
     """LEFT JOIN with an ON residual: filter the expanded matches, then append
     one null-padded row for every probe row whose matches all failed (including
     rows that never matched — their placeholder also fails the residual)."""
     cols, probe_idx, matched, out_active = _expand_join(
-        out_capacity, unique, emit, count, lo, perm_b, probe_page, build_page
+        out_capacity, unique, emit, count, lo, perm_b, probe_page, build_page, qid, way
     )
     env = {s: _cval_of(c) for s, c in zip(symbols, cols)}
     v = residual_fn(env)
