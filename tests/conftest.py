@@ -54,7 +54,5 @@ def tpch_tiny():
 
 
 def pytest_configure(config):
-    # "slow" excludes a test from the tier-1 sweep (`-m 'not slow'`):
-    # currently the full 22-query megakernel corpus A/B, whose tier-1 slice
-    # runs the join-heaviest four queries instead
+    # "slow" excludes a test from the tier-1 sweep (`-m 'not slow'`)
     config.addinivalue_line("markers", "slow: excluded from the tier-1 run")

@@ -677,23 +677,6 @@ class TestSmokeCheck:
         spec.loader.exec_module(mod)
         assert mod.run_batching_smoke() == []
 
-    def test_megakernel_smoke_passes(self):
-        """The megakernel-plane smoke: paired pallas_compile/pallas_launch
-        spans with shape class + fused-op list on the E-args, bit-identical
-        fused vs serial run, strictly fewer device programs, HELP-linted
-        launch/fallback counters."""
-        import importlib.util
-        import os
-
-        tools = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "tools")
-        spec = importlib.util.spec_from_file_location(
-            "obs_smoke", os.path.join(tools, "obs_smoke.py")
-        )
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        assert mod.run_megakernel_smoke() == []
-
     def test_tensor_smoke_passes(self):
         """The tensor-plane smoke: paired vector_kernel/topk_fusion spans
         with rows/dim/k on the E-args, fused top-k bit-identical to the

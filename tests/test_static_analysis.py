@@ -631,21 +631,6 @@ class TestEngineLint:
             baseline = json.load(f)
         assert not [e for e in baseline if "unnamed-thread" in str(e)]
 
-    def test_kill_pallas_call_outside_ops(self, tmp_path):
-        findings = self._lint_snippet(tmp_path, "runtime/x.py", (
-            "from jax.experimental import pallas as pl\n"
-            "def f(k, xs):\n"
-            "    return pl.pallas_call(k, out_shape=xs)\n"
-        ))
-        assert [f.rule for f in findings] == ["pallas-call-outside-ops"]
-        # the ops/ kernel layer is the sanctioned launch site
-        ok = self._lint_snippet(tmp_path, "ops/megakernels.py", (
-            "from jax.experimental import pallas as pl\n"
-            "def f(k, xs):\n"
-            "    return pl.pallas_call(k, out_shape=xs)\n"
-        ))
-        assert ok == []
-
     def test_kill_jit_without_cost_hook(self, tmp_path):
         # every form a raw jax.jit takes in the engine: decorator,
         # partial-wrapped decorator, and plain call

@@ -560,42 +560,6 @@ def unnamed_thread(tree: ast.AST, source_lines: Sequence[str],
 
 
 # --------------------------------------------------------------------------- #
-# pallas-call-outside-ops
-# --------------------------------------------------------------------------- #
-
-
-@rule(
-    "pallas-call-outside-ops",
-    "direct pl.pallas_call launches belong in trino_tpu/ops/ — runtime code "
-    "goes through the megakernel/compiler layer so pallas_compile/"
-    "pallas_launch spans and fallback accounting cannot be skipped",
-)
-def pallas_call_outside_ops(tree: ast.AST, source_lines: Sequence[str],
-                            path: str) -> List[Finding]:
-    """Every kernel launch must route through the ops/ kernel layer
-    (ops/pallas_kernels.py, ops/megakernels.py): that layer owns the paired
-    flight spans, the launch/fallback counters, and the interpret-mode
-    bit-identity contract. A ``pl.pallas_call`` (or
-    ``pallas.pallas_call`` / bare ``pallas_call``) anywhere else in the
-    engine dodges all three."""
-    norm = path.replace("\\", "/")
-    if "/ops/" in norm or norm.startswith("ops/"):
-        return []
-    findings: List[Finding] = []
-    for node in ast.walk(tree):
-        if not isinstance(node, ast.Call):
-            continue
-        chain = _attr_chain(node.func)
-        if chain == "pallas_call" or chain.endswith(".pallas_call"):
-            findings.append(Finding(
-                path, node.lineno, pallas_call_outside_ops.id,
-                "direct pl.pallas_call outside trino_tpu/ops/ — launch "
-                "through the megakernel/compiler layer",
-            ))
-    return findings
-
-
-# --------------------------------------------------------------------------- #
 # jit-without-cost-hook
 # --------------------------------------------------------------------------- #
 
@@ -636,6 +600,5 @@ ALL_RULES = (
     bare_except_swallow,
     undeclared_session_property,
     unnamed_thread,
-    pallas_call_outside_ops,
     jit_without_cost_hook,
 )

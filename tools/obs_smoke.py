@@ -663,96 +663,6 @@ def run_batching_smoke(scale: float = 0.001) -> List[str]:
     return problems
 
 
-def run_megakernel_smoke(scale: float = 0.001) -> List[str]:
-    """Megakernel-plane smoke (ops/megakernels.py): a join-heavy query with
-    ``pallas_fusion=on`` under the flight recorder must leave a valid
-    Perfetto export with PAIRED ``pallas_compile``/``pallas_launch`` spans
-    (shape class + fused-op list on the E-args), results bit-identical to
-    the serial run, strictly fewer device program launches than serial, and
-    the launch/fallback counters registered with HELP text.
-
-    Returns a list of problems; [] means the smoke check passed.
-    """
-    from trino_tpu.ops import megakernels as MK
-    from trino_tpu.runtime.device_scheduler import program_launches
-    from trino_tpu.runtime.local import LocalQueryRunner
-    from trino_tpu.runtime.observability import RECORDER, validate_chrome_trace
-
-    problems: List[str] = []
-    sql = (
-        "SELECT n_name, sum(l_extendedprice), count(*) "
-        "FROM lineitem "
-        "JOIN orders ON l_orderkey = o_orderkey "
-        "JOIN customer ON o_custkey = c_custkey "
-        "JOIN nation ON c_nationkey = n_nationkey "
-        "GROUP BY n_name ORDER BY n_name"
-    )
-    runner = LocalQueryRunner.tpch(scale=scale)
-    n0 = program_launches()
-    serial = runner.execute(sql).rows
-    serial_launches = program_launches() - n0
-    runner.session.set("pallas_fusion", True)
-    # a shape that can never fuse, so the fallback counter family registers
-    runner.execute("SELECT count(*) FROM nation, region")
-    MK.on_pallas_fallback("smoke_probe")
-    RECORDER.clear()
-    RECORDER.enable()
-    try:
-        p0 = MK.pallas_launches()
-        n0 = program_launches()
-        fused = runner.execute(sql).rows
-        fused_launches = program_launches() - n0
-        fused_pallas = MK.pallas_launches() - p0
-    finally:
-        RECORDER.disable()
-    if fused != serial:
-        problems.append("fused results not bit-identical to serial run")
-    if fused_pallas < 1:
-        problems.append("pallas_fusion=on launched no megakernels")
-    if not fused_launches < serial_launches:
-        problems.append(
-            f"fused path did not dispatch strictly fewer device programs "
-            f"({fused_launches} vs serial {serial_launches})"
-        )
-    trace = RECORDER.chrome_trace()
-    RECORDER.clear()
-    problems += validate_chrome_trace(trace)  # paired B/E + monotonic tracks
-    events = trace.get("traceEvents", [])
-    for name in ("pallas_compile", "pallas_launch"):
-        b = sum(1 for e in events
-                if e.get("name") == name and e.get("ph") == "B")
-        e_ = sum(1 for e in events
-                 if e.get("name") == name and e.get("ph") == "E")
-        if not b:
-            problems.append(f"no {name} span in the trace")
-        elif b != e_:
-            problems.append(f"{name} spans unpaired: {b} B vs {e_} E")
-    launches = [
-        (e.get("args") or {})
-        for e in events
-        if e.get("name") == "pallas_launch" and e.get("ph") == "E"
-    ]
-    if not any(
-        a.get("shape_class") and a.get("fused_ops") for a in launches
-    ):
-        problems.append(
-            f"pallas_launch E-args missing shape_class/fused_ops: "
-            f"{launches[:3]}"
-        )
-    if not any(
-        "partial_agg" in str(a.get("fused_ops") or "") for a in launches
-    ):
-        problems.append(
-            "no join->partial-agg fused launch in a Q5-shape query"
-        )
-    problems += _registry_help_problems(required=(
-        "trino_tpu_pallas_launches_total",
-        "trino_tpu_pallas_fallbacks_total",
-        "trino_tpu_device_programs_total",
-    ))
-    return problems
-
-
 def run_tensor_smoke(rows: int = 64, dim: int = 8) -> List[str]:
     """Tensor-plane smoke (ops/tensor.py): a vector top-k query with
     ``tensor_plane``/``vector_topk_fusion`` on, under the flight recorder,
@@ -2007,7 +1917,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     problems += [f"[stats] {p}" for p in run_stats_smoke()]
     problems += [f"[cache] {p}" for p in run_cache_smoke()]
     problems += [f"[batching] {p}" for p in run_batching_smoke()]
-    problems += [f"[megakernel] {p}" for p in run_megakernel_smoke()]
     problems += [f"[tensor] {p}" for p in run_tensor_smoke()]
     problems += [f"[vector-serving] {p}" for p in run_vector_serving_smoke()]
     problems += [f"[ha] {p}" for p in run_ha_smoke()]

@@ -1,7 +1,7 @@
 """trino_tpu — a TPU-native distributed SQL query engine.
 
 A ground-up reimplementation of the capabilities of trinodb/trino (the Java MPP SQL
-engine) with a JAX/XLA/Pallas execution substrate: SQL is parsed/analyzed/planned in
+engine) with a JAX/XLA execution substrate: SQL is parsed/analyzed/planned in
 Python (cold path, like Trino's coordinator), and query fragments execute as compiled
 XLA programs over device-resident columnar Pages, sharded across a TPU mesh with XLA
 collectives playing the role of Trino's HTTP shuffle.

@@ -502,25 +502,6 @@ SESSION_PROPERTIES: Tuple[SessionProperty, ...] = (
         "the next tier (0 = unbounded)",
     ),
     SessionProperty(
-        "pallas_aggregation", "varchar", "auto",
-        "Pallas kernel tier for direct-indexed grouped aggregation: auto | "
-        "off | force | interpret (resolve_pallas_aggregation documents the "
-        "policy: AUTO keeps the XLA formulation — it wins on the measured "
-        "shapes — and 'force' opts into the limb kernels)",
-    ),
-    SessionProperty(
-        "pallas_fusion", "boolean", False,
-        "fragment-fused Pallas megakernels (ops/megakernels.py): hash join "
-        "+ partial agg + repartition epilogue in one launch; off = "
-        "byte-identical serial op-chain path (same contract as "
-        "device_batching)",
-    ),
-    SessionProperty(
-        "pallas_interpret", "varchar", "auto",
-        "megakernel execution mode: auto (pl.pallas_call interpret mode "
-        "off on TPU, on elsewhere — the tier-1 CPU contract) | on | off",
-    ),
-    SessionProperty(
         "query_stats_sync", "boolean", False,
         "fence every operator for exact device/host/compile attribution "
         "(defeats async dispatch; EXPLAIN ANALYZE VERBOSE turns it on)",
@@ -717,30 +698,8 @@ def session_property_names() -> frozenset:
 
 
 # --------------------------------------------------------------------------- #
-# pallas-tier policy resolvers (THE documented policy — executor._pallas_mode
-# and the device-batching admission check both delegate here, so the mode
-# vocabulary cannot drift between the launch sites)
+# session-value resolvers
 # --------------------------------------------------------------------------- #
-
-
-def resolve_pallas_aggregation(value) -> str:
-    """``pallas_aggregation`` session value -> static engine mode.
-
-    - ``auto``/``off`` -> ``"off"``: the XLA direct-indexed formulation.
-      Measured v5e SF1 (2026-07-29, chained-loop slope): XLA runs Q1 in
-      0.98 ms and a G=60 3-key shape in 0.93 ms — both at the HBM roofline —
-      while the Pallas limb kernels take 1.38 / 1.23 ms (the extra limb
-      lanes cost bandwidth), so AUTO keeps XLA.
-    - ``force`` -> ``"tpu"``: opt into the compiled limb kernels.
-    - ``interpret`` -> ``"interpret"``: pl.pallas_call interpret mode, the
-      CPU test hook.
-    """
-    mode = str(value or "auto").lower()
-    if mode == "interpret":
-        return "interpret"
-    if mode == "force":
-        return "tpu"
-    return "off"
 
 
 def resolve_ann_mode(value) -> Tuple[str, Optional[int]]:
@@ -764,19 +723,6 @@ def resolve_ann_mode(value) -> Tuple[str, Optional[int]]:
     if m:
         return ("approx", max(1, int(m.group(1))))
     return ("off", None)
-
-
-def resolve_pallas_interpret(value, backend: str) -> bool:
-    """``pallas_interpret`` session value -> interpret flag for megakernel
-    launches: ``auto`` runs compiled on TPU and interpret everywhere else
-    (the tier-1 bit-identity contract executes every fused kernel under
-    interpret mode on CPU); ``on``/``off`` force either way."""
-    mode = str(value or "auto").lower()
-    if mode in ("on", "true", "1", "interpret"):
-        return True
-    if mode in ("off", "false", "0"):
-        return False
-    return backend != "tpu"
 
 
 # --------------------------------------------------------------------------- #

@@ -3514,64 +3514,3 @@ def _like_to_regex(pattern: str, escape: Optional[str] = None) -> "re.Pattern":
             out.append(re.escape(ch))
         i += 1
     return re.compile("".join(out), re.DOTALL)
-
-
-# --------------------------------------------------------------------------- #
-# megakernel shape recognition (ops/megakernels.py)
-# --------------------------------------------------------------------------- #
-
-
-@dataclass(frozen=True)
-class MegakernelSpec:
-    """A fragment shape the fused Pallas megakernel path accepts.
-
-    Produced by :func:`plan_megakernel` — the compiler-layer half of the
-    megakernel plane. The executor layers the aggregation spec (direct-
-    indexed domains) and the repartition epilogue on top; this spec answers
-    only "is the JOIN itself expressible as the hash-probe kernel".
-    """
-
-    left_outer: bool
-
-
-def plan_megakernel(kind, criteria, has_filter: bool,
-                    probe_page, build_page) -> Tuple[Optional[MegakernelSpec], str]:
-    """Recognize a join fragment for the fused hash-join megakernel.
-
-    Returns ``(spec, reason)``: a spec when the shape is fused-eligible, or
-    ``(None, reason)`` with a stable fallback label (the
-    ``trino_tpu_pallas_fallbacks_total{reason=}`` vocabulary). Recognition
-    rules (the ARCHITECTURE.md "Megakernel plane" fallback matrix):
-
-    - equi-join with at least one criterion (CROSS has no keys to bucket)
-    - INNER or LEFT after the executor's RIGHT-swap; FULL's unmatched-build
-      tail needs the anti-set pass the kernel does not carry yet
-    - no non-equi residual (the serial path owns ON-clause residuals)
-    - single-lane key columns (int128 limb keys order on two words — the
-      kernel compares one normalized word per column)
-
-    Payload columns are unconstrained: the expansion gathers whole columns
-    through the same ``_permute_column`` body the serial join uses, so
-    multi-lane (int128 limb) and nested payloads ride along identically.
-    """
-    from ..planner.plan import JoinKind as _JK
-
-    if not criteria:
-        return None, "cross_join"
-    if kind not in (_JK.INNER, _JK.LEFT):
-        return None, "join_kind"
-    if has_filter:
-        return None, "residual_filter"
-    for page in (probe_page, build_page):
-        if page.capacity < 1:
-            return None, "empty_layout"
-    return MegakernelSpec(left_outer=(kind == _JK.LEFT)), "ok"
-
-
-def megakernel_key_check(key_cols) -> Tuple[bool, str]:
-    """Physical key-column check: every join key must be a single-lane
-    column (``data.ndim == 1``); multi-lane (int128) keys fall back."""
-    for d, _v in key_cols:
-        if d.ndim != 1:
-            return False, "key_ndim"
-    return True, "ok"

@@ -1181,10 +1181,7 @@ def expand_listed(e_qid, e_lo, e_count, n: int, last_live, perm_b, out_capacity:
 
 
 def expand_probe_slots(emit: jnp.ndarray, out_capacity: int):
-    """Slot-assignment half of rank-space match expansion, shared between the
-    sort-based join (expand_matches) and the hash-probe megakernel
-    (ops/megakernels.py) — both paths MUST place probe row i's output rows at
-    the same slots for the fused/serial bit-identity contract to hold.
+    """Slot-assignment half of rank-space match expansion.
 
     Returns (probe_idx, d, out_active, total):
     - probe_idx[p]: probe row for output slot p (last i with start[i] <= p)

@@ -154,31 +154,11 @@ def _repartition_epilogue(n_parts: int, key_idx: Tuple[int, ...], page: Page):
     return Page(cols, sorted_payloads[-1]), offsets, counts
 
 
-# ops/megakernels.py re-traces the plain body inside its fused kernels (the
-# epilogue as a megakernel output stage); the jit wrapper is the standalone
-# launch the TPU tier dispatches per exchange edge
 _jit_repartition_epilogue = partial(kernelcost.jit, static_argnums=(0, 1))(
     _repartition_epilogue
 )
 
 _jit_partition_dest = kernelcost.jit(_partition_dest, static_argnums=(0, 1))
-
-
-def _take_fused_dest(page: Page, key_idx: Tuple[int, ...], n_parts: int):
-    """Consume a megakernel-attached per-row destination array, if one rides
-    on this exact Page object for this exact partitioning spec (the megakernel
-    plane computed it inside the producing fragment's fused kernel, so the
-    standalone ``_jit_partition_dest`` program never dispatches). Returns the
-    dest array or None; the attachment is popped — it is only valid for the
-    page object it was computed from."""
-    payload = page.__dict__.pop("_megakernel_epilogue", None)
-    if not payload:
-        return None
-    if payload.get("key_idx") != tuple(key_idx) or payload.get("n_parts") != n_parts:
-        # a different exchange spec than the fused stage anticipated — the
-        # precomputed dest is for the wrong partitioning, recompute
-        return None
-    return payload.get("dest")
 
 
 def repartition_frames(
@@ -212,18 +192,10 @@ def repartition_frames(
             cols, offsets, counts, compress=compress, pool=pool
         )
         return frames, counts
-    fused = _take_fused_dest(page, key_idx, n_parts)
     with RECORDER.span(
-        "repartition_kernel", "exchange", parts=n_parts, capacity=page.capacity,
-        fused=fused is not None,
+        "repartition_kernel", "exchange", parts=n_parts, capacity=page.capacity
     ):
-        # a megakernel-fused fragment already computed dest in its output
-        # stage — bit-identical to _jit_partition_dest (same _partition_dest
-        # body), so the standalone hash program never dispatches
-        dest = np.asarray(
-            fused if fused is not None
-            else _jit_partition_dest(n_parts, key_idx, page)
-        )
+        dest = np.asarray(_jit_partition_dest(n_parts, key_idx, page))
         host_cols = [
             (c.type, np.asarray(c.data), np.asarray(c.valid), c.dictionary)
             for c in page.columns
@@ -277,11 +249,7 @@ def repartition_to_host(page: Page, key_idx: Sequence[int], n_parts: int):
                 for c, (d, v) in zip(sorted_page.columns, host_cols)
             ]
             return cols, np.asarray(off), np.asarray(cnt)
-        fused = _take_fused_dest(page, key_idx, n_parts)
-        dest = np.asarray(
-            fused if fused is not None
-            else _jit_partition_dest(n_parts, key_idx, page)
-        )
+        dest = np.asarray(_jit_partition_dest(n_parts, key_idx, page))
         order = np.concatenate(
             [np.flatnonzero(dest == p) for p in range(n_parts)]
         )

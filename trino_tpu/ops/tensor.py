@@ -32,7 +32,7 @@ rule, fragmenter split) lives with its planes:
 - **Observability**: ``trino_tpu_vector_kernel_launches_total`` +
   ``trino_tpu_vector_topk_fallbacks_total{reason}`` counters, and the paired
   ``vector_kernel`` / ``topk_fusion`` flight spans (rows/dim/k on E-args).
-  Fallback labels, like the megakernel plane's, are short stable strings:
+  Fallback labels are short stable strings:
   ``unprojected_order_key`` (a fusable ORDER BY similarity whose other sort
   keys are not computed by the scoring projection), ``kernel_error`` (the
   fused program failed at runtime; the serial project+sort pair finished the

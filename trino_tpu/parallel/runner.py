@@ -156,16 +156,9 @@ def run_fragment_partition(executor: "_FragmentExecutor", root: PlanNode) -> Pag
         _, page = executor.execute()
         return page
     rel = executor.eval(root)
-    out = Page(
+    return Page(
         tuple(rel.column_for(s) for s in root.output_symbols), rel.page.active
     )
-    if "_megakernel_epilogue" in rel.page.__dict__:
-        # a fused root computed the exchange destination as its kernel
-        # output stage — carry it across the output-symbol rewrap
-        from ..ops.megakernels import reattach_epilogue
-
-        reattach_epilogue(rel.page, out, root.output_symbols)
-    return out
 
 
 class _FragmentExecutor(PlanExecutor):

@@ -258,14 +258,11 @@ class _AdaptiveTracedExecutor(_TracedExecutor):
         self._record(id(node), jnp.maximum(actual - cap, 0), actual, cap, in_cap)
         return cap
 
-    def _join_relations(self, node: JoinNode, left: Relation, right: Relation,
-                        allow_fusion: bool = True):
+    def _join_relations(self, node: JoinNode, left: Relation, right: Relation):
         prev = self._join_key
         self._join_key = id(node)
         try:
-            # allow_fusion is moot here: traced executors never host-sync,
-            # so the megakernel gate (_fusion_enabled) is always off
-            return super()._join_relations(node, left, right, allow_fusion)
+            return super()._join_relations(node, left, right)
         finally:
             self._join_key = prev
 

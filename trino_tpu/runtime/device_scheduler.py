@@ -267,22 +267,6 @@ def _chain_statically_batchable(root, session) -> bool:
             return False
     except KeyError:
         pass
-    # Pallas kernels are not exercised under vmap — keep them serial. The
-    # mode vocabulary resolves through the central knob registry (the same
-    # policy executor._pallas_mode applies), so the two launch sites cannot
-    # drift. The megakernel plane (pallas_fusion) composes freely: batchable
-    # chains are scan-rooted and join-free, so a fused join/agg fragment
-    # never appears inside a ragged lane body — fusion and batching serve
-    # disjoint fragment shapes of the same query.
-    from .. import knobs
-
-    try:
-        if knobs.resolve_pallas_aggregation(
-            session.get("pallas_aggregation")
-        ) != "off":
-            return False
-    except KeyError:
-        pass
     return True
 
 
@@ -353,8 +337,7 @@ def _apply_chain_node(rel, node, types):
         domains = _direct_agg_domains(rel, node)
         if domains is not None:
             page = _jit_direct_aggregate(
-                node.group_keys, node.aggregations, domains, rel.symbols,
-                rel.page, "off",
+                node.group_keys, node.aggregations, domains, rel.symbols, rel.page
             )
             return Relation(page, out_symbols)
         # global aggregation (no group keys): the serial path's
@@ -825,18 +808,16 @@ class DeviceScheduler:
         """The serial tail of a submitted item: the same per-operator
         programs _eval_node would dispatch, minus per-node bookkeeping
         (the caller books the root — the fragment-cache-hit convention)."""
-        return self._serial_chain(
-            rel, chain, executor.types, executor._pallas_mode(), count
-        )
+        return self._serial_chain(rel, chain, executor.types, count)
 
     @staticmethod
-    def _serial_chain(rel, chain, types, pallas_mode: str, count: bool):
+    def _serial_chain(rel, chain, types, count: bool):
         from ..planner.plan import AggregationNode
         from .executor import aggregate_relation
 
         for node in chain:
             if isinstance(node, AggregationNode):
-                rel = aggregate_relation(rel, node, types, pallas_mode)
+                rel = aggregate_relation(rel, node, types)
             else:
                 rel = _apply_chain_node(rel, node, types)
             if count:
@@ -1050,12 +1031,8 @@ class DeviceScheduler:
                 lane.event.set()
 
     def _launch_single(self, lane: _Lane):
-        # batchable chains pre-check pallas to the "off" resolution, so the
-        # shared serial walk is exactly the owning executor's computation
         self.single_launches += 1
-        return self._serial_chain(
-            lane.rel, lane.chain, lane.types, "off", count=True
-        )
+        return self._serial_chain(lane.rel, lane.chain, lane.types, count=True)
 
     def _launch_ragged(self, group, reps: List[_Lane], unique,
                        priority: float = 1.0) -> None:

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import knobs
 from ..metadata import Metadata, Session
 from . import kernelcost
 from .device_scheduler import on_program_launch
@@ -290,19 +289,6 @@ def _jit_pack_page(out, page, at):
     return jax.lax.dynamic_update_slice_in_dim(out, page, at, axis=0)
 
 
-class _KeyView:
-    """column_for shim over resolved group-key source columns — the
-    direct-indexed domain computation consults only the key columns'
-    type/dictionary, so the fused planner can run it before the joined
-    page exists."""
-
-    def __init__(self, cols: Dict[str, Column]):
-        self._cols = cols
-
-    def column_for(self, symbol: str) -> Column:
-        return self._cols[symbol]
-
-
 def _sync_int(x, site: str) -> int:
     """The device-to-host read of one integer on the operator path: the host
     waits here for everything dispatched before it. One span `sync:<site>`
@@ -503,14 +489,9 @@ class PlanExecutor:
         among them, most rows one probe row emits, probe rows that emit),
         which ``_jit_join_match`` computed: the one read carries all four.
         Returns (capacity, what was read): the four, or None where nothing
-        but the rows emitted was read (the fused join's ``emit``) or nothing
-        at all (traced)."""
-        if totals is None:
-            read = None
-            total = _sync_int(jnp.sum(emit), "join_capacity")
-        else:
-            read = _sync_ints(totals, "join_capacity")
-            total = read[0]
+        was read (traced)."""
+        read = _sync_ints(totals, "join_capacity")
+        total = read[0]
         _note(rows_out=total)
         _count_join_rows(out=total)
         return _round_capacity(max(total, 1)), read
@@ -568,10 +549,6 @@ class PlanExecutor:
         # operator-state spill stats (io.trino.spiller SpillMetrics analogue)
         self.spill_count = 0
         self.spilled_bytes = 0
-        # megakernel plane: the launch site (server/worker.py) plants the
-        # fragment's output partitioning here — (key_symbols, n_parts) — so
-        # a fused root can run the repartition epilogue as its output stage
-        self.repartition_hint = None
         # kernel cost plane (runtime/kernelcost.py): id(node) -> aggregated
         # XLA cost-model attribution for this query's launches. Only filled
         # in stats mode with the kernel_cost session property on (EXPLAIN
@@ -915,9 +892,7 @@ class PlanExecutor:
         return tuple(compiled)
 
     def _project_relation(self, node: ProjectNode, rel: Relation) -> Relation:
-        """Project an already-evaluated relation (shared by the standard walk
-        and the megakernel plane's serial-finish fallback, which must not
-        re-evaluate the project's source subtree)."""
+        """Project an already-evaluated relation."""
         compiled = self._compile_assignments(node.assignments, rel)
         symbols = []
         alias_of = {}  # output symbol -> input symbol (identity projections)
@@ -942,22 +917,6 @@ class PlanExecutor:
             if out is None:
                 break
             sorted_by.append(out)
-        payload = rel.page.__dict__.get("_megakernel_epilogue")
-        if payload and payload.get("keys"):
-            # a fused source computed the exchange dest in-kernel; a
-            # projection is row-preserving (active rides through unchanged),
-            # so the dest stays valid as long as every partition key passes
-            # through as an identity reference — carry it to the new page
-            # under the aliased names
-            renamed = tuple(alias_of.get(k) for k in payload["keys"])
-            if all(r is not None for r in renamed):
-                from ..ops.megakernels import attach_epilogue
-
-                attach_epilogue(
-                    page, payload["dest"],
-                    tuple(symbols.index(r) for r in renamed),
-                    payload["n_parts"], keys=renamed,
-                )
         return Relation(page, tuple(symbols), tuple(sorted_by))
 
     def _exec_UnnestNode(self, node) -> Relation:
@@ -988,9 +947,6 @@ class PlanExecutor:
         distinct_aggs = [a for _, a in node.aggregations if a.distinct]
         if distinct_aggs:
             return self._exec_distinct_aggregation(node)
-        fused = self._try_fused_join_aggregate(node)
-        if fused is not None:
-            return fused
         rel = self.eval(node.source)
         thresh = self._spill_threshold()
         if thresh and self.allow_host_sync and node.group_keys:
@@ -999,339 +955,7 @@ class PlanExecutor:
             total = page_bytes(rel.page)
             if total > thresh:
                 return self._spill_partitioned_aggregate(rel, node, total, thresh)
-        return aggregate_relation(rel, node, self.types, self._pallas_mode())
-
-    def _pallas_mode(self) -> str:
-        """Resolve the pallas_aggregation session property to a static mode:
-        'tpu' (compiled kernels), 'interpret' (pl.pallas_call interpret mode —
-        the CPU test hook), or 'off'. THE policy (why AUTO keeps the XLA
-        formulation, with the v5e measurements) lives in the central knob
-        registry: knobs.resolve_pallas_aggregation."""
-        try:
-            mode = self.session.get("pallas_aggregation")
-        except KeyError:
-            mode = "auto"
-        return knobs.resolve_pallas_aggregation(mode)
-
-    # ------------------------------------------------- megakernel plane
-
-    def _fusion_enabled(self) -> bool:
-        """pallas_fusion session gate. Off (the default) keeps the execution
-        path byte-identical to the serial op-chain (the device_batching
-        contract). Stats mode stays serial so EXPLAIN ANALYZE attributes
-        per-operator time; traced executors (allow_host_sync=False) run one
-        fused XLA program already and host-sync nothing mid-plan."""
-        if not self.allow_host_sync or self.collect_stats:
-            return False
-        try:
-            return bool(self.session.get("pallas_fusion"))
-        except KeyError:
-            return False
-
-    def _fusion_interpret(self) -> bool:
-        try:
-            mode = self.session.get("pallas_interpret")
-        except KeyError:
-            mode = "auto"
-        return knobs.resolve_pallas_interpret(mode, jax.default_backend())
-
-    def _epilogue_spec_for(self, symbols: Tuple[str, ...]):
-        """(key_idx, n_parts) when this fragment's output feeds a hash
-        exchange whose keys the produced symbols cover (the launch site —
-        server/worker.py — plants ``repartition_hint`` before execution), so
-        the megakernel computes the exchange destination as its output stage
-        and ops/repartition skips the standalone hash program."""
-        hint = getattr(self, "repartition_hint", None)
-        if not hint:
-            return None
-        keys, n_parts = hint
-        if not keys or n_parts <= 1:
-            return None
-        if not all(k in symbols for k in keys):
-            return None
-        return tuple(symbols.index(k) for k in keys), int(n_parts)
-
-    def _fused_join_spec(self, kind, node: JoinNode, probe, build,
-                         pkeys, bkeys):
-        """Shared shape gate: compiler recognition + physical key check.
-        Returns the MegakernelSpec or None (fallback ticked)."""
-        from ..ops import megakernels as MK
-        from ..ops.compiler import megakernel_key_check, plan_megakernel
-
-        spec, reason = plan_megakernel(
-            kind, node.criteria, node.filter is not None,
-            probe.page, build.page,
-        )
-        if spec is None:
-            MK.on_pallas_fallback(reason)
-            return None
-        for cols in (pkeys, bkeys):
-            ok, reason = megakernel_key_check(cols)
-            if not ok:
-                MK.on_pallas_fallback(reason)
-                return None
-        return spec
-
-    def _try_fused_join(
-        self, kind, node: JoinNode, probe: Relation, build: Relation,
-        pkeys, bkeys, luts,
-    ) -> Optional[Relation]:
-        """Attempt the fused hash-join megakernel for an already-normalized
-        (RIGHT-swapped) join: ops/compiler.plan_megakernel recognizes the
-        shape, ops/megakernels runs build+probe+expand (+ the repartition
-        dest) as Pallas launches. Returns the fused Relation, or None after
-        a labeled fallback tick — the caller runs the serial op-chain. A
-        compiled (non-interpret) launch that fails raises instead."""
-        from ..ops import megakernels as MK
-
-        spec = self._fused_join_spec(kind, node, probe, build, pkeys, bkeys)
-        if spec is None:
-            return None
-        interp = self._fusion_interpret()
-        out_symbols = probe.symbols + build.symbols
-        try:
-            pr = MK.probe_phase(
-                pkeys, bkeys, luts, probe.page.active, build.page.active,
-                spec.left_outer, interp,
-            )
-            if pr is None:
-                return None  # bucket skew; fallback already ticked
-            out_capacity, _ = self._choose_join_capacity(
-                pr["emit"], probe.capacity, build.capacity
-            )
-            epi_spec = self._epilogue_spec_for(out_symbols)
-            page, dest = MK.expand_phase(
-                pr, pkeys, bkeys, luts, probe.page, build.page,
-                out_capacity, out_symbols, None, None, epi_spec, interp,
-            )
-        except Exception:
-            if not interp:
-                # a compiled launch that Mosaic refuses is the query's
-                # error, with the compiler's message: a kernel must not
-                # quietly give way to its reference on the chip
-                raise
-            MK.on_pallas_fallback("kernel_error")
-            return None
-        if dest is not None:
-            MK.attach_epilogue(
-                page, dest, epi_spec[0], epi_spec[1],
-                keys=(self.repartition_hint or ((),))[0],
-            )
-        # probe-major expansion preserves the probe side's order (the serial
-        # join's out_sorted rule for non-FULL kinds)
-        return Relation(page, out_symbols, probe.sorted_by)
-
-    def _try_fused_join_aggregate(self, node: AggregationNode) -> Optional[Relation]:
-        """join -> [project] -> partial-agg fusion: when a (non-distinct,
-        grouped) aggregation sits on a fused-eligible join — possibly with
-        one elementwise ProjectNode in between (the shape the optimizer
-        emits for every sum(expr)-over-join fragment) — build, probe,
-        expansion, the projected expressions, and the group stage all run
-        inside megakernel launches; the join output never materializes
-        between operators, and the whole fragment books ONE device program
-        where the serial walk books two or three.
-
-        Group strategy mirrors aggregate_relation exactly: direct-indexed
-        (small static dictionary/boolean domains) runs entirely inside the
-        expand kernel; every other shape takes the sort path — group-sort +
-        boundary detection inside the expand kernel, one host sync for the
-        group count (the sync the serial path performs too), then the
-        reduction stage as the aggregate kernel. Returns the aggregated
-        Relation, or None for the standard walk."""
-        if not self._fusion_enabled():
-            return None
-        proj = None
-        src = node.source
-        if isinstance(src, ProjectNode) and isinstance(src.source, JoinNode):
-            proj, src = src, src.source
-        if not isinstance(src, JoinNode) or not node.group_keys:
-            return None
-        if self._spill_threshold():
-            return None  # the spill paths host-sync sizes — serial only
-        if self._pallas_mode() != "off":
-            return None  # the limb kernels cannot nest inside the fused kernel
-        if any(
-            a.distinct or a.ordering or a.function in _LANE_AGGS
-            for _, a in node.aggregations
-        ):
-            # lane-valued aggregates host-sync their static lane width;
-            # aggregate ORDER BY pre-sorts the whole relation — serial only
-            return None
-        from ..ops import megakernels as MK
-
-        pre = self._join_inputs(src)
-        if isinstance(pre, Relation):
-            # the operator-state spill path ran the whole join (it cannot
-            # trigger with spill_operator_threshold_bytes unset, but stay
-            # safe against future gates): finish serially
-            return self._serial_agg_finish(node, proj, pre)
-        left, right = pre
-        kind, src_n, probe, build, pkeys, bkeys, luts = self._join_sides(
-            src, left, right
-        )
-
-        def serial_finish() -> Relation:
-            # ONE spelling of the fallback: serial join (fusion already
-            # declined — don't re-attempt), booked like _eval_node would
-            return self._serial_agg_finish(
-                node, proj,
-                self._join_relations(src, left, right, allow_fusion=False),
-                book_join=True,
-            )
-
-        spec = self._fused_join_spec(kind, src_n, probe, build, pkeys, bkeys)
-        if spec is None:
-            return serial_finish()
-        base_symbols = probe.symbols + build.symbols
-        view = Relation(
-            Page(
-                tuple(probe.page.columns) + tuple(build.page.columns),
-                probe.page.active,
-            ),
-            base_symbols,
-            probe.sorted_by,
-        )
-        interp = self._fusion_interpret()
-        try:
-            pr = MK.probe_phase(
-                pkeys, bkeys, luts, probe.page.active, build.page.active,
-                spec.left_outer, interp,
-            )
-            if pr is None:
-                return serial_finish()
-            out_capacity, _ = self._choose_join_capacity(
-                pr["emit"], probe.capacity, build.capacity
-            )
-            # fold the intermediate projection into the kernel: the same
-            # compiled expression closures the serial _project_impl runs
-            # (compile_expression caches on (expr, layout, capacity), so the
-            # jit static key is stable across executions)
-            proj_spec = None
-            post_symbols = base_symbols
-            post_sorted = view.sorted_by
-            key_sources: Dict[str, Column] = {}
-            if proj is not None:
-                layout = view.layout()
-                compiled = []
-                symbols = []
-                alias_of = {}
-                for sym, expr in proj.assignments:
-                    fn, out_dict = compile_expression(expr, layout, out_capacity)
-                    type_ = self.types.get(sym) or expr.type
-                    compiled.append((fn, type_, out_dict))
-                    symbols.append(sym)
-                    if isinstance(expr, Reference):
-                        alias_of[expr.symbol] = sym
-                        key_sources[sym] = view.column_for(expr.symbol)
-                proj_spec = (tuple(compiled), tuple(symbols))
-                post_symbols = tuple(symbols)
-                post_sorted = []
-                for s in view.sorted_by:
-                    out = alias_of.get(s)
-                    if out is None:
-                        break
-                    post_sorted.append(out)
-                post_sorted = tuple(post_sorted)
-            else:
-                key_sources = {s: view.column_for(s) for s in node.group_keys
-                               if s in base_symbols}
-            agg_symbols = node.group_keys + tuple(s for s, _ in node.aggregations)
-            epi_spec = self._epilogue_spec_for(agg_symbols)
-            domains = None
-            if all(k in key_sources for k in node.group_keys) and not any(
-                a.function not in _DIRECT_AGG_FUNCS for _, a in node.aggregations
-            ):
-                domains = _direct_agg_domains(_KeyView(key_sources), node)
-            if domains is not None:
-                agg_spec = ("direct", (
-                    tuple(node.group_keys), tuple(node.aggregations),
-                    tuple(domains), tuple(post_symbols),
-                ))
-                page, dest = MK.expand_phase(
-                    pr, pkeys, bkeys, luts, probe.page, build.page,
-                    out_capacity, base_symbols, proj_spec, agg_spec,
-                    epi_spec, interp,
-                )
-            else:
-                needed = _needed_agg_symbols(node)
-                presorted = bool(post_sorted) and (
-                    post_sorted[0] == node.group_keys[0]
-                )
-                if presorted and any(
-                    a.function in _RESORT_AGGS for _, a in node.aggregations
-                ):
-                    # serial would _force_dense here — a no-op for joined
-                    # pages (the expansion emits a dense active prefix),
-                    # so the presorted grouping is safe to take as-is
-                    pass
-                mode = "presorted" if presorted else "sort"
-                agg_spec = (mode, (
-                    tuple(node.group_keys), tuple(needed), tuple(post_symbols),
-                ))
-                if presorted:
-                    # the serial presorted fast path, fused: the expand
-                    # kernel verifies sortedness in-program; a violation
-                    # re-groups through one extra kernel — the exact
-                    # decision (and cost) of the serial path
-                    joined, p, ng, n_grp, viol = MK.expand_phase(
-                        pr, pkeys, bkeys, luts, probe.page, build.page,
-                        out_capacity, base_symbols, proj_spec, agg_spec,
-                        None, interp,
-                    )
-                    if bool(viol):
-                        sorted_page, new_group, num_groups = MK.group_sort_phase(
-                            tuple(node.group_keys), tuple(needed),
-                            tuple(post_symbols), joined, interp,
-                        )
-                    else:
-                        sorted_page, new_group, num_groups = p, ng, n_grp
-                else:
-                    sorted_page, new_group, num_groups = MK.expand_phase(
-                        pr, pkeys, bkeys, luts, probe.page, build.page,
-                        out_capacity, base_symbols, proj_spec, agg_spec,
-                        None, interp,
-                    )
-                # the group-count host sync the serial sort path performs
-                out_cap = min(
-                    _round_capacity(max(_sync_int(num_groups, "num_groups"), 1), base=16),
-                    max(out_capacity, 16),
-                )
-                page, dest = MK.aggregate_phase(
-                    tuple(node.group_keys), tuple(node.aggregations),
-                    tuple(needed), out_cap, sorted_page, new_group,
-                    num_groups, epi_spec, interp,
-                )
-        except Exception:
-            if not interp:
-                raise  # as in _try_fused_join
-            MK.on_pallas_fallback("kernel_error")
-            return serial_finish()
-        if dest is not None:
-            MK.attach_epilogue(
-                page, dest, epi_spec[0], epi_spec[1],
-                keys=(self.repartition_hint or ((),))[0],
-            )
-        return Relation(page, agg_symbols)
-
-    def _serial_agg_finish(self, node: AggregationNode, proj,
-                           join_rel: Relation, book_join: bool = False) -> Relation:
-        """Finish an attempted fused join+agg fragment on the serial path
-        WITHOUT re-evaluating the join inputs, booking the intermediate
-        nodes the way _eval_node would have."""
-        if book_join:
-            on_program_launch()
-            if self.collect_actuals:
-                self._stash_actual(node.source if proj is None else proj.source,
-                                   join_rel)
-            self._account(node.source if proj is None else proj.source, join_rel)
-        rel = join_rel
-        if proj is not None:
-            on_program_launch()
-            rel = self._project_relation(proj, rel)
-            if self.collect_actuals:
-                self._stash_actual(proj, rel)
-            self._account(proj, rel)
-        return aggregate_relation(rel, node, self.types, self._pallas_mode())
+        return aggregate_relation(rel, node, self.types)
 
     def _exec_distinct_aggregation(self, node: AggregationNode) -> Relation:
         """x(DISTINCT col): dedup on (group keys, col) first, then aggregate.
@@ -1354,7 +978,7 @@ class PlanExecutor:
             aggregations=(),
             step=AggregationStep.SINGLE,
         )
-        deduped = aggregate_relation(rel, dedup_node, self.types, self._pallas_mode())
+        deduped = aggregate_relation(rel, dedup_node, self.types)
         # the dedup's own numbers, before the aggregations after it note theirs
         _note(distinct=dcol, distinct_rows_in=_rows_or_capacity(rel), distinct_groups=deduped.rows)
         REGISTRY.counter(DISTINCT_COUNTER, help=DISTINCT_HELP).inc()
@@ -1368,9 +992,7 @@ class PlanExecutor:
             ),
             step=node.step,
         )
-        dist_rel = aggregate_relation(
-            deduped, dist_part, self.types, self._pallas_mode()
-        )
+        dist_rel = aggregate_relation(deduped, dist_part, self.types)
         plain_aggs = tuple(
             (s, a) for s, a in node.aggregations if not a.distinct
         )
@@ -1382,9 +1004,7 @@ class PlanExecutor:
             aggregations=plain_aggs,
             step=node.step,
         )
-        plain_rel = aggregate_relation(
-            rel, plain_part, self.types, self._pallas_mode()
-        )
+        plain_rel = aggregate_relation(rel, plain_part, self.types)
         # both outputs order groups identically (same keys, same machinery —
         # group rows sit compacted at the front) but their CAPACITIES differ
         # (the distinct side aggregated the smaller deduped relation): verify
@@ -1430,18 +1050,6 @@ class PlanExecutor:
     # ----------------------------------------------------------------- joins
 
     def _exec_JoinNode(self, node: JoinNode) -> Relation:
-        pre = self._join_inputs(node)
-        if isinstance(pre, Relation):
-            return pre  # the operator-state spill path ran the whole join
-        left, right = pre
-        return self._join_relations(node, left, right)
-
-    def _join_inputs(self, node: JoinNode):
-        """Shared join preamble — dynamic filtering, input compaction, the
-        operator-state spill gate — factored out so the megakernel plane
-        (join -> partial-agg fusion) evaluates inputs exactly the way the
-        serial path does. Returns ``(left, right)`` Relations, or a finished
-        Relation when the spill-partitioned path executed the join itself."""
         # dynamic filtering (ref: server/DynamicFilterService.java:101 +
         # DynamicFilterSourceOperator): evaluate the build side first, collect
         # its key ranges, and AND them into the probe subtree as a filter so
@@ -1489,13 +1097,11 @@ class PlanExecutor:
             total = page_bytes(left.page) + page_bytes(right.page)
             if total > thresh:
                 return self._spill_partitioned_join(node, left, right, total, thresh)
-        return left, right
+        return self._join_relations(node, left, right)
 
-    def _join_sides(self, node: JoinNode, left: Relation, right: Relation):
-        """RIGHT-swap + key/LUT extraction shared by the serial join and the
-        fused megakernel path: returns (kind, node, probe, build, pkeys,
-        bkeys, luts) with RIGHT normalized to LEFT (sides swapped; output
-        symbols reorder by symbol lookup, so the swap is free)."""
+    def _join_relations(self, node: JoinNode, left: Relation, right: Relation) -> Relation:
+        # RIGHT normalizes to LEFT with the sides swapped (output symbols
+        # reorder by symbol lookup, so the swap is free)
         kind = node.kind
         if kind == JoinKind.RIGHT:
             node = JoinNode(
@@ -1522,23 +1128,7 @@ class PlanExecutor:
             )
             # cross-dictionary key translation for string join keys
             luts = _string_key_luts(node, probe, build)
-        return kind, node, probe, build, pkeys, bkeys, luts
-
-    def _join_relations(
-        self, node: JoinNode, left: Relation, right: Relation,
-        allow_fusion: bool = True,
-    ) -> Relation:
-        kind, node, probe, build, pkeys, bkeys, luts = self._join_sides(
-            node, left, right
-        )
         left_outer = kind in (JoinKind.LEFT, JoinKind.FULL)
-        if allow_fusion and self._fusion_enabled():
-            rel = self._try_fused_join(
-                kind, node, probe, build, pkeys, bkeys, luts
-            )
-            if rel is not None:
-                return rel
-
         key_bits, key_bases = self._join_key_widths(node, probe, build)
         # where the executor reads the join's totals, the match stops at the
         # merge and the expansion takes the ranks back to the probe's order
@@ -1811,9 +1401,7 @@ class PlanExecutor:
         outs: List[Relation] = []
         for blob in parts:
             outs.append(
-                aggregate_relation(
-                    self._unspill(blob, rel), node, self.types, self._pallas_mode()
-                )
+                aggregate_relation(self._unspill(blob, rel), node, self.types)
             )
         page = _concat_pages([o.page for o in outs])
         return Relation(page, outs[0].symbols)
@@ -2285,7 +1873,6 @@ def aggregate_relation(
     rel: Relation,
     node: AggregationNode,
     types: Dict[str, Type],
-    pallas_mode: str = "off",
 ) -> Relation:
     """Grouped aggregation, two strategies (ref GroupByHash.java:82-98 — the
     engine picks a hash strategy per key shape; here per domain knowledge):
@@ -2300,8 +1887,7 @@ def aggregate_relation(
     domains = _direct_agg_domains(rel, node)
     if domains is not None:
         page = _jit_direct_aggregate(
-            node.group_keys, node.aggregations, domains, rel.symbols, rel.page,
-            pallas_mode,
+            node.group_keys, node.aggregations, domains, rel.symbols, rel.page
         )
         _note_aggregation(node, rel, "direct", None)
         return Relation(page, node.group_keys + tuple(s for s, _ in node.aggregations))
@@ -2541,9 +2127,7 @@ def _group_sort_impl(group_keys, needed, symbols, page: Page):
     values ascending. The order is one ``K.sort_perm`` over the keys' packed
     order fields (a key's width is its type's or its dictionary's, so seven
     keys are a handful of words and the program holds one three-operand sort
-    whatever the keys and the columns); the columns follow in one gather.
-    Plain body — ops/megakernels.py re-traces it inside the fused join
-    kernel's sort-path aggregation stage (bit-identity by construction)."""
+    whatever the keys and the columns); the columns follow in one gather."""
     rel = Relation(page, symbols)
     fields = []
     for i, k in enumerate(group_keys):
@@ -2891,7 +2475,6 @@ def _direct_aggregate_impl(
     domains: Tuple[int, ...],
     symbols: Tuple[str, ...],
     page: Page,
-    pallas_mode: str = "off",
 ) -> Page:
     """Direct-indexed aggregation for small-domain group keys: gid computed
     elementwise from dictionary codes / bools — NO sort, NO scatter, no host
@@ -2925,28 +2508,7 @@ def _direct_aggregate_impl(
             Column(c.type, code_g.astype(c.data.dtype), code_g < D - 1, c.dictionary)
         )
 
-    # Pallas kernel tier (ops/pallas_kernels.py grouped sums): exact int64
-    # sums/counts via 16-bit limb accumulation in native int32 — ONE data pass
-    # per reduction instead of int64-emulated [G, n] reductions. min/max and
-    # float sums stay on the XLA formulation.
-    from ..ops import pallas_kernels as PK
-
-    use_pallas = pallas_mode != "off" and G <= PK.PALLAS_GROUP_LIMIT
-    interp = pallas_mode == "interpret"
-    if pallas_mode == "tpu" and page.capacity < 32768:
-        use_pallas = False  # launch overhead beats the win on tiny pages
-
     def reduce_fn(vals, w, kind):
-        if use_pallas and kind == "count":
-            return PK.grouped_sum_i32(w.astype(jnp.int32), w, gid, G, interpret=interp)
-        if (
-            use_pallas
-            and kind == "sum"
-            and not jnp.issubdtype(vals.dtype, jnp.floating)
-        ):
-            return PK.grouped_sum_i64(
-                vals.astype(jnp.int64), w, gid, G, interpret=interp
-            )
         return K.direct_group_reduce(vals, w, gid, G, kind)
 
     group_exists = reduce_fn(active.astype(jnp.int64), active, "count") > 0
@@ -2964,10 +2526,7 @@ def _direct_aggregate_impl(
     return Page(tuple(out_cols), group_exists)
 
 
-# the plain body stays importable: ops/megakernels.py re-traces it INSIDE the
-# fused join kernel (join -> partial-agg fusion), which is what makes the
-# fused aggregation bit-identical to this serial formulation by construction
-_jit_direct_aggregate = partial(kernelcost.jit, static_argnums=(0, 1, 2, 3, 5))(
+_jit_direct_aggregate = partial(kernelcost.jit, static_argnums=(0, 1, 2, 3))(
     _direct_aggregate_impl
 )
 

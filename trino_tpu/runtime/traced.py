@@ -147,8 +147,7 @@ class _TracedExecutor(PlanExecutor):
         domains = _direct_agg_domains(rel, node)
         if domains is not None:
             page = _jit_direct_aggregate.__wrapped__(
-                node.group_keys, node.aggregations, domains, rel.symbols, rel.page,
-                self._pallas_mode(),
+                node.group_keys, node.aggregations, domains, rel.symbols, rel.page
             )
             return Relation(
                 page, node.group_keys + tuple(s for s, _ in node.aggregations)

@@ -739,16 +739,6 @@ class TaskManager:
             catalogs=getattr(self.metadata, "catalogs", None),
             scope=f"part{desc.partition}/{desc.n_workers}",
         )
-        # megakernel plane: tell the executor this fragment's output feeds a
-        # hash exchange, so a fused root runs the repartition epilogue as
-        # its kernel output stage (ops/megakernels.attach_epilogue) and
-        # _emit_output's repartition skips the standalone hash program
-        out_keys = list(desc.output.get("keys", []))
-        out_n = int(desc.output.get("n", 1))
-        if out_keys and out_n > 1 and desc.output.get("kind") not in (
-            "gather", "broadcast",
-        ):
-            executor.repartition_hint = (tuple(out_keys), out_n)
         out_page = run_fragment_partition(executor, desc.root)
         self._emit_output(task, desc, out_page)
 
